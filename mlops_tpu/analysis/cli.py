@@ -96,13 +96,8 @@ def _run_analyze(args: argparse.Namespace) -> int:
 
     notes: list[str] = []
     if not getattr(args, "no_trace", False):
-        # First jax touch of the command: re-assert an explicit
-        # JAX_PLATFORMS before any backend initializes (commands.py does
-        # this for every other subcommand; analyze defers it to here so
-        # --no-trace stays importable on JAX-less machines).
-        from mlops_tpu.commands import _honor_jax_platforms_env
-
-        _honor_jax_platforms_env()
+        # First jax touch of the command (deferred to here so --no-trace
+        # stays importable on JAX-less machines).
         from mlops_tpu.analysis.traces import run_trace_checks
 
         trace_findings, notes = timed("layer2", run_trace_checks)

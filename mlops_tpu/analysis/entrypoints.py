@@ -244,7 +244,7 @@ def _build_serve_quant():
     qparams = abstract_quant_params()
     monitor = _abstract_monitor()
     # use_kernel=False: the analyzer traces the jnp composite route — the
-    # Pallas route is the same math (parity-pinned bitwise under jit) but
+    # Pallas route is the same math (parity-pinned to a tolerance) but
     # its jaxpr hides the body inside a pallas_call, which Layer-2's
     # structural checks cannot see through.
     entry = make_quant_packed_base(use_kernel=False)

@@ -69,7 +69,7 @@ class EntryPoint:
     # monitor/state.py). None = all buckets are one family.
     bucket_families: tuple[tuple[int, ...], ...] | None = None
     # Declared x64 entry (the gbm-tensor tier): the trace runs inside
-    # `jax.experimental.enable_x64()` — exactly how production lowers it
+    # `ops/gbm_tensor.py x64_context()` — exactly how production lowers it
     # (ops/gbm_tensor.py) — and the dtype rules treat f64 as the entry's
     # CONTRACT rather than a leak: TPU301 is skipped, and TPU303 ignores
     # round-trips through an f64 endpoint (the f64->f32->f64 narrowing at
@@ -365,9 +365,9 @@ def run_trace_checks(
             # aval canonicalization would otherwise silently demote its
             # f64 signature to f32 and trace a program nobody compiles.
             if entry.x64:
-                from jax.experimental import enable_x64
+                from mlops_tpu.ops.gbm_tensor import x64_context
 
-                ctx = enable_x64()
+                ctx = x64_context()
             else:
                 ctx = contextlib.nullcontext()
             with ctx:

@@ -7,15 +7,11 @@ for the per-entry-point job builders, `keys.py` for the versioned key, and
 from mlops_tpu.compilecache.cache import (
     CacheJob,
     CompileCache,
-    donation_deserialize_safe,
     from_config,
-    serialization_available,
 )
 
 __all__ = [
     "CacheJob",
     "CompileCache",
-    "donation_deserialize_safe",
     "from_config",
-    "serialization_available",
 ]

@@ -1,8 +1,7 @@
 """Persistent AOT executable cache.
 
 The readiness gate of every process — serve warmup, train windows, bulk
-sweeps — pays XLA compilation from scratch today (~54 s measured for the
-serving engine's bucket/group grid on the bench box). Compile time is pure
+sweeps — otherwise pays XLA compilation from scratch. Compile time is pure
 goodput loss (*ML Productivity Goodput*, arxiv 2502.06982), and prediction
 serving is exactly the workload where ahead-of-time compiled artifacts pay
 off (*A Tensor Compiler for Unified ML Prediction Serving*, arxiv
@@ -20,18 +19,15 @@ are atomic tmp+rename (the same discipline as `data/stream.py` outputs), so
 a crashed process can never leave a half-written artifact that a later one
 trusts.
 
-Capability gates, formalized here instead of scattered at call sites:
-
-- ``serialization_available()`` — jaxlibs without
-  ``jax.experimental.serialize_executable`` fall back to configuring JAX's
-  own persistent compilation cache dir under ``<dir>/xla`` (slower than
-  executable deserialization, still skips XLA re-optimization).
-- ``donation_deserialize_safe()`` — on the jaxlib 0.4.x CPU backend a
-  DONATED executable deserialized from cache segfaults (TP pjit step) or
-  silently corrupts results (dense scan window) — reproduced fresh-vs-warm
-  both ways (see parallel/compat.py donation_argnums, PR 1). Donated
-  programs on that backend bypass the cache entirely (no read, no write)
-  and the bypass is counted with its reason in ``stats()``.
+An executable is compiled for a fixed device assignment, and
+``deserialize_and_load`` must be told which devices those are (its default
+is every device of the backend, which loads a single-device program that
+then cannot execute on a multi-device host). The artifact header records
+the device ids the program was compiled for; a hit is loaded onto exactly
+those. An artifact that fails an integrity check is discarded and
+recompiled, and the cause is logged; one that loads and then cannot RUN
+(``CacheJob.execute_args``) is an error — logged with its cause, counted
+``unrunnable``, removed and recompiled — never a silent discard.
 
 Artifacts are trusted local state (same trust level as JAX's own persistent
 compilation cache): the checksum guards corruption and truncation, not
@@ -41,6 +37,8 @@ adversarial payloads — do not point ``cache.dir`` at an untrusted store.
 from __future__ import annotations
 
 import dataclasses
+import json
+import logging
 import os
 import pickle
 import threading
@@ -55,40 +53,13 @@ from mlops_tpu.utils.timing import StageClock
 
 _HEADER_MAGIC = "mlops-tpu-exe"
 
+logger = logging.getLogger(__name__)
+
 # tpulint Layer-3 manifest: one stats mutex, declared so the analyzer (and
 # the runtime sanitizer) flag any future nesting under it. Compiles,
 # deserializes, and disk I/O all happen OUTSIDE `_lock` by design — it
 # guards only the counters/program-stats dicts (see _record/stats).
 TPULINT_LOCK_ORDER = {"CompileCache": ("_lock",)}
-
-
-def _serialize_module():
-    try:
-        from jax.experimental import serialize_executable
-
-        return serialize_executable
-    # Capability probe, not error handling: any import failure (renamed
-    # module on a future jax, missing pjrt support) means "use the
-    # jax-persistent-cache fallback". pragma: depends on installed jaxlib.
-    except Exception:  # tpulint: disable=TPU201
-        return None
-
-
-def serialization_available() -> bool:
-    """True when this jaxlib can serialize/deserialize compiled
-    executables (`jax.experimental.serialize_executable`)."""
-    return _serialize_module() is not None
-
-
-def donation_deserialize_safe() -> bool:
-    """False on the jaxlib 0.4.x CPU backend, where executing a donated
-    executable deserialized from cache segfaults or silently corrupts
-    results (the PR 1 reproduction this gate formalizes)."""
-    import jax
-    import jaxlib
-
-    legacy = jaxlib.__version__.startswith("0.4.")
-    return not (legacy and jax.default_backend() == "cpu")
 
 
 @dataclasses.dataclass
@@ -110,6 +81,15 @@ class CacheJob:
     execute_args: tuple | None = None
 
 
+def execute_once(job: CacheJob, fn: Callable) -> None:
+    """Run ``fn`` on the job's ``execute_args``, where it carries any, and
+    wait for the result."""
+    if job.execute_args is not None:
+        import jax
+
+        jax.block_until_ready(fn(*job.execute_args))
+
+
 class CompileCache:
     """Directory-backed executable cache; thread-safe (warmup pools call
     ``load_or_compile`` concurrently — XLA compilation releases the GIL, so
@@ -118,36 +98,19 @@ class CompileCache:
     def __init__(self, directory: str | Path):
         self.directory = Path(directory)
         self.directory.mkdir(parents=True, exist_ok=True)
-        self._se = _serialize_module()
-        self.mode = "serialize" if self._se is not None else "jax-persistent-cache"
-        if self._se is None:  # pragma: no cover - depends on installed jaxlib
-            self._enable_xla_fallback()
+        from jax.experimental import serialize_executable
+
+        self._se = serialize_executable
         self._lock = threading.Lock()
         self._clock = StageClock()
         self._counts = {
             "hits": 0,
             "misses": 0,
-            "bypasses": 0,
             "discards": 0,
+            "unrunnable": 0,
             "unserializable": 0,
         }
-        self._bypass_reasons: dict[str, int] = {}
         self._programs: dict[str, dict[str, Any]] = {}
-
-    # ----------------------------------------------------------- fallback
-    def _enable_xla_fallback(self) -> None:
-        """No executable serialization on this jaxlib: route XLA's own
-        persistent compilation cache at ``<dir>/xla`` so recompiles still
-        skip optimization. Never clobbers a cache dir the process already
-        configured (tests/CI point JAX at their own)."""
-        import jax
-
-        if getattr(jax.config, "jax_compilation_cache_dir", None):
-            return
-        xla_dir = self.directory / "xla"
-        xla_dir.mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(xla_dir))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
     # -------------------------------------------------------------- paths
     def _artifact_path(self, entry_id: str, digest: str) -> Path:
@@ -169,38 +132,41 @@ class CompileCache:
             mesh_shape=job.mesh_shape,
             donated=job.donated,
         )
-        if job.donated and not donation_deserialize_safe():
-            # The formalized jaxlib-0.4.x-CPU hazard: never deserialize —
-            # and never persist, so no artifact exists for this backend to
-            # read back by accident.
-            fn, seconds = self._compile(job)
-            self._record(
-                label, digest, "bypass-compiled", seconds,
-                bypass_reason="donated-deserialize-unsafe",
-            )
-            return self._maybe_execute(job, fn)
-
         path = self._artifact_path(job.entry_id, digest)
-        if self._se is not None and path.is_file():
+        if path.is_file():
             fn, seconds = self._try_deserialize(path)
-            if fn is not None:
+            if fn is not None and self._runs(job, fn, path):
                 self._record(label, digest, "deserialized", seconds)
-                return self._maybe_execute(job, fn)
-            # Corrupt/truncated/incompatible artifact: already unlinked by
-            # _try_deserialize; fall through to a fresh compile.
+                return fn
+            # Corrupt/truncated/incompatible/unrunnable artifact: already
+            # unlinked, logged and counted; fall through to a fresh compile.
 
         fn, seconds = self._compile(job)
-        if self._se is not None:
-            self._persist(path, components, fn)
+        self._persist(path, components, fn, job)
         self._record(label, digest, "compiled", seconds)
-        return self._maybe_execute(job, fn)
-
-    def _maybe_execute(self, job: CacheJob, fn: Callable) -> Callable:
-        if job.execute_args is not None:
-            import jax
-
-            jax.block_until_ready(fn(*job.execute_args))
+        execute_once(job, fn)
         return fn
+
+    def _runs(self, job: CacheJob, fn: Callable, path: Path) -> bool:
+        """Execute a deserialized program once (where the job carries
+        ``execute_args``). A program that loaded and cannot run is an
+        ERROR with its cause in the log — the artifact goes, the caller
+        recompiles, and ``unrunnable`` in stats keeps the event visible."""
+        try:
+            execute_once(job, fn)
+            return True
+        # Whatever the runtime raises for an executable it loaded and
+        # cannot run (wrong device assignment, runtime refusing the
+        # program) is the event this method exists to report.
+        except Exception:  # tpulint: disable=TPU201
+            logger.exception(
+                "compile cache: artifact %s loaded but cannot run; "
+                "removing it and recompiling", path,
+            )
+            path.unlink(missing_ok=True)
+            with self._lock:
+                self._counts["unrunnable"] += 1
+            return False
 
     def _compile(self, job: CacheJob) -> tuple[Callable, float]:
         start = time.perf_counter()
@@ -208,10 +174,21 @@ class CompileCache:
             compiled = job.jitted.lower(*job.abstract_args).compile()
         return compiled, time.perf_counter() - start
 
+    def _load(self, payload, in_tree, out_tree, device_ids: list[int]):
+        """Deserialize onto the devices the program was compiled for."""
+        import jax
+
+        by_id = {d.id: d for d in jax.devices()}
+        return self._se.deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in device_ids],
+        )
+
     def _try_deserialize(self, path: Path) -> tuple[Callable | None, float]:
-        """Checksum-verified read; ANY failure discards the artifact and
-        reports None (the caller recompiles) — corruption can cost a
-        compile, never a crash and never a stale/garbled program."""
+        """Checksum-verified read; a failure discards the artifact, logs
+        the cause and reports None (the caller recompiles) — corruption
+        can cost a compile, never a crash and never a stale/garbled
+        program."""
         start = time.perf_counter()
         try:
             with self._clock.stage("deserialize"):
@@ -221,8 +198,6 @@ class CompileCache:
                 # path below, never in a served program.
                 raw = faults.corrupt("compilecache.read", raw)
                 header_line, _, blob = raw.partition(b"\n")
-                import json
-
                 header = json.loads(header_line)
                 if header.get("magic") != _HEADER_MAGIC:
                     raise ValueError("bad artifact magic")
@@ -232,41 +207,49 @@ class CompileCache:
                     raise ValueError("artifact truncated")
                 if sha256(blob).hexdigest() != header.get("sha256"):
                     raise ValueError("artifact checksum mismatch")
-                payload, in_tree, out_tree = pickle.loads(blob)
-                fn = self._se.deserialize_and_load(payload, in_tree, out_tree)
+                fn = self._load(*pickle.loads(blob), header["device_ids"])
             return fn, time.perf_counter() - start
         # The breadth is the contract: unreadable pickle, jaxlib refusing
-        # the executable, header rot — all become a counted discard plus a
-        # recompile, never an exception on the warmup path.
-        except Exception:  # tpulint: disable=TPU201
+        # the executable, header rot — all become a counted, logged
+        # discard plus a recompile, never an exception on the warmup path.
+        except Exception as err:  # tpulint: disable=TPU201
+            logger.warning(
+                "compile cache: discarding artifact %s (%s: %s)",
+                path, type(err).__name__, err,
+            )
             path.unlink(missing_ok=True)
             with self._lock:
                 self._counts["discards"] += 1
             return None, time.perf_counter() - start
 
-    def _persist(self, path: Path, components: dict, compiled: Any) -> None:
+    def _persist(
+        self, path: Path, components: dict, compiled: Any, job: CacheJob
+    ) -> None:
         """Atomic tmp+rename write (stream.py discipline): concurrent
         writers race benignly (same key -> same bytes; os.replace is
         atomic), and a crash never leaves a partial artifact in place.
 
-        The payload is VALIDATED (one in-process deserialize) before it
-        touches disk: on jaxlib 0.4.x CPU, an executable whose compile was
-        served from JAX's persistent compilation cache on disk serializes
-        into an artifact that fails at load with "Symbols not found"
-        (reproduced cross-process) — such programs are counted
-        ``unserializable`` and never persisted, so the artifact store only
-        ever holds executables proven to round-trip."""
+        The payload is VALIDATED before it touches disk: deserialized onto
+        the devices it was compiled for and, where the job carries
+        ``execute_args``, executed once. A program that does not survive
+        that is logged with its cause, counted ``unserializable`` and
+        never persisted, so the store only ever holds executables proven
+        to round-trip AND run."""
         try:
+            device_ids = [
+                d.id for d in compiled.runtime_executable().local_devices()
+            ]
             serialized = self._se.serialize(compiled)
-            self._se.deserialize_and_load(*serialized)
-        # Some backends compile programs their PjRt runtime cannot
-        # serialize or round-trip (the jaxlib 0.4.x case above; exotic
-        # plugin backends); serving must not die for a cache write.
+            execute_once(job, self._load(*serialized, device_ids))
+        # Serving must not die for a cache write; the cause is logged.
         except Exception:  # tpulint: disable=TPU201
+            logger.exception(
+                "compile cache: %s does not round-trip; not persisted",
+                job.label or job.entry_id,
+            )
             with self._lock:
                 self._counts["unserializable"] += 1
             return
-        import json
 
         blob = pickle.dumps(serialized)
         header = {
@@ -274,6 +257,7 @@ class CompileCache:
             "format": keys.CACHE_FORMAT_VERSION,
             "sha256": sha256(blob).hexdigest(),
             "payload_bytes": len(blob),
+            "device_ids": device_ids,
             "key": components,
         }
         path.parent.mkdir(parents=True, exist_ok=True)
@@ -298,18 +282,9 @@ class CompileCache:
         digest: str,
         source: str,
         seconds: float,
-        bypass_reason: str | None = None,
     ) -> None:
         with self._lock:
-            if source == "deserialized":
-                self._counts["hits"] += 1
-            elif source == "compiled":
-                self._counts["misses"] += 1
-            else:
-                self._counts["bypasses"] += 1
-                self._bypass_reasons[bypass_reason] = (
-                    self._bypass_reasons.get(bypass_reason, 0) + 1
-                )
+            self._counts["hits" if source == "deserialized" else "misses"] += 1
             self._programs[label] = {
                 "source": source,
                 "seconds": round(seconds, 4),
@@ -317,7 +292,7 @@ class CompileCache:
             }
 
     def stats(self) -> dict[str, Any]:
-        """Hit/miss/bypass counts plus per-program compile vs deserialize
+        """Hit/miss/discard counts plus per-program compile vs deserialize
         wall time (`utils/timing.py StageClock` accumulates the busy
         seconds per stage)."""
         with self._lock:
@@ -326,10 +301,8 @@ class CompileCache:
                 for name, timing in self._clock.report(1.0).items()
             }
             return {
-                "mode": self.mode,
                 "dir": str(self.directory),
                 **dict(self._counts),
-                "bypass_reasons": dict(self._bypass_reasons),
                 "compile_s": round(clock.get("compile", 0.0), 4),
                 "deserialize_s": round(clock.get("deserialize", 0.0), 4),
                 "programs": {k: dict(v) for k, v in self._programs.items()},

@@ -26,7 +26,7 @@ import hashlib
 import json
 from typing import Any
 
-CACHE_FORMAT_VERSION = 1
+CACHE_FORMAT_VERSION = 2
 
 
 def environment_fingerprint() -> dict[str, Any]:

@@ -23,7 +23,7 @@ import os
 import time
 from typing import Any, Callable
 
-from mlops_tpu.compilecache.cache import CacheJob, CompileCache
+from mlops_tpu.compilecache.cache import CacheJob, CompileCache, execute_once
 from mlops_tpu.compilecache.keys import (
     model_fingerprint,
     train_fingerprint,
@@ -156,7 +156,7 @@ def serve_predict_jobs(
     import jax
     import numpy as np
 
-    from mlops_tpu.ops.predict import _acc_donation, make_packed_predict_base
+    from mlops_tpu.ops.predict import ACC_DONATION, make_packed_predict_base
 
     concrete = _is_concrete(variables)
     if (mesh is not None or placement is not None) and not concrete:
@@ -165,7 +165,6 @@ def serve_predict_jobs(
             "shardings are the lowered layout)"
         )
     config_hash = model_fingerprint(model_config) + device_tag
-    donate = _acc_donation()
     mesh_shape = tuple(mesh.devices.shape) if mesh is not None else None
     jobs = []
     for bucket in buckets:
@@ -176,14 +175,14 @@ def serve_predict_jobs(
                 # dispatch cache, and per-job objects keep the thread pool
                 # free of shared mutable state.
                 jitted=jax.jit(
-                    make_packed_predict_base(model), donate_argnums=donate
+                    make_packed_predict_base(model), donate_argnums=ACC_DONATION
                 ),
                 abstract_args=_serve_avals(
                     variables, monitor, (bucket,), mesh, placement
                 ),
                 config_hash=config_hash,
                 mesh_shape=mesh_shape,
-                donated=bool(donate),
+                donated=True,
                 label=f"serve-predict-packed/b{bucket}",
                 meta={"bucket": bucket},
                 execute_args=(
@@ -214,7 +213,7 @@ def serve_group_jobs(
     import jax
     import numpy as np
 
-    from mlops_tpu.ops.predict import _acc_donation, make_packed_grouped_base
+    from mlops_tpu.ops.predict import ACC_DONATION, make_packed_grouped_base
 
     concrete = _is_concrete(variables)
     if (mesh is not None or placement is not None) and not concrete:
@@ -223,7 +222,6 @@ def serve_group_jobs(
             "shardings are the lowered layout)"
         )
     config_hash = model_fingerprint(model_config) + device_tag
-    donate = _acc_donation()
     mesh_shape = tuple(mesh.devices.shape) if mesh is not None else None
     jobs = []
     for slots, rows in grid:
@@ -231,14 +229,14 @@ def serve_group_jobs(
             CacheJob(
                 entry_id="serve-predict-group-packed",
                 jitted=jax.jit(
-                    make_packed_grouped_base(model), donate_argnums=donate
+                    make_packed_grouped_base(model), donate_argnums=ACC_DONATION
                 ),
                 abstract_args=_serve_avals(
                     variables, monitor, (slots, rows), mesh, placement
                 ),
                 config_hash=config_hash,
                 mesh_shape=mesh_shape,
-                donated=bool(donate),
+                donated=True,
                 label=f"serve-predict-group-packed/g{slots}x{rows}",
                 meta={"slots": slots, "rows": rows},
                 execute_args=(
@@ -271,7 +269,7 @@ def serve_quant_jobs(
     import jax
     import numpy as np
 
-    from mlops_tpu.ops.predict import _acc_donation
+    from mlops_tpu.ops.predict import ACC_DONATION
     from mlops_tpu.ops.quant import QUANT_FORMAT, quant_params_geometry
     from mlops_tpu.ops.quant_kernel import make_quant_packed_base
 
@@ -285,20 +283,19 @@ def serve_quant_jobs(
     config_hash = (
         model_fingerprint((QUANT_FORMAT, embed_dim, hidden)) + device_tag
     )
-    donate = _acc_donation()
     jobs = []
     for bucket in buckets:
         jobs.append(
             CacheJob(
                 entry_id="serve-predict-quant-packed",
                 jitted=jax.jit(
-                    make_quant_packed_base(), donate_argnums=donate
+                    make_quant_packed_base(), donate_argnums=ACC_DONATION
                 ),
                 abstract_args=_serve_avals(
                     qparams, monitor, (bucket,), None, placement
                 ),
                 config_hash=config_hash,
-                donated=bool(donate),
+                donated=True,
                 label=f"serve-predict-quant-packed/b{bucket}",
                 meta={"bucket": bucket},
                 execute_args=(
@@ -325,7 +322,7 @@ def serve_quant_group_jobs(
     import jax
     import numpy as np
 
-    from mlops_tpu.ops.predict import _acc_donation
+    from mlops_tpu.ops.predict import ACC_DONATION
     from mlops_tpu.ops.quant import QUANT_FORMAT, quant_params_geometry
     from mlops_tpu.ops.quant_kernel import make_quant_grouped_base
 
@@ -339,20 +336,19 @@ def serve_quant_group_jobs(
     config_hash = (
         model_fingerprint((QUANT_FORMAT, embed_dim, hidden)) + device_tag
     )
-    donate = _acc_donation()
     jobs = []
     for slots, rows in grid:
         jobs.append(
             CacheJob(
                 entry_id="serve-predict-quant-group-packed",
                 jitted=jax.jit(
-                    make_quant_grouped_base(), donate_argnums=donate
+                    make_quant_grouped_base(), donate_argnums=ACC_DONATION
                 ),
                 abstract_args=_serve_avals(
                     qparams, monitor, (slots, rows), None, placement
                 ),
                 config_hash=config_hash,
-                donated=bool(donate),
+                donated=True,
                 label=f"serve-predict-quant-group-packed/g{slots}x{rows}",
                 meta={"slots": slots, "rows": rows},
                 execute_args=(
@@ -444,7 +440,7 @@ def serve_gbm_jobs(
         gbm_fingerprint,
         make_gbm_packed_base,
     )
-    from mlops_tpu.ops.predict import _acc_donation
+    from mlops_tpu.ops.predict import ACC_DONATION
 
     concrete = _is_concrete(variables)
     if placement is not None and not concrete:
@@ -453,7 +449,6 @@ def serve_gbm_jobs(
             "shardings are the lowered layout)"
         )
     config_hash = gbm_fingerprint(geometry) + device_tag
-    donate = _acc_donation()
     # Committed f64 scalar: a host np.float64 fed to the compiled
     # executable outside the x64 context would canonicalize to f32 and
     # miss the f64 temperature signature.
@@ -466,14 +461,14 @@ def serve_gbm_jobs(
                 jitted=_X64Jitted(
                     jax.jit(
                         make_gbm_packed_base(geometry.depth),
-                        donate_argnums=donate,
+                        donate_argnums=ACC_DONATION,
                     )
                 ),
                 abstract_args=_gbm_serve_avals(
                     variables, monitor, (bucket,), placement
                 ),
                 config_hash=config_hash,
-                donated=bool(donate),
+                donated=True,
                 label=f"serve-predict-gbm-packed/b{bucket}",
                 meta={"bucket": bucket},
                 execute_args=(
@@ -506,7 +501,7 @@ def serve_gbm_group_jobs(
         gbm_fingerprint,
         make_gbm_grouped_base,
     )
-    from mlops_tpu.ops.predict import _acc_donation
+    from mlops_tpu.ops.predict import ACC_DONATION
 
     concrete = _is_concrete(variables)
     if placement is not None and not concrete:
@@ -515,7 +510,6 @@ def serve_gbm_group_jobs(
             "shardings are the lowered layout)"
         )
     config_hash = gbm_fingerprint(geometry) + device_tag
-    donate = _acc_donation()
     temp = device_put_x64(np.float64(temperature)) if concrete else None
     jobs = []
     for slots, rows in grid:
@@ -525,14 +519,14 @@ def serve_gbm_group_jobs(
                 jitted=_X64Jitted(
                     jax.jit(
                         make_gbm_grouped_base(geometry.depth),
-                        donate_argnums=donate,
+                        donate_argnums=ACC_DONATION,
                     )
                 ),
                 abstract_args=_gbm_serve_avals(
                     variables, monitor, (slots, rows), placement
                 ),
                 config_hash=config_hash,
-                donated=bool(donate),
+                donated=True,
                 label=f"serve-predict-gbm-group-packed/g{slots}x{rows}",
                 meta={"slots": slots, "rows": rows},
                 execute_args=(
@@ -630,13 +624,9 @@ def train_window_job(
     jitted: Callable | None = None,
 ) -> CacheJob:
     """The dense scan window (entry ``train-step-dense``) at one (window,
-    dataset-shape) signature. Donation follows `parallel/compat.py
-    donation_argnums`: when the backend donates the train state, the cache
-    layer's capability gate bypasses deserialization on backends where a
-    cached donated executable misbehaves."""
+    dataset-shape) signature. The train state is donated."""
     import jax
 
-    from mlops_tpu.parallel.compat import donation_argnums
     from mlops_tpu.train.loop import make_train_window
 
     if jitted is None:
@@ -648,7 +638,7 @@ def train_window_job(
         jitted=jitted,
         abstract_args=args,
         config_hash=train_fingerprint(model, train_config, f"window={window}"),
-        donated=bool(donation_argnums(0)),
+        donated=True,
         label=f"train-step-dense/w{window}xn{rows}",
         meta={"window": window, "rows": rows},
     )
@@ -670,7 +660,6 @@ def tp_step_job(
     import jax
     import jax.numpy as jnp
 
-    from mlops_tpu.parallel.compat import donation_argnums
 
     S = jax.ShapeDtypeStruct
     cat_a, num_a, _ = _schema_avals((batch_size,))
@@ -686,7 +675,7 @@ def tp_step_job(
         ),
         config_hash=train_fingerprint(model, train_config, "tp"),
         mesh_shape=tuple(mesh.devices.shape),
-        donated=bool(donation_argnums(0)),
+        donated=True,
         label=f"train-step-tp/b{batch_size}",
         meta={"batch_size": batch_size},
     )
@@ -713,10 +702,7 @@ def run_jobs(
         if cache is not None:
             return cache.load_or_compile(job)
         fn = job.jitted.lower(*job.abstract_args).compile()
-        if job.execute_args is not None:
-            import jax
-
-            jax.block_until_ready(fn(*job.execute_args))
+        execute_once(job, fn)
         return fn
 
     if not jobs:

@@ -433,8 +433,8 @@ def score_csv_stream(
     )
     transfer = make_chunk_transfer(bundle, mesh)
     # cat ids narrow to int8 on the device path (max vocab cardinality is
-    # 12; lossless, and host->device bytes are the transfer bottleneck on
-    # remote-attached chips) — same convention as score_dataset.
+    # 12; lossless, a quarter of the host->device bytes) — same
+    # convention as score_dataset.
     narrow = None if bundle.flavor == "sklearn" else np.int8
 
     # Warm the one compiled chunk program before the streamed (and timed)

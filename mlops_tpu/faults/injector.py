@@ -10,7 +10,7 @@ NAMED INJECTION POINTS (``faults.fire("serve.engine.dispatch")``,
 what:
 
 - ``raise``   — raise a named exception (device error, OSError, ...)
-- ``delay``   — sleep ``delay_s`` (an engine stall / slow tunnel)
+- ``delay``   — sleep ``delay_s`` (an engine stall / slow device)
 - ``kill``    — SIGKILL this process mid-operation (torn-write proofs)
 - ``corrupt`` — flip seeded bits in the bytes passing a read point
 

@@ -10,10 +10,12 @@ the bottleneck long before the chip is.
 Build model: compiled on first use with plain ``g++ -O3 -shared -fPIC``
 into ``_build/`` next to the source, keyed by a source hash so edits
 rebuild automatically. No pybind11 (not in the image) — a pure C ABI called
-through ctypes. Everything degrades gracefully: if the toolchain is absent
-or compilation fails, callers fall back to the pure-Python encoder
-(``Preprocessor.encode``) with identical semantics — a parity test pins
-native == Python output exactly.
+through ctypes. If the toolchain is absent or compilation fails, callers
+fall back to the pure-Python encoder (``Preprocessor.encode``) with
+identical semantics — a parity test pins native == Python output exactly
+— and `encoder_status` says which one serves and why, so a result can
+name its encoder (chip_smoke.py prints it, and fails when the build
+failed on a machine that has ``g++``).
 """
 
 from __future__ import annotations
@@ -43,9 +45,11 @@ _ERRORS = {
 }
 
 _lib_cache: ctypes.CDLL | None | bool = None  # False = tried and failed
+_python_reason = ""  # why the Python encoder serves, when it does
 
 
 def _compile() -> Path | None:
+    global _python_reason
     source = _SRC.read_bytes()
     tag = hashlib.sha256(source).hexdigest()[:12]
     so_path = _BUILD_DIR / f"encoder_{tag}.so"
@@ -67,8 +71,8 @@ def _compile() -> Path | None:
         os.replace(tmp_path, so_path)
     except (OSError, subprocess.SubprocessError) as err:
         detail = getattr(err, "stderr", "") or str(err)
-        logger.warning("native encoder build failed (%s); using Python path",
-                       detail.strip()[:500])
+        _python_reason = f"build failed: {detail.strip()[:500]}"
+        logger.warning("native encoder %s; using Python path", _python_reason)
         tmp_path.unlink(missing_ok=True)
         return None
     # Clean superseded builds (old source hashes).
@@ -79,9 +83,10 @@ def _compile() -> Path | None:
 
 
 def _lib() -> ctypes.CDLL | None:
-    global _lib_cache
+    global _lib_cache, _python_reason
     if _lib_cache is None:
         if os.environ.get("MLOPS_TPU_NO_NATIVE"):
+            _python_reason = "MLOPS_TPU_NO_NATIVE is set"
             _lib_cache = False
         else:
             so_path = _compile()
@@ -100,6 +105,7 @@ def _lib() -> ctypes.CDLL | None:
                         "Python path", so_path.name, err,
                     )
                     so_path.unlink(missing_ok=True)
+                    _python_reason = f"load failed: {err}"
                     _lib_cache = False
                     return None
                 lib.mlops_encode_csv.restype = ctypes.c_long
@@ -124,6 +130,15 @@ def _lib() -> ctypes.CDLL | None:
 
 def native_available() -> bool:
     return _lib() is not None
+
+
+def encoder_status() -> dict[str, str]:
+    """Which encoder serves in this process — ``{"encoder": "c++"}`` or
+    ``{"encoder": "python", "reason": ...}`` — after trying to build and
+    load the native one."""
+    if native_available():
+        return {"encoder": "c++"}
+    return {"encoder": "python", "reason": _python_reason}
 
 
 def encode_csv_native(
@@ -235,5 +250,6 @@ __all__ = [
     "encode_csv",
     "encode_csv_bytes",
     "encode_csv_native",
+    "encoder_status",
     "native_available",
 ]

@@ -34,9 +34,8 @@ explicit add chain), ``1/(1+exp(-s))`` on the f64 score — so
 ``SklearnBaseline.predict_proba`` (pinned in tests/test_gbm_tensor.py),
 including unknown / out-of-range / non-integer category values. The f64
 compute requires tracing, lowering, AND ``device_put`` of the tree
-tensors inside a ``jax.experimental.enable_x64()`` context (thread-local
-in jax 0.4.x — concurrent f32 dispatches on other threads are
-unaffected); the compiled executable itself runs fine outside it. The
+tensors inside a ``jax.enable_x64(True)`` context (thread-local —
+concurrent f32 dispatches on other threads are unaffected); the compiled executable itself runs fine outside it. The
 monitors stay f32 by the explicit dtype pins in `ops/drift.py` /
 `ops/outlier.py`, so the packed buffer is one f32 vector exactly like
 the other tiers.
@@ -74,12 +73,12 @@ class GbmGeometry:
 
 def x64_context():
     """The thread-local double-precision context every gbm-tensor trace,
-    lowering, and ``device_put`` of tree tensors must run inside (jax
-    0.4.x: entering it inside an f32 trace is a type error; committed f64
-    arrays fed to a non-x64 jit silently downcast)."""
-    from jax.experimental import enable_x64
+    lowering, and ``device_put`` of tree tensors must run inside (entering
+    it inside an f32 trace is a type error; committed f64 arrays fed to a
+    non-x64 jit silently downcast)."""
+    import jax
 
-    return enable_x64()
+    return jax.enable_x64(True)
 
 
 def device_put_x64(tree: Any) -> Any:

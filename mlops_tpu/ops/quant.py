@@ -21,8 +21,9 @@ the f32-after-dequant rule is what buys the bulk throughput there).
 Categorical lookup is a one-hot matmul, not a gather: `broadcasted_iota`
 comparisons lower on Mosaic (TPU Pallas) where dynamic gathers do not,
 and every consumer — the jnp composite, the Pallas kernel body, and the
-bulk chunk program — calls the SAME `student_logits`, so serve/bulk/
-kernel paths are bit-identical by construction.
+bulk chunk program — calls the SAME `student_logits`, so the serve/bulk/
+kernel paths are one definition (equal to f32 rounding across compilers:
+`ops/quant_kernel.py KERNEL_COMPOSITE_ATOL`).
 
 Fitting lives in `train/distill.py distill_quant_student` (the fidelity
 gate) and `train/calibrate.py` (the post-hoc temperature refit); this
@@ -134,7 +135,7 @@ def one_hot_2d(ids_col: jnp.ndarray, k: int) -> jnp.ndarray:
     """One-hot of an id column ``[N]`` -> f32 ``[N, k]`` via a 2-D
     broadcasted iota — the Mosaic-safe form (1-D iota does not lower on
     TPU Pallas; `jax.nn.one_hot` builds one). The ONE one-hot rule every
-    quant-tier consumer shares, so kernel and composite agree bitwise."""
+    quant-tier consumer shares."""
     iota = jax.lax.broadcasted_iota(jnp.int32, (ids_col.shape[0], k), 1)
     return (ids_col[:, None] == iota).astype(jnp.float32)
 
@@ -178,7 +179,7 @@ def quant_student_logits(
 ) -> jnp.ndarray:
     """Forward through the QUANTIZED tree: dequantize in-jit, then the
     shared f32 forward — serving, bulk, and the Pallas kernel body all
-    route through here (bit parity by construction)."""
+    route through here (one definition)."""
     w1 = dequantize_dense(qparams["w1_q"], qparams["w1_s"])
     w2 = qparams["w2_q"].astype(jnp.float32) * qparams["w2_s"]
     return student_logits(

@@ -10,7 +10,10 @@ counts, and the dense masked K-S statistics — is a single hand-written
 VMEM once, int8/bf16 weights stay quantized in HBM, and nothing round-
 trips between fusion islands.
 
-Split of labor (shared by kernel AND composite, so they agree bitwise):
+Split of labor (shared by kernel AND composite; Mosaic and XLA order and
+round the same expressions differently — and run the f32 matmuls at
+different precisions on the chip — so the contract between the two is a
+tolerance, `KERNEL_COMPOSITE_ATOL` below, not bit equality):
 
 - IN the kernel: student logits -> calibrated probabilities, outlier
   flags, per-feature categorical one-hot COUNTS, and the numeric K-S
@@ -23,14 +26,16 @@ Split of labor (shared by kernel AND composite, so they agree bitwise):
   math (whose ``arange`` constants a kernel body cannot capture), not
   worth kernel bytes.
 
-Capability gate: the kernel is the TPU path. Off-TPU (this CPU container)
-the default route is the jnp COMPOSITE — the same `_fused_core` called
-directly, which is also the bit-parity reference; ``use_kernel=True``
-forces the kernel (interpret mode off-TPU) so the parity tests exercise
-the pallas_call pipeline everywhere. The packed calling convention,
-layout (`packed_layout`), and accumulator fold are identical to the
-exact tier, so `serve/engine.py` runs this tier through the SAME exec
-tables, buckets, and swap/rollback machinery.
+Routing (`ops/kernel_gate.py`): with ``use_kernel=None`` — production —
+serve buckets up to QUANT_KERNEL_MAX_ROWS take the COMPILED kernel where
+the program is lowered for a TPU, and the jnp COMPOSITE (the same
+`_fused_core` called directly, also the parity reference) on every other
+platform; larger buckets are the composite everywhere. ``use_kernel=True``
+is the pallas_call unconditionally — compiled, unless the caller (a CPU
+test) passes ``interpret=True`` itself. The packed calling convention,
+layout (`packed_layout`), and accumulator fold are identical to the exact
+tier, so `serve/engine.py` runs this tier through the SAME exec tables,
+buckets, and swap/rollback machinery.
 """
 
 from __future__ import annotations
@@ -48,6 +53,7 @@ from mlops_tpu.monitor.state import (
     fold_accumulator,
     fold_accumulator_grouped,
 )
+from mlops_tpu.ops.kernel_gate import tpu_kernel_or
 from mlops_tpu.ops.drift import (
     _kolmogorov_sf,
     chi2_two_sample,
@@ -55,39 +61,19 @@ from mlops_tpu.ops.drift import (
 )
 from mlops_tpu.ops.quant import dequantize_dense, one_hot_2d
 
-# Same compat alias as ops/attention.py (jax >= 0.5 renamed the class).
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-if _CompilerParams is None:
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams — update the compat alias in ops/quant_kernel.py "
-        "for this jax version"
-    )
-
 # Largest row bucket the kernel serves. 256 is the top serve bucket; the
 # dense K-S working set at B=256 (a [256, 2048] f32 comparison plane per
 # numeric feature, features walked sequentially) stays a few MB — well
 # inside VMEM.
 QUANT_KERNEL_MAX_ROWS = 256
 
-
-def quant_kernel_available() -> bool:
-    """Capability gate: Mosaic lowering exists on the TPU backend only.
-    Everything else (this CPU container included) runs the jnp composite
-    by default and the kernel only under interpret-mode force."""
-    return jax.default_backend() == "tpu"
-
-
-def _route_kernel(use_kernel: bool | None, rows: int) -> tuple[bool, bool]:
-    """-> (run_pallas_call, interpret). ``None`` auto-routes: kernel on
-    TPU for supported buckets, composite otherwise. ``True`` forces the
-    pallas_call anywhere (interpret off-TPU — the parity tests);
-    ``False`` forces the composite."""
-    if use_kernel is None:
-        use_kernel = quant_kernel_available() and rows <= QUANT_KERNEL_MAX_ROWS
-    return use_kernel, jax.default_backend() != "tpu"
+# The kernel-vs-composite contract, stated once (tests/test_quant.py on
+# the CPU in interpret mode, chip_smoke.py compiled on the chip): max abs
+# difference of any packed value or accumulator entry. In interpret mode
+# the two differ by an ulp (1.5e-8); compiled on a v5e the largest
+# difference was 2.7e-3, on a probability of the 256-row body (chip runs,
+# PR 22) — the tolerance is that with headroom for another bundle.
+KERNEL_COMPOSITE_ATOL = 1e-2
 
 
 def _fused_core(
@@ -195,11 +181,12 @@ def quant_fused(
     numeric: jnp.ndarray,
     mask: jnp.ndarray,
     use_kernel: bool | None = None,
+    interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused quant predict for one padded request:
     ``(preds [B], flags [B], drift [D])`` — the same triple the exact
     tier's packed body produces, with the heavy body routed through the
-    Pallas kernel or its jnp composite (`_route_kernel`)."""
+    Pallas kernel or its jnp composite (module docstring: Routing)."""
     b = cat_ids.shape[0]
     maskf_row = mask.astype(jnp.float32)[None, :]
     temp_11 = jnp.asarray(temperature, jnp.float32).reshape(1, 1)
@@ -213,35 +200,43 @@ def quant_fused(
         monitor.out_threshold.reshape(1, 1), temp_11,
         cat_ids, numeric, maskf_row,
     )
-    run_kernel, interpret = _route_kernel(use_kernel, b)
-    if run_kernel:
-        c, k = qparams["embed"].shape[0], qparams["embed"].shape[1]
-        m = numeric.shape[1]
+    c, k = qparams["embed"].shape[0], qparams["embed"].shape[1]
+    m = numeric.shape[1]
+
+    def kernel(*args):
         # Scalars ride SMEM; every tensor operand is a whole-array VMEM
         # block (grid=() — no index maps).
         smem = {5, 6, 11, 12}  # w2_s, b2, threshold, temperature
-        in_specs = [
-            pl.BlockSpec(
-                memory_space=pltpu.SMEM if i in smem else pltpu.VMEM
-            )
-            for i in range(len(core_args))
-        ]
-        preds, flags, cat_counts, ks_stat = pl.pallas_call(
-            _fused_kernel,
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec(memory_space=pltpu.VMEM) for _ in range(4)
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((1, b), jnp.float32),
-                jax.ShapeDtypeStruct((1, b), jnp.float32),
-                jax.ShapeDtypeStruct((c, k), jnp.float32),
-                jax.ShapeDtypeStruct((1, m), jnp.float32),
-            ],
-            interpret=interpret,
-        )(*core_args)
+        return tuple(
+            pl.pallas_call(
+                _fused_kernel,
+                in_specs=[
+                    pl.BlockSpec(
+                        memory_space=pltpu.SMEM if i in smem else pltpu.VMEM
+                    )
+                    for i in range(len(args))
+                ],
+                out_specs=[
+                    pl.BlockSpec(memory_space=pltpu.VMEM) for _ in range(4)
+                ],
+                out_shape=[
+                    jax.ShapeDtypeStruct((1, b), jnp.float32),
+                    jax.ShapeDtypeStruct((1, b), jnp.float32),
+                    jax.ShapeDtypeStruct((c, k), jnp.float32),
+                    jax.ShapeDtypeStruct((1, m), jnp.float32),
+                ],
+                interpret=interpret,
+                name="quant_fused",
+            )(*args)
+        )
+
+    if use_kernel:
+        outs = kernel(*core_args)
+    elif use_kernel is None and b <= QUANT_KERNEL_MAX_ROWS:
+        outs = tpu_kernel_or(kernel, _fused_core, *core_args)
     else:
-        preds, flags, cat_counts, ks_stat = _fused_core(*core_args)
+        outs = _fused_core(*core_args)
+    preds, flags, cat_counts, ks_stat = outs
 
     # P-value assembly + drift: tiny scalar math on [C,K]/[M] aggregates,
     # shared by both routes (same `1 - p` order as
@@ -259,7 +254,9 @@ def quant_fused(
     return preds[0], flags[0], drift
 
 
-def make_quant_packed_base(use_kernel: bool | None = None) -> Callable:
+def make_quant_packed_base(
+    use_kernel: bool | None = None, interpret: bool = False
+) -> Callable:
     """Quant twin of `ops/predict.py make_packed_predict_base`: identical
     7-argument cacheable signature and ``f32[2B + D]`` packed layout
     (`packed_layout` slices it), with ``variables`` = the quant param
@@ -276,7 +273,8 @@ def make_quant_packed_base(use_kernel: bool | None = None) -> Callable:
         mask: jnp.ndarray,
     ):
         preds, flags, drift = quant_fused(
-            qparams, monitor, temperature, cat_ids, numeric, mask, use_kernel
+            qparams, monitor, temperature, cat_ids, numeric, mask, use_kernel,
+            interpret,
         )
         packed = jnp.concatenate([preds, flags, drift])
         return packed, fold_accumulator(acc, flags, drift, mask)
@@ -284,7 +282,9 @@ def make_quant_packed_base(use_kernel: bool | None = None) -> Callable:
     return predict
 
 
-def make_quant_grouped_base(use_kernel: bool | None = None) -> Callable:
+def make_quant_grouped_base(
+    use_kernel: bool | None = None, interpret: bool = False
+) -> Callable:
     """Quant twin of `make_packed_grouped_base`: ``f32[S, 2R+D]`` packed
     group output, per-request drift over each slot's OWN rows (the vmap
     batches the pallas_call over slots), accumulator folded outside the
@@ -292,7 +292,8 @@ def make_quant_grouped_base(use_kernel: bool | None = None) -> Callable:
 
     def single(qparams, monitor, temperature, cat_ids, numeric, mask):
         return quant_fused(
-            qparams, monitor, temperature, cat_ids, numeric, mask, use_kernel
+            qparams, monitor, temperature, cat_ids, numeric, mask, use_kernel,
+            interpret,
         )
 
     def grouped(

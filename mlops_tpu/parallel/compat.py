@@ -1,69 +1,25 @@
-"""shard_map across JAX versions.
-
-jax >= 0.5 exposes ``jax.shard_map`` with a ``check_vma`` kwarg; 0.4.x has
-``jax.experimental.shard_map.shard_map`` with the same flag named
-``check_rep``. Every shard_map in the framework routes through this one
-seam so the kernels run on the container's pinned jax and current releases
-alike.
-"""
+"""The one seam every shard_map in the framework routes through."""
 
 from __future__ import annotations
 
 from typing import Callable
 
-try:  # jax >= 0.5
-    from jax import shard_map as _shard_map
-
-    _REP_KWARG = "check_vma"
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _REP_KWARG = "check_rep"
-
-# True on jax 0.4.x. Callers that only need the replication checker off on
-# the legacy path (because modern jax accepts their pvary/pcast
-# annotations) gate on this instead of passing check_vma=False outright.
-LEGACY_SHARD_MAP = _REP_KWARG == "check_rep"
+import jax
 
 
 def shard_map(
-    f: Callable, *, mesh, in_specs, out_specs, check_vma: bool | None = None
+    f: Callable, *, mesh, in_specs, out_specs, check_vma: bool = True
 ) -> Callable:
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if check_vma is not None:
-        kwargs[_REP_KWARG] = check_vma
-    return _shard_map(f, **kwargs)
-
-
-def donation_argnums(*argnums: int) -> tuple[int, ...]:
-    """Donation argnums for a train-step jit, gated on a jaxlib 0.4.x CPU
-    bug: a DONATED executable deserialized from the persistent compilation
-    cache misbehaves when run — the sharded TP step segfaults outright and
-    the dense scan window silently returns corrupted numbers (both
-    reproduced fresh-vs-warm on this container; gone in jax >= 0.5). On
-    the 0.4.x CPU backend donation buys nothing anyway, so drop it there;
-    TPU/GPU and newer jax get the full donation list."""
-    import jax
-
-    # One version boundary for the whole module: the structural
-    # LEGACY_SHARD_MAP probe, not a second __version__ parse.
-    if not LEGACY_SHARD_MAP or jax.default_backend() != "cpu":
-        return argnums
-    return ()
+    """``jax.shard_map``; callers whose bodies the varying-manual-axes
+    checker cannot type pass ``check_vma=False``."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
+    )
 
 
 def pcast_varying(x, axis_names: tuple[str, ...]):
-    """Type ``x`` as varying over ``axis_names`` inside shard_map.
-
-    jax >= 0.7 requires the annotation (``lax.pcast``/``pvary``) for scan
-    carries under the varying-manual-axes type system; 0.4.x has no such
-    system (``check_rep=False`` covers it) and the value passes through."""
-    import jax
-
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axis_names, to="varying")
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        return pvary(x, axis_names)
-    return x
+    """Type ``x`` as varying over ``axis_names`` inside shard_map — the
+    annotation scan carries need under the varying-manual-axes type
+    system."""
+    return jax.lax.pcast(x, axis_names, to="varying")

@@ -25,11 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from mlops_tpu.parallel.compat import (
-    LEGACY_SHARD_MAP,
-    pcast_varying,
-    shard_map,
-)
+from mlops_tpu.parallel.compat import pcast_varying, shard_map
 
 
 def pipeline_stage_shard(
@@ -135,10 +131,5 @@ def make_pipeline(
             mesh=mesh,
             in_specs=(P(axis_name), x_spec),
             out_specs=x_spec,
-            # 0.4.x's replication checker cannot type the stage-varying
-            # scan carry, so only THERE is it disabled (correctness is
-            # pinned by the fold-equivalence tests); modern jax accepts
-            # the pcast_varying annotations and keeps its checker on.
-            check_vma=False if LEGACY_SHARD_MAP else None,
         )
     )

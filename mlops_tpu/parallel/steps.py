@@ -16,7 +16,6 @@ import optax
 from jax.sharding import Mesh
 
 from mlops_tpu.config import TrainConfig
-from mlops_tpu.parallel.compat import donation_argnums
 from mlops_tpu.parallel.sharding import batch_sharding, param_shardings, replicated
 from mlops_tpu.train.loop import TrainState, training_loss, update_ema
 
@@ -72,10 +71,7 @@ def make_sharded_train_step(
         step,
         in_shardings=(state_shardings, data_in, data_in, label_in, replicated(mesh)),
         out_shardings=(state_shardings, replicated(mesh)),
-        # Full donation on TPU/GPU and on jax >= 0.5; empty only on the
-        # 0.4.x CPU backend, where a cached donated executable misbehaves
-        # after deserialization (parallel/compat.py).
-        donate_argnums=donation_argnums(0),
+        donate_argnums=(0,),
     )
     return step_fn, state_shardings
 

@@ -951,8 +951,11 @@ def _engine_main(
     tentpole: the supervisor forks a replacement that runs this same
     function against the same shm ring."""
     from mlops_tpu.compilecache.cache import from_config
+    from mlops_tpu.compilecache.location import enable_persistent_cache
     from mlops_tpu.tenancy import TenantRegistry, single_tenant_config
 
+    # Before this process's first compile.
+    enable_persistent_cache(aot_store_on=bool(config.cache.dir))
     serve_cfg = config.serve
     stop = {"flag": False}
 
@@ -964,22 +967,28 @@ def _engine_main(
 
     if tenancy is None:
         tenancy = single_tenant_config(bundle_dir)
-    # Per-replica device assignment (post-review fix): when THIS
-    # process's jax visibility spans enough devices for the whole fleet
-    # (a dev box, the forced-host-device sim — production multi-chip
-    # deployments scope visibility per process instead, making each
-    # replica's device 0 its own chip), replica r takes its own
-    # S-device slice so replicas actually occupy E·S devices instead of
-    # all stacking on device 0. The slice index rides into the AOT
-    # cache key (device_tag), so differently-placed artifacts never
-    # cross-load. With too few visible devices, replicas share the
-    # default device — still useful when dispatches are
-    # latency/transport-bound (the bench's simulated-device framing).
+    # Per-replica device assignment: every replica is its own process
+    # that sees the whole backend, and replica r takes its own S-device
+    # slice of it. The slice index rides into the AOT cache key
+    # (device_tag), so differently-placed artifacts never cross-load. A
+    # replica that cannot have its own slice REFUSES to start — sharing
+    # device 0 in silence would report E replicas while one chip does
+    # the work. (Per-process chip visibility, so each replica owns a
+    # chip outright, is ROADMAP R7.)
     import jax
 
     shards = serve_cfg.model_shards
     device_index: int | None = None
-    if ring.replicas > 1 and jax.device_count() >= ring.replicas * shards:
+    if ring.replicas > 1:
+        needed = ring.replicas * shards
+        if jax.device_count() < needed:
+            raise SystemExit(
+                f"engine replica {replica} cannot have its own device: "
+                f"serve.engine_replicas={ring.replicas} x "
+                f"serve.model_shards={shards} needs {needed} devices, "
+                f"this process sees {jax.device_count()}; refusing to "
+                "share one device between replicas"
+            )
         device_index = replica * shards
         logger.info(
             "engine replica %d pinned to device slice [%d, %d)",

@@ -230,13 +230,8 @@ def make_train_window(
         state, losses = jax.lax.scan(one_step, state, xs=None, length=window)
         return state, losses.mean()
 
-    from mlops_tpu.parallel.compat import donation_argnums
-
-    # Donation gated off only on the 0.4.x CPU backend, where a cached
-    # donated executable silently corrupts its results after
-    # deserialization (parallel/compat.py); everywhere else the train
-    # state updates in place in HBM.
-    return jax.jit(run_window, donate_argnums=donation_argnums(0))
+    # The train state is donated: it updates in place in HBM.
+    return jax.jit(run_window, donate_argnums=(0,))
 
 
 def make_eval_fn(model) -> Callable:
@@ -335,10 +330,7 @@ def fit(
                     # AOT-load the window scan through the persistent
                     # executable cache (entry ``train-step-dense``): repeat
                     # runs of a config deserialize instead of re-tracing +
-                    # re-XLA-compiling per process. On backends where the
-                    # state is donated and a cached donated executable
-                    # misbehaves, the cache layer's capability gate
-                    # bypass-compiles (compilecache/cache.py).
+                    # re-XLA-compiling per process.
                     from mlops_tpu.compilecache.warmup import train_window_job
 
                     run_window = compile_cache.load_or_compile(

@@ -125,13 +125,8 @@ def pretrain_bert(
 
     # The initial carry is never reused after the call: donate it so the
     # params + adam moments update in place in HBM instead of
-    # double-buffering (tpulint TPU105). Gated off on the 0.4.x CPU
-    # backend (cached donated executables misbehave — parallel/compat.py).
-    from mlops_tpu.parallel.compat import donation_argnums
-
-    @partial(
-        jax.jit, static_argnums=1, donate_argnums=donation_argnums(0)
-    )
+    # double-buffering (tpulint TPU105).
+    @partial(jax.jit, static_argnums=1, donate_argnums=(0,))
     def run(carry, n_steps):
         return jax.lax.scan(step, carry, None, length=n_steps)
 

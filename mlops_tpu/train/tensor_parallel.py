@@ -142,9 +142,7 @@ def make_tp_trainer(
         # AOT-load the pjit step through the persistent executable cache
         # (entry ``train-step-tp``), keyed by mesh shape + state/batch
         # signature; any OTHER batch shape falls back to the jitted step
-        # so the cached executable is never fed a novel signature. On
-        # backends where the donated state makes a deserialized executable
-        # unsafe, the cache layer bypass-compiles (compilecache/cache.py).
+        # so the cached executable is never fed a novel signature.
         from mlops_tpu.compilecache.warmup import tp_step_job
 
         batch = config.train.batch_size

@@ -9,14 +9,15 @@ FLOP counts come from XLA's OWN cost model (`compiled.cost_analysis()`),
 not hand-derived formulas — it covers every model family, includes fused
 elementwise work the analytic count would miss, and matches what the
 compiler actually scheduled. Peak FLOP/s is a small device-kind table
-(bf16/f32 matmul peaks from published TPU specs) with an env override
-(``MLOPS_TPU_PEAK_FLOPS``) for kinds the table doesn't know.
+(bf16 matmul peaks from published TPU specs). A device that is not in the
+table is an error, not a default: a made-up or measured-here denominator
+would make the MFU meaningless. CPUs have no published peak and report no
+MFU.
 """
 
 from __future__ import annotations
 
 import logging
-import os
 from typing import Any
 
 import jax
@@ -52,56 +53,42 @@ _DTYPE_SCALE: dict[str, float] = {
 
 
 def peak_flops(device: Any, dtype: str = "bf16") -> float | None:
-    """Best-known peak FLOP/s for ``device`` at executing precision
-    ``dtype`` ("bf16"/"f32"/"int8" and aliases), or None when unknown.
-
-    ``MLOPS_TPU_PEAK_FLOPS`` overrides VERBATIM — no dtype scaling (the
-    user measured it at whatever precision they measured it at; e.g. a
-    CPU's measured GEMM peak, letting CPU bench runs report a real MFU
-    too).
-    """
+    """Published peak FLOP/s for ``device`` at executing precision
+    ``dtype`` ("bf16"/"f32"/"int8" and aliases). None for a CPU (no
+    published peak: callers report no MFU there); any other device kind
+    missing from the table raises."""
     if dtype not in _DTYPE_SCALE:
         raise ValueError(
             f"unknown executing dtype {dtype!r}; expected one of "
             f"{sorted(_DTYPE_SCALE)}"
         )
-    override = os.environ.get("MLOPS_TPU_PEAK_FLOPS")
-    if override:
-        return float(override)
-    kind = getattr(device, "device_kind", "").lower()
+    if getattr(device, "platform", "") == "cpu":
+        return None
+    kind = getattr(device, "device_kind", "")
     for needle, peak in _PEAKS:
-        if needle in kind:
+        if needle in kind.lower():
             return peak * _DTYPE_SCALE[dtype]
-    return None
+    raise ValueError(
+        f"no published peak for device kind {kind!r}; add it, with its "
+        "source, to utils/flops.py _PEAKS"
+    )
 
 
-def compile_with_flops(fn, *args) -> tuple[Any | None, float | None]:
+def compile_with_flops(fn, *args) -> tuple[Any, float | None]:
     """Compile ``fn(*args)`` ONCE; return ``(executable, flops)``.
 
     The executable is directly callable with the same args (so callers can
-    time it without a second ``jax.jit`` compile). Either element is None
-    when that half failed — some plugin backends compile fine but expose
-    no cost analysis.
+    time it without a second ``jax.jit`` compile). A compile error
+    propagates. ``flops`` is None when the backend exposes no cost
+    analysis.
     """
-    compiled = None
-    try:
-        compiled = jax.jit(fn).lower(*args).compile()
-    # Plugin backends raise backend-specific compile errors that share no
-    # base class (XlaRuntimeError, RuntimeError, ValueError, ...); the
-    # contract here is "None when this backend can't compile it", so the
-    # breadth is the point — logged so the cause is never silent.
-    except Exception as err:  # tpulint: disable=TPU201
-        logger.debug("compile for FLOP counting failed: %s", err)
-        return None, None
+    compiled = jax.jit(fn).lower(*args).compile()
     try:
         analysis = compiled.cost_analysis()
-        if isinstance(analysis, (list, tuple)):  # per-device list on old APIs
-            analysis = analysis[0]
         flops = float(analysis.get("flops", 0.0))
         return compiled, (flops if flops > 0 else None)
     except (
         AttributeError,  # backend exposes no cost_analysis / returns None
-        IndexError,  # empty per-device analysis list
         KeyError,
         TypeError,  # non-mapping analysis object
         ValueError,
@@ -116,46 +103,6 @@ def compiled_flops(fn, *args) -> float | None:
     """FLOPs of one call of ``fn(*args)`` per XLA's cost analysis (None
     when unavailable)."""
     return compile_with_flops(fn, *args)[1]
-
-
-def measured_gemm_peak(
-    n: int = 1024, reps: int = 5, dtype: str = "f32"
-) -> float:
-    """Empirical dense-matmul peak of the CURRENT backend (FLOP/s): best
-    of ``reps`` timed ``n×n @ n×n`` matmuls at executing precision
-    ``dtype``. The honest denominator for CPU fallback benches, where no
-    published peak exists — reported MFU then reads "fraction of this
-    host's measured GEMM rate at the SAME precision", which is the
-    comparable quantity to a TPU's spec-sheet peak."""
-    import time
-
-    import jax.numpy as jnp
-
-    jdt = {
-        "bf16": jnp.bfloat16, "bfloat16": jnp.bfloat16,
-        "f32": jnp.float32, "float32": jnp.float32,
-        "int8": jnp.int8,
-    }[dtype]
-    if jdt == jnp.int8:
-        # int8 GEMM accumulates in int32 on every backend that has it.
-        a = jnp.ones((n, n), jnp.int8)
-        b = jnp.ones((n, n), jnp.int8)
-        f = jax.jit(
-            lambda a, b: jax.lax.dot(
-                a, b, preferred_element_type=jnp.int32
-            )
-        )
-    else:
-        a = jnp.ones((n, n), jdt)
-        b = jnp.ones((n, n), jdt)
-        f = jax.jit(lambda a, b: a @ b)
-    jax.block_until_ready(f(a, b))
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        jax.block_until_ready(f(a, b))
-        best = min(best, time.perf_counter() - t0)
-    return 2.0 * n**3 / best
 
 
 def mfu(flops_per_call: float | None, calls_per_s: float, peak: float | None):

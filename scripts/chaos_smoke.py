@@ -176,10 +176,7 @@ raise SystemExit("kill fault did not fire")
 _CACHE_KILL = """
 import jax, jax.numpy as jnp
 from mlops_tpu import faults
-from mlops_tpu.compilecache.cache import (
-    CacheJob, CompileCache, serialization_available)
-if not serialization_available():
-    print("NO-SERIALIZATION"); raise SystemExit(0)
+from mlops_tpu.compilecache.cache import CacheJob, CompileCache
 faults.arm(faults.FaultPlan.from_rules(
     [{"point": "compilecache.persist.midwrite", "mode": "kill"}]))
 CompileCache({cache!r}).load_or_compile(CacheJob(
@@ -191,10 +188,7 @@ raise SystemExit("kill fault did not fire")
 _CACHE_CORRUPT = """
 import numpy as np, jax, jax.numpy as jnp
 from mlops_tpu import faults
-from mlops_tpu.compilecache.cache import (
-    CacheJob, CompileCache, serialization_available)
-if not serialization_available():
-    print("NO-SERIALIZATION"); raise SystemExit(0)
+from mlops_tpu.compilecache.cache import CacheJob, CompileCache
 job = CacheJob(entry_id="chaos", jitted=jax.jit(lambda x: x * 3.0),
                abstract_args=(jax.ShapeDtypeStruct((4,), jnp.float32),))
 CompileCache({cache!r}).load_or_compile(job)  # persist a good artifact
@@ -320,10 +314,6 @@ def midwrite_and_corruption_scenarios(tmp: str) -> None:
         env={**os.environ, "JAX_PLATFORMS": "cpu"},
         capture_output=True, text=True, timeout=600, cwd=REPO,
     )
-    if "NO-SERIALIZATION" in proc.stdout:
-        print("# chaos-smoke: cache scenarios skipped (no serialization)",
-              flush=True)
-        return
     assert proc.returncode == -signal.SIGKILL, (
         proc.returncode, proc.stderr[-1000:]
     )

@@ -1,6 +1,6 @@
 """Equal-budget HPO comparison: successive halving vs random search.
 
-VERDICT r4 #6's "done" evidence: at the SAME total step budget
+The successive-halving evidence: at the SAME total step budget
 (trials x steps), SHA should select a better (or equal) validation AUC
 than random search, because it reallocates most of the budget to the
 candidates that earn it. One JSON line:
@@ -20,10 +20,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from mlops_tpu.commands import _honor_jax_platforms_env  # noqa: E402
-
-_honor_jax_platforms_env()
 
 import numpy as np  # noqa: E402
 

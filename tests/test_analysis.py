@@ -909,9 +909,7 @@ def test_trace_layer_clean_on_registered_entry_points():
 def test_float64_leak_detected():
     from mlops_tpu.analysis.traces import check_dtypes
 
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(lambda x: jnp.sin(x) * 2.0)(
             jax.ShapeDtypeStruct((4,), jnp.float64)
         )

@@ -263,10 +263,6 @@ def test_cache_corrupt_on_read_discards_and_recompiles(tmp_path):
 
     from mlops_tpu.compilecache.cache import CacheJob, CompileCache
 
-    if not __import__("mlops_tpu.compilecache.cache", fromlist=["x"]) \
-            .serialization_available():
-        pytest.skip("no executable serialization on this jaxlib")
-
     def f(x):
         return x * 2.0 + 1.0
 
@@ -309,11 +305,7 @@ def test_cache_persist_midwrite_kill_never_leaves_a_partial_artifact(
     script = textwrap.dedent("""
         import jax, jax.numpy as jnp, sys
         from mlops_tpu import faults
-        from mlops_tpu.compilecache.cache import (
-            CacheJob, CompileCache, serialization_available,
-        )
-        if not serialization_available():
-            print("NO-SERIALIZATION"); raise SystemExit(0)
+        from mlops_tpu.compilecache.cache import CacheJob, CompileCache
         faults.arm(faults.FaultPlan.from_rules(
             [{"point": "compilecache.persist.midwrite", "mode": "kill"}]
         ))
@@ -330,8 +322,6 @@ def test_cache_persist_midwrite_kill_never_leaves_a_partial_artifact(
         env={**os.environ, "JAX_PLATFORMS": "cpu"},
         capture_output=True, text=True, timeout=600, cwd=REPO,
     )
-    if "NO-SERIALIZATION" in proc.stdout:
-        pytest.skip("no executable serialization on this jaxlib")
     assert proc.returncode == -signal.SIGKILL, proc.stderr[-2000:]
     assert list(tmp_path.rglob("*.jaxexe")) == []  # nothing torn landed
 

@@ -24,6 +24,15 @@ def test_native_builds():
     )
 
 
+def test_encoder_status_names_the_encoder_and_the_reason(monkeypatch):
+    assert native.encoder_status() == {"encoder": "c++"}
+    monkeypatch.setattr(native, "_lib_cache", None)
+    monkeypatch.setenv("MLOPS_TPU_NO_NATIVE", "1")
+    status = native.encoder_status()
+    assert status["encoder"] == "python"
+    assert "MLOPS_TPU_NO_NATIVE" in status["reason"]
+
+
 def test_native_matches_python_exactly(csv_file):
     path, columns, labels = csv_file
     prep = Preprocessor.fit(columns)
