@@ -401,40 +401,17 @@ def _score_batch(config) -> int:
         from mlops_tpu.compilecache.cache import from_config
         from mlops_tpu.data.stream import score_csv_stream
 
-        recorder = None
-        stage_sink = None
-        if config.trace.enabled:
-            # tracewire: pipeline stage timings land in the same span
-            # JSONL stream the servers write (kind="stage" records,
-            # docs/observability.md) — the bulk path's half of the
-            # queryable-log story.
-            from pathlib import Path
-
-            from mlops_tpu.trace import TraceRecorder
-
-            config.trace.validate()
-            recorder = TraceRecorder(
-                Path(config.trace.dir) / "spans-bulk.jsonl",
-                capacity=config.trace.ring_capacity,
-                flush_interval_s=config.trace.flush_interval_s,
-            )
-            stage_sink = recorder.stage_sink("score-stream")
         mesh = make_mesh(jax.device_count()) if jax.device_count() > 1 else None
-        try:
-            stats = score_csv_stream(
-                bundle,
-                config.data.train_path,
-                out_path=config.score.output_path or None,
-                chunk_rows=config.score.chunk_rows,
-                mesh=mesh,
-                exact=True if config.score.exact else None,
-                pipeline_depth=config.score.pipeline_depth,
-                compile_cache=from_config(config),
-                stage_sink=stage_sink,
-            )
-        finally:
-            if recorder is not None:
-                recorder.close()
+        stats = score_csv_stream(
+            bundle,
+            config.data.train_path,
+            out_path=config.score.output_path or None,
+            chunk_rows=config.score.chunk_rows,
+            mesh=mesh,
+            exact=True if config.score.exact else None,
+            pipeline_depth=config.score.pipeline_depth,
+            compile_cache=from_config(config),
+        )
         print(json.dumps(stats))
         return 0
     if config.data.train_path:

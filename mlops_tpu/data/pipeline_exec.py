@@ -136,15 +136,16 @@ def run_pipeline(
     depth: int = 4,
     source_name: str = "read",
     sink_name: str = "write",
-    stage_sink: Callable | None = None,
+    span_attrs: dict | None = None,
 ) -> PipelineStats:
     """Stream ``source`` through ``stages`` into ``sink`` (see module
     docstring for the execution model). Returns per-stage timing stats;
-    re-raises the original exception if any stage fails. ``stage_sink``
-    (tracewire — `trace/recorder.TraceRecorder.stage_sink`) additionally
-    streams every completed stage execution into the span JSONL."""
+    re-raises the original exception if any stage fails. With
+    ``span_attrs`` every stage execution is also a ``mlops:pipe.<stage>``
+    span in a profiler trace, carrying them (`utils/timing.py
+    StageClock`)."""
     depth = max(1, int(depth))
-    clock = StageClock(sink=stage_sink)
+    clock = StageClock(span_attrs)
     start = time.perf_counter()
     if depth <= 1:
         items = _run_serial(source, stages, sink, clock, source_name, sink_name)

@@ -381,7 +381,6 @@ def score_csv_stream(
     pipeline_depth: int = 2,
     native: bool | None = None,
     compile_cache=None,
-    stage_sink=None,
 ) -> dict[str, float]:
     """Stream-score a CSV/Parquet of any size through the bundle's fused
     predict.
@@ -403,10 +402,8 @@ def score_csv_stream(
     the pipeline and propagates) never leaves a partial file behind
     looking like a finished run.
 
-    ``stage_sink`` (tracewire): a `TraceRecorder.stage_sink` callable —
-    every stage execution additionally lands as a kind="stage" record in
-    the span JSONL (`mlops-tpu score-batch score.streaming=true
-    trace.enabled=true`).
+    In a profiler trace every stage execution is a ``mlops:pipe.<stage>``
+    span carrying the job's number (`parallel/bulk.py next_job_id`).
     """
     import contextlib
 
@@ -416,6 +413,7 @@ def score_csv_stream(
         make_chunk_scorer,
         make_chunk_transfer,
         mesh_chunk_rows,
+        next_job_id,
         use_distilled_bulk,
     )
 
@@ -565,7 +563,7 @@ def score_csv_stream(
                 ],
                 write_chunk,
                 depth=pipeline_depth,
-                stage_sink=stage_sink,
+                span_attrs={"job": next_job_id()},
             )
         if tmp_path is not None:
             tmp_path.replace(out_path)

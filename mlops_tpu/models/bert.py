@@ -37,6 +37,7 @@ import dataclasses
 from statistics import NormalDist
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
@@ -110,6 +111,10 @@ class TokenLayout:
         return np.asarray([nd.inv_cdf(q) for q in qs], np.float32)
 
 
+# `embed` and `head` are scopes of a device trace only (flax names what is
+# a module; the tokenizer, the embedding front and the read-out as wholes are
+# none): they name no parameter and change no number.
+@jax.named_scope("embed")
 def tokenize(
     cat_ids: jnp.ndarray, numeric: jnp.ndarray, layout: TokenLayout
 ) -> jnp.ndarray:
@@ -138,6 +143,7 @@ def tokenize(
     return jnp.concatenate([cls, pairs, sep], axis=1)
 
 
+@jax.named_scope("embed")
 def apply_embed_front(
     mod: nn.Module,
     tokens: jnp.ndarray,
@@ -162,6 +168,7 @@ def apply_embed_front(
     return nn.LayerNorm(dtype=dtype, name="ln_embed")(x)
 
 
+@jax.named_scope("head")
 def apply_cls_head(
     mod: nn.Module, x: jnp.ndarray, hidden: int, dtype: jnp.dtype
 ) -> jnp.ndarray:

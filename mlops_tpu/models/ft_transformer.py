@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import Callable, Sequence
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -89,17 +90,21 @@ class TransformerBlock(nn.Module):
         )(h, deterministic=not train)
         x = x + nn.Dropout(self.dropout, deterministic=not train)(h)
 
-        h = nn.LayerNorm(dtype=self.dtype)(x)
-        # MLP on [N*S, D]: same params/numerics, but the backward's dW is
-        # a single 2D GEMM instead of a two-contracting-dims dot_general
-        # XLA:CPU can't run fast (see MultiHeadSelfAttention's note).
-        n, s, d = h.shape
-        h = h.reshape(n * s, d)
-        h = nn.Dense(4 * self.token_dim, dtype=self.dtype)(h)
-        h = nn.gelu(h)
-        h = nn.Dropout(self.dropout, deterministic=not train)(h)
-        h = nn.Dense(self.token_dim, dtype=self.dtype)(h)
-        return x + h.reshape(n, s, d)
+        # flax names what is a module (LayerNorm_1, Dense_0) in a device
+        # trace; the FFN half as a whole is no module, so it is named here
+        with jax.named_scope("ffn"):
+            h = nn.LayerNorm(dtype=self.dtype)(x)
+            # MLP on [N*S, D]: same params/numerics, but the backward's dW
+            # is a single 2D GEMM instead of a two-contracting-dims
+            # dot_general XLA:CPU can't run fast (see
+            # MultiHeadSelfAttention's note).
+            n, s, d = h.shape
+            h = h.reshape(n * s, d)
+            h = nn.Dense(4 * self.token_dim, dtype=self.dtype)(h)
+            h = nn.gelu(h)
+            h = nn.Dropout(self.dropout, deterministic=not train)(h)
+            h = nn.Dense(self.token_dim, dtype=self.dtype)(h)
+            return x + h.reshape(n, s, d)
 
 
 def apply_ft_head(mod: nn.Module, x: jnp.ndarray, dtype: jnp.dtype) -> jnp.ndarray:

@@ -288,6 +288,7 @@ def merge_accumulators(
     )
 
 
+@jax.named_scope("drift")  # the scope its operations carry in a device trace
 def drift_scores(
     state: MonitorState,
     cat_ids: jnp.ndarray,
@@ -324,6 +325,7 @@ def drift_scores(
     return 1.0 - jnp.concatenate([cat_p, num_p])
 
 
+@jax.named_scope("outlier")
 def outlier_flags(
     state: MonitorState, numeric: jnp.ndarray, mask: jnp.ndarray | None = None
 ) -> jnp.ndarray:

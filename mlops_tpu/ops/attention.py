@@ -434,6 +434,7 @@ def flash_attention(
 FLASH_MIN_SEQ = 128
 
 
+@jax.named_scope("attend")  # the scope its operations carry in a device trace
 def attend(
     q: jnp.ndarray,
     k: jnp.ndarray,

@@ -15,11 +15,24 @@ Mechanics (scaling-book recipe):
 - batch drift is a *dataset-level* statistic: K-S/chi² over millions of rows
   saturates (any tiny shift -> p≈0), so it is computed once over a bounded
   uniform row sample — same semantics as the serving monitor, bounded cost.
+
+Where a job's time goes (always on): ``BulkScoreResult.phases`` holds the
+seconds of its four phases and ``compile_events`` what it traced, lowered,
+compiled and took from JAX's persistent cache (`compilecache/events.py`).
+In a profiler trace the same phases are ``mlops:bulk.<phase>`` spans
+inside one ``mlops:bulk.job``, the pipeline's stage executions are
+``mlops:pipe.<stage>`` spans on their own threads, and every one of them
+carries the job's ``job`` number, all on the device operations' clock.
+With no profiler session open a span is one flag test.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import itertools
+import os
+import time
 from typing import Any
 
 import jax
@@ -28,6 +41,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from mlops_tpu.bundle.bundle import Bundle
+from mlops_tpu.compilecache.events import CompileCounter, compile_counter
 from mlops_tpu.data.encode import EncodedDataset
 from mlops_tpu.monitor.state import drift_scores, outlier_flags
 from mlops_tpu.parallel.sharding import batch_sharding, replicated
@@ -38,6 +52,32 @@ from mlops_tpu.schema import SCHEMA
 # amortizes the per-fetch round trip while capping in-flight device
 # buffers.
 FETCH_WAVE = 32
+
+# A bulk job's phases, in order: scorer + transfer build; the in-call
+# warm-up call (trace, lower, compile or cache load, one run); the
+# pipelined sweep; the drift sample.
+PHASES = ("build", "warmup", "sweep", "drift")
+
+_JOB_IDS = itertools.count(1)
+
+
+def next_job_id() -> int:
+    """The number of the next bulk job in this process: every span of one
+    job carries it, so spans on the pipeline's threads can be tied to
+    their job."""
+    return next(_JOB_IDS)
+
+
+@contextlib.contextmanager
+def _phase(phases: dict[str, float], name: str, job: int):
+    """One phase of a job: its seconds into ``phases`` and, in a profiler
+    trace, a ``mlops:bulk.<name>`` span over the same statements."""
+    start = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(f"mlops:bulk.{name}", job=job):
+            yield
+    finally:
+        phases[name] = time.perf_counter() - start
 
 
 def mesh_chunk_rows(chunk_rows: int, mesh: Mesh | None) -> int:
@@ -59,13 +99,18 @@ class BulkScoreResult:
     outliers: np.ndarray  # float32 [N]
     feature_drift: dict[str, float]  # per-feature 1 - p_val on the sample
     rows: int
-    elapsed_s: float  # device scoring time (excludes data generation/IO)
+    elapsed_s: float  # the pipelined sweep's wall time: no scorer build, no
+    # warm-up chunk, no drift sample (``phases`` has those)
     path: str = "exact"  # "exact" | "distilled" | "quant" — which params scored
     pipeline: dict[str, Any] | None = None  # per-stage busy/occupancy
     # timings from the streaming executor (None for the empty dataset)
     compile_cache: dict[str, Any] | None = None  # hit/miss/bypass counts +
     # per-program compile vs deserialize wall time (compilecache/cache.py)
     # when the sweep ran against a persistent executable cache
+    phases: dict[str, float] | None = None  # seconds of each of PHASES
+    compile_events: dict[str, Any] | None = None  # what the job traced,
+    # lowered, compiled and loaded (`compilecache/events.py
+    # CompileCounter.delta`); a job that re-traces its chunk program says so
 
     @property
     def rows_per_s(self) -> float:
@@ -92,6 +137,14 @@ class BulkScoreResult:
             **(
                 {"compile_cache": self.compile_cache}
                 if self.compile_cache is not None
+                else {}
+            ),
+            **(
+                {
+                    "phases": {k: round(v, 4) for k, v in self.phases.items()},
+                    "compile_events": self.compile_events,
+                }
+                if self.phases is not None
                 else {}
             ),
         }
@@ -384,112 +437,145 @@ def score_dataset(
             elapsed_s=0.0,
         )
     chunk = mesh_chunk_rows(chunk_rows, mesh)
-    scorer = make_chunk_scorer(
-        bundle, mesh, exact, compile_cache=compile_cache, chunk_rows=chunk,
-        tier=tier,
-    )
-    transfer = make_chunk_transfer(bundle, mesh)
+    job = next_job_id()
+    phases: dict[str, float] = {}
+    counter = compile_counter()
+    traced_before = counter.snapshot()
+    with jax.profiler.TraceAnnotation(
+        "mlops:bulk.job",
+        job=job,
+        pid=os.getpid(),
+        rows=n,
+        chunk_rows=chunk,
+        chunks=-(-n // chunk),
+        path=path,
+    ):
+        with _phase(phases, "build", job):
+            scorer = make_chunk_scorer(
+                bundle, mesh, exact, compile_cache=compile_cache,
+                chunk_rows=chunk, tier=tier,
+            )
+            transfer = make_chunk_transfer(bundle, mesh)
+        predictions = np.empty(n, np.float32)
+        outliers = np.empty(n, np.float32)
 
-    predictions = np.empty(n, np.float32)
-    outliers = np.empty(n, np.float32)
+        # Warm the executable before the timed run. The host tree ensemble
+        # has nothing to compile, so sklearn-flavor warmup scores a single
+        # row.
+        warm_rows = 1 if bundle.flavor == "sklearn" else chunk
+        warm_dtype = np.int8 if bundle.flavor != "sklearn" else np.int32
+        cat0 = np.zeros((chunk, SCHEMA.num_categorical), warm_dtype)
+        num0 = np.zeros((chunk, SCHEMA.num_numeric), np.float32)
+        with _phase(phases, "warmup", job):
+            jax.block_until_ready(
+                scorer(cat0, num0, np.arange(chunk) < warm_rows)[0]
+            )
 
-    # Warm the executable before the timed run. The host tree ensemble has
-    # nothing to compile, so sklearn-flavor warmup scores a single row.
-    warm_rows = 1 if bundle.flavor == "sklearn" else chunk
-    warm_dtype = np.int8 if bundle.flavor != "sklearn" else np.int32
-    cat0 = np.zeros((chunk, SCHEMA.num_categorical), warm_dtype)
-    num0 = np.zeros((chunk, SCHEMA.num_numeric), np.float32)
-    jax.block_until_ready(
-        scorer(cat0, num0, np.arange(chunk) < warm_rows)[0]
-    )
+        narrow = (
+            np.int8 if bundle.flavor != "sklearn" else ds.cat_ids.dtype
+        )  # host trees index with the original ids; device path widens in-jit
+        base_index = np.arange(chunk)
+        full_mask = np.ones(chunk, bool)
 
-    narrow = (
-        np.int8 if bundle.flavor != "sklearn" else ds.cat_ids.dtype
-    )  # host trees index with the original ids; device path widens in-jit
-    base_index = np.arange(chunk)
-    full_mask = np.ones(chunk, bool)
+        def slice_chunk(span):
+            start, stop = span
+            size = stop - start
+            cat = ds.cat_ids[start:stop].astype(narrow)
+            num = ds.numeric[start:stop]
+            if size < chunk:
+                cat = np.pad(cat, ((0, chunk - size), (0, 0)))
+                num = np.pad(num, ((0, chunk - size), (0, 0)))
+                mask = base_index < size
+            else:
+                mask = full_mask
+            return start, stop, cat, num, mask
 
-    def slice_chunk(span):
-        start, stop = span
-        size = stop - start
-        cat = ds.cat_ids[start:stop].astype(narrow)
-        num = ds.numeric[start:stop]
-        if size < chunk:
-            cat = np.pad(cat, ((0, chunk - size), (0, 0)))
-            num = np.pad(num, ((0, chunk - size), (0, 0)))
-            mask = base_index < size
-        else:
-            mask = full_mask
-        return start, stop, cat, num, mask
+        def transfer_chunk(item):
+            start, stop, cat, num, mask = item
+            return (start, stop, *transfer(cat, num, mask))
 
-    def transfer_chunk(item):
-        start, stop, cat, num, mask = item
-        return (start, stop, *transfer(cat, num, mask))
+        def compute_chunk(item):
+            start, stop, cat, num, mask = item
+            return (start, stop, *scorer(cat, num, mask))
 
-    def compute_chunk(item):
-        start, stop, cat, num, mask = item
-        return (start, stop, *scorer(cat, num, mask))
+        def fetch_chunks(items):
+            # Batched fetch: one device_get round trip for everything
+            # already dispatched, instead of one per chunk. The executor
+            # bounds the gather at the queue depth, so in-flight device
+            # buffers stay fixed regardless of dataset size.
+            fetched = jax.device_get(
+                [(probs, flags) for _, _, probs, flags in items]
+            )
+            return [
+                (start, stop, probs, flags)
+                for (start, stop, _, _), (probs, flags) in zip(items, fetched)
+            ]
 
-    def fetch_chunks(items):
-        # Batched fetch: one device_get round trip for everything already
-        # dispatched, instead of one per chunk. The executor bounds the gather at the queue depth, so
-        # in-flight device buffers stay fixed regardless of dataset size.
-        fetched = jax.device_get(
-            [(probs, flags) for _, _, probs, flags in items]
+        def store_chunk(item):
+            start, stop, probs, flags = item
+            size = stop - start
+            predictions[start:stop] = probs[:size]
+            outliers[start:stop] = flags[:size]
+
+        spans = (
+            (start, min(start + chunk, n)) for start in range(0, n, chunk)
         )
-        return [
-            (start, stop, probs, flags)
-            for (start, stop, _, _), (probs, flags) in zip(items, fetched)
-        ]
+        with _phase(phases, "sweep", job):
+            pipe = run_pipeline(
+                spans,
+                [
+                    Stage("slice", slice_chunk),
+                    Stage("transfer", transfer_chunk),
+                    Stage("compute", compute_chunk),
+                    # The fetch stage keeps the old wave semantics: its
+                    # deep input queue lets the compute stage dispatch up
+                    # to FETCH_WAVE chunks ahead (JAX queues the
+                    # copies/kernels asynchronously) and one batched
+                    # device_get drains them — one transport round trip
+                    # per wave instead of per chunk, independent of
+                    # pipeline_depth. batch_max >= 2 also keeps fetch in
+                    # list-in/list-out mode at depth 1 (the gather is
+                    # still at most one item there).
+                    Stage(
+                        "fetch",
+                        fetch_chunks,
+                        batch_max=FETCH_WAVE,
+                        queue_depth=FETCH_WAVE,
+                    ),
+                ],
+                store_chunk,
+                depth=pipeline_depth,
+                source_name="span",
+                sink_name="store",
+                span_attrs={"job": job},
+            )
+        elapsed = pipe.wall_s
 
-    def store_chunk(item):
-        start, stop, probs, flags = item
-        size = stop - start
-        predictions[start:stop] = probs[:size]
-        outliers[start:stop] = flags[:size]
-
-    spans = (
-        (start, min(start + chunk, n)) for start in range(0, n, chunk)
-    )
-    pipe = run_pipeline(
-        spans,
-        [
-            Stage("slice", slice_chunk),
-            Stage("transfer", transfer_chunk),
-            Stage("compute", compute_chunk),
-            # The fetch stage keeps the old wave semantics: its deep input
-            # queue lets the compute stage dispatch up to FETCH_WAVE chunks
-            # ahead (JAX queues the copies/kernels asynchronously) and one
-            # batched device_get drains them — one transport round trip
-            # per wave instead of per chunk, independent of pipeline_depth.
-            # batch_max >= 2 also keeps fetch in list-in/list-out mode at
-            # depth 1 (the gather is still at most one item there).
-            Stage(
-                "fetch",
-                fetch_chunks,
-                batch_max=FETCH_WAVE,
-                queue_depth=FETCH_WAVE,
-            ),
-        ],
-        store_chunk,
-        depth=pipeline_depth,
-        source_name="span",
-        sink_name="store",
-    )
-    elapsed = pipe.wall_s
-
-    # Dataset-level drift on a bounded uniform sample (see module docstring).
-    take = min(n, drift_sample)
-    idx = (
-        np.random.default_rng(seed).choice(n, take, replace=False)
-        if take < n
-        else np.arange(n)
-    )
-    drift = np.asarray(
-        drift_scores(
-            bundle.monitor, ds.cat_ids[idx], ds.numeric[idx], np.ones(take, bool)
-        )
-    )
+        with _phase(phases, "drift", job):
+            # Dataset-level drift on a bounded uniform sample (see module
+            # docstring).
+            take = min(n, drift_sample)
+            idx = (
+                np.random.default_rng(seed).choice(n, take, replace=False)
+                if take < n
+                else np.arange(n)
+            )
+            drift = np.asarray(
+                drift_scores(
+                    bundle.monitor,
+                    ds.cat_ids[idx],
+                    ds.numeric[idx],
+                    np.ones(take, bool),
+                )
+            )
+        compile_events = CompileCounter.delta(traced_before, counter.snapshot())
+        # a marker at the job's end: what the job traced, on the trace's clock
+        with jax.profiler.TraceAnnotation(
+            "mlops:bulk.compile_events",
+            job=job,
+            **{**compile_events, "programs": "|".join(compile_events["programs"])},
+        ):
+            pass
     return BulkScoreResult(
         predictions=predictions,
         outliers=outliers,
@@ -503,4 +589,6 @@ def score_dataset(
         compile_cache=(
             compile_cache.stats() if compile_cache is not None else None
         ),
+        phases=phases,
+        compile_events=compile_events,
     )

@@ -20,6 +20,13 @@ crosses three processes (front end -> shm ring -> engine -> device):
 - `report.py`: the ``mlops-tpu trace-report`` CLI's aggregation —
   p50/p99 per stage per compiled entry from the span JSONL.
 
+The bulk path (`score-batch`) is not traced here. A bulk job times its
+own phases and counts what it re-traced, always (`parallel/bulk.py
+BulkScoreResult.phases` / ``compile_events``, printed in the command's
+summary), and under a `jax.profiler` session it writes ``mlops:bulk.*``
+and ``mlops:pipe.*`` spans into the profiler's trace, on the device
+operations' clock (docs/observability.md "Bulk jobs").
+
 Everything here is jax-free (front-end processes import it) and gated
 behind the ``trace`` config section: disarmed, the serving hot path pays
 one ``is None`` check per request (the faultline discipline — bench pins

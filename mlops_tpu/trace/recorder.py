@@ -77,26 +77,6 @@ class TraceRecorder:
             # its own discipline.
             self._on_drop(1)
 
-    def stage_sink(self, source: str) -> Callable[[str, float, float, int], None]:
-        """A `utils/timing.StageClock` sink: pipeline/bulk stage timings
-        land in the same JSONL stream as request spans (kind="stage"),
-        so trace-report and the jq runbook see one file format."""
-        import time
-
-        def sink(stage: str, start: float, elapsed_s: float, items: int) -> None:
-            self.record(
-                {
-                    "kind": "stage",
-                    "ts": time.time(),
-                    "source": source,
-                    "stage": stage,
-                    "dur_ms": round(elapsed_s * 1e3, 4),
-                    "items": items,
-                }
-            )
-
-        return sink
-
     # ------------------------------------------------------------- writer
     def _drain(self) -> list[dict[str, Any]]:
         with self._lock:

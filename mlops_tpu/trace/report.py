@@ -23,7 +23,7 @@ def load_spans(path: str | Path) -> list[dict[str, Any]]:
     ``spans*.jsonl``, so a multi-worker plane's per-worker files
     aggregate as ONE trace set with no manual concatenation), a glob
     pattern (``traces/spans-w*.jsonl`` — cross-directory sweeps), or a
-    single JSONL file. Non-span records (kind="stage") and torn/garbage
+    single JSONL file. Records of another kind and torn/garbage
     lines are skipped — the report must work on a file mid-append."""
     import glob as _glob
 

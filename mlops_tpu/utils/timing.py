@@ -35,32 +35,43 @@ class StageClock:
     Thread-safe: each stage runs on its own thread, and the executor's
     serial mode shares one clock across all stages on the caller thread.
 
-    ``sink`` (optional) streams every completed stage as a span event —
-    ``sink(name, start_perf_counter, elapsed_s, items)`` — into the
-    tracewire layer (`trace/recorder.py TraceRecorder.stage_sink`), so
-    pipeline/bulk stage timings land in the same queryable JSONL as
-    request spans. Called OUTSIDE the lock; the tracewire sink is a
-    bounded non-blocking enqueue, never I/O on this thread.
+    ``span_attrs`` (optional): with them, every stage execution also runs
+    under a ``jax.profiler.TraceAnnotation`` named ``mlops:pipe.<stage>``
+    carrying ``items`` and these attributes (`parallel/bulk.py` passes
+    its ``job``), on the stage's own thread. With no profiler session open
+    the annotation is inert (one flag test); in a traced run the stage
+    lands on the device operations' clock. Without ``span_attrs`` the
+    clock imports nothing: jax-free callers pass none.
     """
 
-    def __init__(self, sink=None) -> None:
+    def __init__(self, span_attrs: dict | None = None) -> None:
         self._lock = threading.Lock()
         self._busy: dict[str, float] = {}
         self._items: dict[str, int] = {}
-        self._sink = sink
+        self._span_attrs = span_attrs
+        if span_attrs is not None:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
+
+    def _span(self, name: str, items: int):
+        if self._span_attrs is None:
+            return contextlib.nullcontext()
+        return self._annotation(
+            f"mlops:pipe.{name}", items=items, **self._span_attrs
+        )
 
     @contextlib.contextmanager
     def stage(self, name: str, items: int = 1):
-        start = time.perf_counter()
-        try:
-            yield
-        finally:
-            elapsed = time.perf_counter() - start
-            with self._lock:
-                self._busy[name] = self._busy.get(name, 0.0) + elapsed
-                self._items[name] = self._items.get(name, 0) + items
-            if self._sink is not None:
-                self._sink(name, start, elapsed, items)
+        with self._span(name, items):
+            start = time.perf_counter()
+            try:
+                yield
+            finally:
+                elapsed = time.perf_counter() - start
+                with self._lock:
+                    self._busy[name] = self._busy.get(name, 0.0) + elapsed
+                    self._items[name] = self._items.get(name, 0) + items
 
     def report(self, wall_s: float) -> dict[str, dict[str, float]]:
         with self._lock:

@@ -55,6 +55,36 @@ def persistent_cache_off():
         jax.config.update("jax_enable_compilation_cache", old)
 
 
+@contextlib.contextmanager
+def program_spans(profile_dir):
+    """A `jax.profiler` session around the block (the tracers the benchmark
+    harness uses: Python tracer off, host tracer at level 1); afterwards
+    the list it yielded holds every ``mlops:`` span the program wrote into
+    the profile as ``(name, start_ns, end_ns, attributes)``, by start."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    spans: list[tuple] = []
+    jax.profiler.start_trace(str(profile_dir), profiler_options=options)
+    try:
+        yield spans
+    finally:
+        jax.profiler.stop_trace()
+    from jax.profiler import ProfileData
+
+    (found,) = Path(profile_dir).glob("plugins/profile/*/*.xplane.pb")
+    for plane in ProfileData.from_file(str(found)).planes:
+        for line in plane.lines:
+            for event in line.events:
+                if event.name.startswith("mlops:"):
+                    start = int(event.start_ns)
+                    spans.append(
+                        (event.name, start, start + int(event.duration_ns),
+                         dict(event.stats))
+                    )
+    spans.sort(key=lambda span: span[1])
+
+
 @pytest.fixture(scope="session")
 def synth_small():
     from mlops_tpu.data import generate_synthetic

@@ -141,6 +141,10 @@ def test_score_exact_flag_forces_ensemble(
         assert _score_batch(config) == 0
         out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
         assert out["path"] == want
+        # the printed summary says where the job's time went and what it
+        # re-traced (the chunk program, a new jax.jit every job)
+        assert list(out["phases"]) == ["build", "warmup", "sweep", "drift"]
+        assert "fused" in out["compile_events"]["programs"]
 
 
 # Heaviest end-to-end path (~60s serial on CPU): excluded from the

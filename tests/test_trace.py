@@ -547,32 +547,39 @@ def test_trace_config_validation():
     assert TraceConfig(enabled=True).validate().enabled
 
 
-# --------------------------------------------------------- StageClock sink
+# -------------------------------------------------------- StageClock spans
 def test_stage_clock_emits_span_events_to_sink(tmp_path):
+    """The sink is the profiler's own trace: with ``span_attrs`` every
+    stage execution is a ``mlops:pipe.<stage>`` annotation carrying them;
+    without, the clock annotates nothing (and imports nothing)."""
+    from conftest import program_spans
+
     from mlops_tpu.utils.timing import StageClock
 
-    recorder = TraceRecorder(tmp_path / "spans.jsonl")
-    clock = StageClock(sink=recorder.stage_sink("bulk"))
-    with clock.stage("encode", items=3):
-        pass
-    with clock.stage("compute"):
-        pass
-    recorder.close()
-    records = [
-        json.loads(line)
-        for line in (tmp_path / "spans.jsonl").read_text().splitlines()
+    clock, bare = StageClock({"job": 7}), StageClock()
+    with program_spans(tmp_path) as spans:
+        with clock.stage("encode", items=3):
+            pass
+        with clock.stage("compute"):
+            pass
+        with bare.stage("write"):
+            pass
+    assert [(name, attrs) for name, _, _, attrs in spans] == [
+        ("mlops:pipe.encode", {"items": 3, "job": 7}),
+        ("mlops:pipe.compute", {"items": 1, "job": 7}),
     ]
-    assert [r["stage"] for r in records] == ["encode", "compute"]
-    assert all(r["kind"] == "stage" and r["source"] == "bulk" for r in records)
-    assert records[0]["items"] == 3
-    # report() still works with a sink attached (the existing contract).
+    assert all(end >= start for _, start, end, _ in spans)
+    # report() is the same with and without spans (the existing contract).
     assert set(clock.report(1.0)) == {"encode", "compute"}
+    assert bare.report(1.0)["write"]["items"] == 1
 
 
 def test_stream_scoring_emits_stage_records(tiny_pipeline, tmp_path):
-    """The production wiring: `score-batch score.streaming=true` with
-    tracing armed streams every pipeline stage execution into the span
-    JSONL (the bulk path's half of the queryable-log story)."""
+    """The production wiring: `score-batch score.streaming=true` under a
+    profiler session writes every pipeline stage execution into the trace
+    as a ``mlops:pipe.<stage>`` span carrying the job's number."""
+    from conftest import program_spans
+
     from mlops_tpu.bundle import load_bundle
     from mlops_tpu.data import generate_synthetic, write_csv_columns
     from mlops_tpu.data.stream import score_csv_stream
@@ -581,24 +588,18 @@ def test_stream_scoring_emits_stage_records(tiny_pipeline, tmp_path):
     bundle = load_bundle(result.bundle_dir)
     columns, labels = generate_synthetic(400, seed=3)
     write_csv_columns(tmp_path / "in.csv", columns, labels)
-    recorder = TraceRecorder(tmp_path / "spans-bulk.jsonl")
-    stats = score_csv_stream(
-        bundle,
-        tmp_path / "in.csv",
-        tmp_path / "out.csv",
-        chunk_rows=256,
-        pipeline_depth=1,
-        stage_sink=recorder.stage_sink("score-stream"),
-    )
-    recorder.close()
+    with program_spans(tmp_path / "profile") as spans:
+        stats = score_csv_stream(
+            bundle,
+            tmp_path / "in.csv",
+            tmp_path / "out.csv",
+            chunk_rows=256,
+            pipeline_depth=1,
+        )
     assert stats["rows"] == 400
-    records = [
-        json.loads(line)
-        for line in (tmp_path / "spans-bulk.jsonl").read_text().splitlines()
-    ]
-    assert records, "no stage records landed"
-    assert all(
-        r["kind"] == "stage" and r["source"] == "score-stream"
-        and r["dur_ms"] >= 0 for r in records
-    )
-    assert {"encode", "compute"} <= {r["stage"] for r in records}
+    assert spans, "no stage spans landed"
+    stages = {name.removeprefix("mlops:pipe.") for name, *_ in spans}
+    assert {"read", "encode", "transfer", "compute", "fetch", "write"} == stages
+    assert len({attrs["job"] for *_, attrs in spans}) == 1
+    compute = [s for s in spans if s[0] == "mlops:pipe.compute"]
+    assert len(compute) == stats["stages"]["compute"]["items"] == 2
