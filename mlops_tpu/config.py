@@ -74,7 +74,7 @@ class ModelConfig:
     pipeline_stages: int = 0
     # Tensor parallelism (Flax families): lay params out over a
     # ('data','model') mesh with 'model' axis = tensor_parallel, per the
-    # Megatron column/row/head PARAM_RULES (`parallel/sharding.py`);
+    # Megatron column/row PARAM_RULES (`parallel/sharding.py`);
     # the train step is `parallel/steps.py make_sharded_train_step`, the
     # product loop `train/tensor_parallel.py`. 0 = off. The device count
     # must be a multiple of it.

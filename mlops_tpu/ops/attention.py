@@ -4,9 +4,13 @@ The reference has no attention anywhere (sklearn trees only); attention
 enters this framework through the FT-Transformer (BASELINE.json config 3)
 and the BERT stretch config (config 5). Two execution paths:
 
-- ``reference_attention`` — plain jnp einsum softmax; what XLA already fuses
-  well at short sequence (FT-Transformer runs at seq=24 where this is
-  near-roofline).
+- ``dense_attention`` — plain jnp softmax attention, one head at a time
+  over the fused projection's own ``[B, S, 3*H*D]`` layout; the path of
+  every sequence under ``FLASH_MIN_SEQ`` and of every padding mask and
+  weight dropout. ``reference_attention`` is the same mathematics for
+  ``[B, S, H, D]`` arguments (the flash dispatch off-TPU, and the tests'
+  reference). No roofline share of either was ever measured; what a v5e
+  trace shows at S=48 is in PERF.md section 5.
 - ``flash_attention`` — a Pallas TPU kernel with online softmax: Q/K/V are
   streamed through VMEM in (block_q, block_k) tiles, scores never materialize
   in HBM, so activation memory is O(S·D) instead of O(S²). This is the path
@@ -40,6 +44,34 @@ from mlops_tpu.ops.kernel_gate import tpu_kernel_or
 NEG_INF = -1e30
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "rate"))
+def _one_head(q, k, v, mask=None, keep=None, *, scale, rate=0.0):
+    """Softmax attention of one head, ``[B, S, D]`` each -> ``[B, S, D]``:
+    operands in their own dtype into both products, scores cast to f32
+    before scaling and softmax, probabilities cast to ``v.dtype``.
+    ``mask`` is ``[B, S_k]`` (True = attend); ``keep`` is the weight
+    dropout's ``[B, S_q, S_k]`` draw at ``rate``.
+
+    Jitted so that a program traces and lowers it ONCE for all its heads
+    and blocks (144 calls in the bert-base chunk program, which a bulk
+    job re-traces at its start: on a v5e machine's host, trace + lower of
+    that program take 0.53 s with the ``jit`` and 1.10 s without, 0.52 s
+    before the heads were unrolled; PERF.md section 6, PR 26). XLA
+    inlines the calls. The price: the one lowering carries the scope of
+    the FIRST call site, so in a device trace every head's operations
+    read ``.../block_0/MultiHeadSelfAttention_0/attend/...`` whatever
+    block ran them. A reader that folds the block index (``program_trace.py``
+    does) is unharmed; a per-block reading of ``attend`` is not to be had
+    from the trace."""
+    s = jnp.einsum("bqd,bkd->bqk", q, k).astype(jnp.float32) * scale
+    if mask is not None:
+        s = jnp.where(mask[:, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    if keep is not None:
+        p = jnp.where(keep, p / (1.0 - rate), 0.0)
+    return jnp.einsum("bqk,bkd->bqd", p.astype(v.dtype), v)
+
+
 def reference_attention(
     q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, scale: float | None = None
 ) -> jnp.ndarray:
@@ -49,6 +81,57 @@ def reference_attention(
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+
+
+@jax.named_scope("attend")  # the scope its operations carry in a device trace
+def dense_attention(
+    qkv: jnp.ndarray,
+    heads: int,
+    *,
+    mask: jnp.ndarray | None = None,
+    dropout_rate: float = 0.0,
+    dropout_rng: jax.Array | None = None,
+) -> jnp.ndarray:
+    """Dense self-attention over the fused projection as the matmul wrote
+    it: ``qkv`` is ``[B, S, 3*H*D]`` (q, k, v side by side, heads side by
+    side in each), the result ``[B, S, H*D]``, ready for a 2-D output
+    projection. A head is a D-wide slice of the minor axis, read in place
+    by that head's products; the heads' outputs are concatenated on the
+    same axis. Nothing is reshaped to ``[B, S, H, D]`` or transposed to
+    ``[B, H, S, D]``: on a TPU both are physical relayouts that put S or D
+    on the 128 lanes (PERF.md section 6, PR 26). ``mask``: ``[B, S]``,
+    True = attend. ``dropout_rng``: where given, attention-weight dropout
+    at ``dropout_rate``, one draw for all heads."""
+    b, s, width = qkv.shape
+    dim = width // 3
+    d = dim // heads
+    keep = (
+        None
+        if dropout_rng is None
+        else jax.random.bernoulli(
+            dropout_rng, 1.0 - dropout_rate, (heads, b, s, s)
+        )
+    )
+
+    def head(part: int, h: int) -> jnp.ndarray:
+        lo = part * dim + h * d
+        return qkv[:, :, lo : lo + d]
+
+    return jnp.concatenate(
+        [
+            _one_head(
+                head(0, h),
+                head(1, h),
+                head(2, h),
+                mask,
+                None if keep is None else keep[h],
+                scale=1.0 / math.sqrt(d),
+                rate=dropout_rate,
+            )
+            for h in range(heads)
+        ],
+        axis=-1,
+    )
 
 
 # --------------------------------------------------------------------------
@@ -434,6 +517,11 @@ def flash_attention(
 FLASH_MIN_SEQ = 128
 
 
+def wants_flash(seq: int, use_flash: bool | None) -> bool:
+    """The dispatch rule: ``use_flash`` where given, else by length."""
+    return seq >= FLASH_MIN_SEQ if use_flash is None else use_flash
+
+
 @jax.named_scope("attend")  # the scope its operations carry in a device trace
 def attend(
     q: jnp.ndarray,
@@ -447,12 +535,12 @@ def attend(
     TPU and XLA's dense attention on any other platform (`kernel_gate`);
     shorter ones are dense everywhere. ``True`` is the compiled kernel
     unconditionally (a compile error off-TPU), ``False`` dense."""
-    if use_flash is None and q.shape[1] >= FLASH_MIN_SEQ:
-        return tpu_kernel_or(
-            functools.partial(flash_attention, scale=scale),
-            functools.partial(reference_attention, scale=scale),
-            q, k, v,
-        )
+    if not wants_flash(q.shape[1], use_flash):
+        return reference_attention(q, k, v, scale)
     if use_flash:
         return flash_attention(q, k, v, scale)
-    return reference_attention(q, k, v, scale)
+    return tpu_kernel_or(
+        functools.partial(flash_attention, scale=scale),
+        functools.partial(reference_attention, scale=scale),
+        q, k, v,
+    )

@@ -1,7 +1,7 @@
 """Tensor-parallel training as a product configuration.
 
 ``model.tensor_parallel = K`` promotes the DP×TP library step
-(`parallel/steps.py make_sharded_train_step` — Megatron column/row/head
+(`parallel/steps.py make_sharded_train_step` — Megatron column/row
 PARAM_RULES over a ('data','model') mesh) to a first-class training
 config, the way ``pipeline_stages`` promotes GPipe: the CLI `train`
 dispatches here, checkpoints resume onto the mesh layout, and the result

@@ -409,7 +409,7 @@ def test_mlp_engine_serves_through_sharded_params(tiny_pipeline, sample_request)
 def test_moe_large_family_served_sharded_not_replicated(tmp_path):
     """ISSUE 13 acceptance parity pin: a LARGE family (moe) trains,
     bundles, and serves through EXPERT-SHARDED params (stacked [E, ...]
-    expert weights split over the model axis, attention heads too) with
+    expert weights split over the model axis) with
     responses bit-identical to the unsharded engine."""
     import jax
 

@@ -22,7 +22,9 @@ for (`ops/kernel_gate.py`), so the production entry points (`attend`,
 `make_quant_packed_base()`) take their TPU branch here with no steering.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -160,3 +162,199 @@ def test_flagship_packed_predict_compiles_for_v5e(
     )
     text = _compile(make_packed_predict_base(model), *args)
     assert "fusion" in text
+
+
+def _entry_instructions(text: str) -> list[tuple[str, list[int], str]]:
+    """(op, dims, op_name) of every array-valued instruction of the ENTRY
+    computation of an optimised HLO text."""
+    found = []
+    entry = text[text.index("\nENTRY ") :]
+    for line in entry.splitlines()[1:]:
+        m = re.match(
+            r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", line
+        )
+        if not m:
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        found.append(
+            (
+                m.group(2),
+                [int(d) for d in m.group(1).split(",") if d],
+                name.group(1) if name else "",
+            )
+        )
+    return found
+
+
+def _layout_changes(text: str, at_least: int) -> list[tuple[str, list[int], str]]:
+    """The ENTRY computation's `reshape`, `copy` and `transpose`
+    instructions of ``at_least`` elements or more. In optimised HLO a
+    reshape that moves nothing is a `bitcast`; what is still called
+    `reshape` is a physical relayout."""
+    return [
+        i
+        for i in _entry_instructions(text)
+        if i[0] in ("reshape", "copy", "transpose")
+        and math.prod(i[1]) >= at_least
+    ]
+
+
+def test_layout_scan_sees_the_relayout_it_guards_against(
+    one_chip, no_persistent_cache
+):
+    """The scan below is only a guard if it reads this compiler's print:
+    on the form the module had before PR 26 (projection straight to
+    ``[rows, 3, H, D]``, ``[B, S, H, D]`` einsums) it has to find the
+    fusions and the physical `reshape` that a v5e trace timed at 3.5 s
+    of a 12.6 s job."""
+    from mlops_tpu.ops.attention import reference_attention
+
+    rows, seq, dim, heads = 256, 48, 768, 12
+
+    def before(x, wqkv, wout):
+        free = (((1,), (0,)), ((), ()))
+        qkv = jax.lax.dot_general(x.reshape(rows * seq, dim), wqkv, free)
+        qkv = qkv.reshape(rows, seq, 3, heads, dim // heads)
+        out = reference_attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2])
+        return jax.lax.dot_general(
+            out.reshape(rows * seq, heads, dim // heads),
+            wout,
+            (((1, 2), (0, 1)), ((), ())),
+        )
+
+    text = _compile(
+        before,
+        *_on(
+            (
+                S((rows, seq, dim), jnp.bfloat16),
+                S((dim, 3, heads, dim // heads), jnp.bfloat16),
+                S((heads, dim // heads, dim), jnp.bfloat16),
+            ),
+            one_chip,
+        ),
+    )
+    ops = {i[0] for i in _entry_instructions(text)}
+    assert {"fusion", "reshape"} <= ops, ops
+    moved = _layout_changes(text, at_least=rows * seq)
+    assert [m for m in moved if m[0] == "reshape"], moved
+
+
+# One layout through the attention module (PERF.md section 6, PR 26): at
+# the shapes the code runs, the module's activations go from the qkv
+# projection through both products to the out projection with no physical
+# `reshape`. The parent of PR 26 compiled, at these sizes, to two such
+# reshapes a block (the qkv output re-tiled with S on the lanes, and back
+# before `out`), 793 kB of temporaries and 7.8 MB touched a row at the
+# bert-base widths, 0.74 MB touched a row at ft_transformer's.
+@pytest.mark.parametrize(
+    "seq,dim,heads,copies,temp_kb_a_row,touched_mb_a_row",
+    [
+        pytest.param(48, 768, 12, 0, 400, 5.5, id="bert-base-s48"),
+        # dim 64 is under the 128 lanes, so the compiler keeps this model's
+        # activations ROW-minor outside the module and copies at its edges
+        pytest.param(24, 64, 8, 3, 50, 0.65, id="ft-transformer-s24"),
+    ],
+)
+def test_attention_module_keeps_one_layout_on_v5e(
+    one_chip,
+    no_persistent_cache,
+    seq,
+    dim,
+    heads,
+    copies,
+    temp_kb_a_row,
+    touched_mb_a_row,
+):
+    from mlops_tpu.models.ft_transformer import TransformerBlock
+
+    rows = 256
+    block = TransformerBlock(heads=heads, token_dim=dim, dropout=0.1)
+    x = S((rows, seq, dim), jnp.bfloat16)
+    variables = jax.eval_shape(
+        lambda: block.init(
+            jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype), train=False
+        )
+    )
+    compiled = (
+        jax.jit(lambda v, x: block.apply(v, x, train=False))
+        .lower(*_on((variables, x), one_chip))
+        .compile()
+    )
+    # anything of a token's worth of elements a row is an activation; the
+    # 2,304-element bias is reshaped, and that is no relayout to guard
+    text = compiled.as_text()
+    named = [i for i in _entry_instructions(text) if "MultiHeadSelfAttention" in i[2]]
+    assert len(named) >= 2 * heads, len(named)  # the scan reads this print
+    moved = _layout_changes(text, at_least=rows * seq)
+    assert [m for m in moved if m[0] == "reshape"] == [], moved
+    in_module = [m for m in moved if "MultiHeadSelfAttention" in m[2]]
+    assert len(in_module) <= copies, in_module
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp / rows <= temp_kb_a_row * 1e3, temp / rows
+    touched = compiled.cost_analysis()["bytes accessed"]
+    assert touched / rows <= touched_mb_a_row * 1e6, touched / rows
+
+
+def test_block_under_tensor_parallel_rules_on_v5e(topo, no_persistent_cache):
+    """A block on a 4-chip ('model',) mesh, params by `PARAM_RULES`: the
+    FFN is Megatron-split (each chip's widening product makes a quarter of
+    the features, one all-reduce after the narrowing one), and the
+    attention module, which has no heads axis to partition, runs whole on
+    every chip with NO collective under its scope. PR 26 measured the
+    alternative, its kernels sharded on heads: GSPMD gathers them and
+    re-splits every head's slices, 13 all-to-alls a block, and the block
+    is slower on four chips than on one (PERF.md section 6)."""
+    import numpy as np
+    from flax import linen as nn
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from mlops_tpu.models.ft_transformer import TransformerBlock
+    from mlops_tpu.parallel.sharding import param_shardings
+
+    rows, seq, dim, heads = 256, 48, 768, 12
+    mesh = Mesh(np.asarray(topo.devices), ("model",))
+
+    class Encoder(nn.Module):  # the rules key on the models' `block_<i>`
+        @nn.compact
+        def __call__(self, x):
+            return TransformerBlock(
+                heads=heads, token_dim=dim, dropout=0.1, name="block_0"
+            )(x, train=False)
+
+    block = Encoder()
+    x = S((rows, seq, dim), jnp.bfloat16)
+    variables = jax.eval_shape(
+        lambda: block.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype))
+    )
+    placed = jax.tree_util.tree_map(
+        lambda leaf, sharding: S(leaf.shape, leaf.dtype, sharding=sharding),
+        variables,
+        param_shardings(mesh, variables),
+    )
+    replicated = NamedSharding(mesh, P())
+    text = (
+        jax.jit(
+            lambda v, x: block.apply(v, x),
+            out_shardings=replicated,
+        )
+        .lower(placed, S(x.shape, x.dtype, sharding=replicated))
+        .compile()
+        .as_text()
+    )
+    collectives = [
+        line
+        for line in text.splitlines()
+        if re.search(
+            r" (all-gather|all-reduce|all-to-all|collective-permute|"
+            r"reduce-scatter)(-start)?\(",
+            line,
+        )
+    ]
+    assert collectives, "nothing is partitioned"
+    assert not [c for c in collectives if "MultiHeadSelfAttention" in c]
+    # a quarter of the FFN's 4 * dim features a chip
+    assert re.search(
+        rf"bf16\[{rows * seq},{dim}\]\S* (fusion|convolution)\(.*"
+        r"ffn/Dense_0/dot_general",
+        text,
+    ), "the FFN's widening product is not split four ways"
