@@ -34,10 +34,15 @@ class Bundle:
     ``flax`` carries a params pytree for a zoo module; ``sklearn`` carries
     the CPU tree-ensemble floor (BASELINE config 1) — the reference ships
     only the sklearn kind (`02-register-model.ipynb:305-353`); ``doc``
-    carries a long-context document model (``doc_records > 1``,
-    `train/long_context.py`) whose inputs are record HISTORIES
-    ``[D, R, C]`` — it scores offline via ``predict-file``/bulk paths,
-    not the single-record HTTP endpoint.
+    carries a long-context document model (family bert with
+    ``doc_records > 1``: `ModelConfig.reads_documents`,
+    `train/long_context.py`) whose inputs are 3-D record HISTORIES
+    ``[D, R, C]`` with ONE answer a document — it scores offline via
+    ``predict-file``, and `score-batch` and the HTTP endpoint refuse it.
+    Family ``evabyte`` also reads histories (``doc_records`` consecutive
+    rows) but is a ``flax`` bundle: 2-D rows in, an answer for every
+    record, so `score-batch`, ``predict-file`` and `score_dataset` score
+    it like any other (chunks rounded to whole histories).
     """
 
     manifest: dict[str, Any]
@@ -159,7 +164,7 @@ def save_bundle(
     directory.mkdir(parents=True, exist_ok=True)
     if model_config.family in SKLEARN_FAMILIES:
         flavor = "sklearn"
-    elif model_config.doc_records > 1:
+    elif model_config.reads_documents:
         flavor = "doc"
     else:
         flavor = "flax"

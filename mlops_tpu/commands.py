@@ -303,9 +303,10 @@ def _versions(config) -> int:
 
 def _predict_file(config) -> int:
     """Batch-score a schema CSV offline with the full fused predict (works
-    for every bundle flavor — flax on device, sklearn floor on host, and
-    ``doc`` long-context bundles, which group consecutive rows into
-    record histories and emit one prediction per document)."""
+    for every bundle flavor — flax on device, family evabyte's per-record
+    history scorer among them; sklearn floor on host; and ``doc``
+    long-context bundles, which group consecutive rows into record
+    histories and emit one prediction per document)."""
     from mlops_tpu.bundle import load_bundle
     from mlops_tpu.native import encode_csv
     from mlops_tpu.serve import InferenceEngine
@@ -388,9 +389,11 @@ def _score_batch(config) -> int:
     bundle = load_bundle(_resolve_bundle(config))
     if bundle.flavor == "doc":
         raise SystemExit(
-            "doc bundles score record histories via "
-            "`predict-file data.train_path=<history csv>`; the bulk "
-            "scorer's per-record contract does not apply"
+            "doc bundles (family bert with doc_records > 1: 3-D record "
+            "histories in, ONE answer a document) score via `predict-file "
+            "data.train_path=<history csv>`; the bulk scorer's per-record "
+            "contract does not apply. Family evabyte also reads histories "
+            "but answers every record: its (flax) bundles are scored here"
         )
     if config.score.streaming:
         # Out-of-core path (the Spark-scale analogue): the dataset never

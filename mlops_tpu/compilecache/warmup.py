@@ -874,7 +874,10 @@ def _warm_bulk(config, bundle) -> list[CacheJob]:
     mesh = make_mesh(jax.device_count()) if jax.device_count() > 1 else None
     # The SAME rounding rule the scoring paths apply — a divergence here
     # is a guaranteed cache-key miss at run time.
-    chunk = mesh_chunk_rows(config.score.chunk_rows, mesh)
+    model_config = bundle.model_config if bundle is not None else config.model
+    chunk = mesh_chunk_rows(
+        config.score.chunk_rows, mesh, model_config.history_rows
+    )
     jobs = []
     if bundle is not None:
         monitor = bundle.monitor

@@ -40,7 +40,8 @@ class DataConfig:
 
 @dataclasses.dataclass
 class ModelConfig:
-    family: str = "mlp"  # mlp | ft_transformer | moe | linear | bert | gbm | rf
+    family: str = "mlp"  # mlp | ft_transformer | moe | linear | bert |
+    # evabyte | gbm | rf
     hidden_dims: tuple[int, ...] = (256, 256, 128)
     embed_dim: int = 16
     dropout: float = 0.1
@@ -58,11 +59,15 @@ class ModelConfig:
     # bounds mirror the reference's hyperopt space, `01-train-model.ipynb:342-353`)
     n_estimators: int = 300
     max_tree_depth: int = 8
-    # Long-context (family bert): read `doc_records` consecutive records as
-    # ONE document (seq = 2 + 46R tokens) and predict the last record's
-    # default from the history; `seq_parallel` routes attention through the
+    # `doc_records` consecutive records are read as ONE sequence. The
+    # family decides what comes back (`reads_documents`, `history_rows`):
+    # family bert reads a 3-D document (seq = 2 + 46R tokens) and predicts
+    # the LAST record's default from the history (training path
+    # `train/long_context.py`); family evabyte is causal, takes the zoo's
+    # 2-D rows and answers EVERY record, conditioned on the records before
+    # it in its history. `seq_parallel` routes bert's attention through the
     # ppermute ring (`parallel.make_ring_attention`) over the mesh's 'seq'
-    # axis — the training path is `train/long_context.py`.
+    # axis.
     doc_records: int = 1
     seq_parallel: bool = False
     # Pipeline parallelism (families bert / ft_transformer): split the
@@ -79,6 +84,30 @@ class ModelConfig:
     # product loop `train/tensor_parallel.py`. 0 = off. The device count
     # must be a multiple of it.
     tensor_parallel: int = 0
+    # Family evabyte (models/evabyte.py; EVA chunked linear attention,
+    # `ops/eva_attention.py`): the gated FFN's width, the bytes a query
+    # attends exactly (older ones only through chunk summaries), the bytes
+    # a summary stands for, and the rotary base. Hidden size, heads and
+    # depth are `token_dim`, `heads`, `depth`.
+    ffn_dim: int = 11008
+    attn_window: int = 2048
+    attn_chunk: int = 16
+    rope_theta: float = 100000.0
+
+    @property
+    def reads_documents(self) -> bool:
+        """True for the 3-D ``doc`` flavour: ``[D, R, C]`` record histories
+        in, ONE answer a document out (bundle flavour ``doc``; refused by
+        `score-batch` and the serving engine). False for every 2-D,
+        answer-a-row family, evabyte's histories included."""
+        return self.family != "evabyte" and self.doc_records > 1
+
+    @property
+    def history_rows(self) -> int:
+        """Consecutive ROWS a 2-D model reads as one sequence (1 = rows
+        are independent): what a bulk chunk must hold whole
+        (`parallel/bulk.py mesh_chunk_rows`)."""
+        return self.doc_records if self.family == "evabyte" else 1
 
     @property
     def uses_layout_trainer(self) -> bool:
@@ -89,7 +118,7 @@ class ModelConfig:
         return bool(
             self.pipeline_stages
             or self.seq_parallel
-            or self.doc_records > 1
+            or self.reads_documents
             or self.tensor_parallel
         )
 

@@ -417,7 +417,9 @@ def score_csv_stream(
         use_distilled_bulk,
     )
 
-    chunk_rows = mesh_chunk_rows(chunk_rows, mesh)
+    chunk_rows = mesh_chunk_rows(
+        chunk_rows, mesh, bundle.model_config.history_rows
+    )
     # Same routing contract as score_dataset: ``exact=None`` auto-routes
     # through the distilled bulk student on CPU backends; the returned
     # stats carry ``path`` so the substitution is always visible.

@@ -10,6 +10,9 @@ the TPU-native zoo is:
 - ``ft_transformer``  feature-tokenized transformer (BASELINE.json config 3)
 - ``bert``            tabular-as-text BERT encoder with jit-fused
   tokenization (BASELINE.json config 5, the stretch)
+- ``evabyte``         byte-level causal decoder (EVA chunked linear
+  attention) over record HISTORIES rendered as text in-jit: consecutive
+  rows are one history, every record gets its own answer
 
 All families share one calling convention:
 ``model.apply(vars, cat_ids[int32 N,C], numeric[f32 N,M], train=...) ->
@@ -27,12 +30,13 @@ from flax import linen as nn
 from mlops_tpu.config import ModelConfig
 from mlops_tpu.models.bert import BertEncoder
 from mlops_tpu.models.ensemble import DeepEnsemble
+from mlops_tpu.models.evabyte import EvaByteScorer
 from mlops_tpu.models.ft_transformer import FTTransformer
 from mlops_tpu.models.mlp import MLP, LinearModel
 from mlops_tpu.models.moe import MoETransformer
 from mlops_tpu.schema.features import SCHEMA
 
-FAMILIES = ("linear", "mlp", "ft_transformer", "moe", "bert")
+FAMILIES = ("linear", "mlp", "ft_transformer", "moe", "bert", "evabyte")
 
 
 def build_model(config: ModelConfig) -> nn.Module:
@@ -87,6 +91,20 @@ def build_model(config: ModelConfig) -> nn.Module:
             dropout=config.dropout,
             dtype=dtype,
         )
+    if config.family == "evabyte":
+        return EvaByteScorer(
+            cards=SCHEMA.cards,
+            num_numeric=SCHEMA.num_numeric,
+            hidden=config.token_dim,
+            depth=config.depth,
+            heads=config.heads,
+            ffn_dim=config.ffn_dim,
+            window=config.attn_window,
+            chunk=config.attn_chunk,
+            rope_theta=config.rope_theta,
+            records_per_history=config.doc_records,
+            dtype=dtype,
+        )
     from mlops_tpu.models.gbm import SKLEARN_FAMILIES
 
     if config.family in SKLEARN_FAMILIES:
@@ -123,6 +141,7 @@ __all__ = [
     "FAMILIES",
     "BertEncoder",
     "DeepEnsemble",
+    "EvaByteScorer",
     "FTTransformer",
     "LinearModel",
     "MLP",

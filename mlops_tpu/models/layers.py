@@ -43,6 +43,7 @@ class FlatDenseGeneral(nn.Module):
     inputs: tuple[int, ...]
     features: tuple[int, ...]
     dtype: jnp.dtype = jnp.bfloat16
+    use_bias: bool = True  # False: no ``bias`` parameter (models/evabyte.py)
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -53,9 +54,14 @@ class FlatDenseGeneral(nn.Module):
             return nn.linear.default_kernel_init(rng, flat, dtype).reshape(shape)
 
         kernel = self.param("kernel", kernel_init, self.inputs + self.features)
-        bias = self.param("bias", nn.initializers.zeros_init(), self.features)
+        bias = (
+            self.param("bias", nn.initializers.zeros_init(), self.features)
+            if self.use_bias
+            else None
+        )
         x, kernel, bias = promote_dtype(x, kernel, bias, dtype=self.dtype)
-        return x @ kernel.reshape(flat) + bias.reshape(flat[1])
+        out = x @ kernel.reshape(flat)
+        return out if bias is None else out + bias.reshape(flat[1])
 
 
 class MultiHeadSelfAttention(nn.Module):

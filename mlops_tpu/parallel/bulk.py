@@ -80,17 +80,21 @@ def _phase(phases: dict[str, float], name: str, job: int):
         phases[name] = time.perf_counter() - start
 
 
-def mesh_chunk_rows(chunk_rows: int, mesh: Mesh | None) -> int:
-    """THE one chunk-size rounding rule over a data mesh (round UP to the
-    'data' axis, floor one row per shard). score_dataset, the streaming
+def mesh_chunk_rows(
+    chunk_rows: int, mesh: Mesh | None, history_rows: int = 1
+) -> int:
+    """THE one chunk-size rounding rule: round UP to whole histories on
+    every shard of the mesh's 'data' axis (floor: one history per shard).
+    ``history_rows`` is `ModelConfig.history_rows`: 1 for models whose
+    rows are independent, the records of one history for a model that
+    reads consecutive rows as one sequence (family evabyte), so that no
+    chunk and no shard ever cuts a history. score_dataset, the streaming
     scorer (data/stream.py), and the compile-cache warmer
     (compilecache/warmup.py) must all agree, or a pre-warmed
     ``bulk-score-chunk`` artifact's signature never matches the shape the
     run actually dispatches (silent cache miss, full recompile)."""
-    if mesh is None:
-        return max(1, chunk_rows)
-    axis = int(mesh.shape["data"])
-    return max(axis, ((chunk_rows + axis - 1) // axis) * axis)
+    unit = history_rows * (1 if mesh is None else int(mesh.shape["data"]))
+    return max(unit, -(-chunk_rows // unit) * unit)
 
 
 @dataclasses.dataclass
@@ -436,7 +440,9 @@ def score_dataset(
             rows=0,
             elapsed_s=0.0,
         )
-    chunk = mesh_chunk_rows(chunk_rows, mesh)
+    config = bundle.model_config
+    chunk = mesh_chunk_rows(chunk_rows, mesh, config.history_rows)
+    histories = -(-n // config.history_rows)
     job = next_job_id()
     phases: dict[str, float] = {}
     counter = compile_counter()
@@ -449,6 +455,10 @@ def score_dataset(
         chunk_rows=chunk,
         chunks=-(-n // chunk),
         path=path,
+        histories=histories,
+        # the text the model reads of the job, padding apart (0 for a
+        # model that reads no text)
+        bytes=n * getattr(bundle.model, "bytes_per_row", 0),
     ):
         with _phase(phases, "build", job):
             scorer = make_chunk_scorer(

@@ -386,7 +386,7 @@ def _check_layout_knobs(config: Config) -> None:
         "pipeline_stages": bool(config.model.pipeline_stages),
         "tensor_parallel": bool(config.model.tensor_parallel),
         "doc_records>1/seq_parallel": (
-            config.model.doc_records > 1 or config.model.seq_parallel
+            config.model.reads_documents or config.model.seq_parallel
         ),
     }
     active = [name for name, on in knobs.items() if on]

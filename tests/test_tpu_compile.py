@@ -358,3 +358,26 @@ def test_block_under_tensor_parallel_rules_on_v5e(topo, no_persistent_cache):
         r"ffn/Dense_0/dot_general",
         text,
     ), "the FFN's widening product is not split four ways"
+
+
+def test_eva_attention_compiles_for_v5e_at_evabytes_shape(one_chip, no_persistent_cache):
+    """`ops/eva_attention.py` at the shape `evabyte-8l.bulk-hist` runs it:
+    two 16,384-byte histories, 32 heads of 128, window 2048, chunk 16,
+    bfloat16. One head's scores at a time is what has to fit: 32 heads'
+    worth (12.9 GB) would not; the program's temporaries stay a small
+    multiple of one head's 0.4 GB."""
+    from mlops_tpu.ops.eva_attention import eva_attend, eva_prep_kv, rope
+
+    def attention(q, k, v, phi, mu):
+        q, k = rope(q, 100000.0), rope(k, 100000.0)
+        k_sum, v_sum = eva_prep_kv(k, v, phi, mu, 16)
+        return eva_attend(q, k, v, k_sum, v_sum, 2048, 16)
+
+    qkv = S((2, 16384, 32, 128), jnp.bfloat16, sharding=one_chip)
+    vec = S((32, 128), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(attention).lower(qkv, qkv, qkv, vec, vec).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 3 * 2**30, memory.temp_size_in_bytes
+    text = compiled.as_text()
+    assert "while" in text  # the heads are a loop, not 32 unrolled bodies
+    assert text.count("f32[2,8,2048,2944]") >= 1  # one head's joint scores
