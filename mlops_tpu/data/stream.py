@@ -415,6 +415,7 @@ def score_csv_stream(
         mesh_chunk_rows,
         next_job_id,
         use_distilled_bulk,
+        warm_chunk_scorer,
     )
 
     chunk_rows = mesh_chunk_rows(
@@ -437,16 +438,13 @@ def score_csv_stream(
     # convention as score_dataset.
     narrow = None if bundle.flavor == "sklearn" else np.int8
 
-    # Warm the one compiled chunk program before the streamed (and timed)
-    # run, so ``rows_per_s`` measures streaming, not a one-off compile.
-    if bundle.flavor != "sklearn":
-        import jax
-
-        warm_cat = np.zeros((chunk_rows, SCHEMA.num_categorical), np.int8)
-        warm_num = np.zeros((chunk_rows, SCHEMA.num_numeric), np.float32)
-        jax.block_until_ready(
-            score_chunk(warm_cat, warm_num, np.arange(chunk_rows) < 1)[0]
-        )
+    # Make sure the one chunk program is compiled before the streamed (and
+    # timed) run, so ``rows_per_s`` measures streaming, not a one-off
+    # compile: score_dataset's rule, a no-op where an earlier call of this
+    # process compiled it.
+    warm_chunk_scorer(
+        score_chunk, transfer, chunk_rows, host_model=bundle.flavor == "sklearn"
+    )
 
     # Source + encode selection: when the native C++ kernel is available
     # and the input is CSV, the reader yields raw byte blocks and the

@@ -11,6 +11,12 @@ Mechanics (scaling-book recipe):
 - a chunk is padded to a fixed shape and jit'd with `in_shardings` that lay
   rows out over the mesh's 'data' axis; params replicate. XLA inserts the
   (trivially few) collectives; every chunk reuses the same executable.
+- the compiled chunk program outlives the job: ``make_bulk_jit`` and
+  ``make_bulk_quant_jit`` hand out the SAME ``jax.jit`` object for the
+  same program from a small bounded keep (``ChunkProgramKeep``), so a
+  process that scores file after file traces, lowers, loads and warms the
+  program once per signature, not once per job. Weights stay arguments: a
+  new bundle of the same architecture reuses the program.
 - classifier probabilities and outlier flags are exact per row.
 - batch drift is a *dataset-level* statistic: K-S/chi² over millions of rows
   saturates (any tiny shift -> p≈0), so it is computed once over a bounded
@@ -18,7 +24,9 @@ Mechanics (scaling-book recipe):
 
 Where a job's time goes (always on): ``BulkScoreResult.phases`` holds the
 seconds of its four phases and ``compile_events`` what it traced, lowered,
-compiled and took from JAX's persistent cache (`compilecache/events.py`).
+compiled and took from JAX's persistent cache (`compilecache/events.py`),
+with ``chunk_program_reused``: 1 where the job found its chunk program
+compiled for its signature and so warmed nothing.
 In a profiler trace the same phases are ``mlops:bulk.<phase>`` spans
 inside one ``mlops:bulk.job``, the pipeline's stage executions are
 ``mlops:pipe.<stage>`` spans on their own threads, and every one of them
@@ -28,11 +36,14 @@ With no profiler session open a span is one flag test.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import itertools
 import os
+import threading
 import time
+from collections.abc import Callable, Hashable
 from typing import Any
 
 import jax
@@ -42,6 +53,7 @@ from jax.sharding import Mesh
 
 from mlops_tpu.bundle.bundle import Bundle
 from mlops_tpu.compilecache.events import CompileCounter, compile_counter
+from mlops_tpu.compilecache.keys import abstract_signature
 from mlops_tpu.data.encode import EncodedDataset
 from mlops_tpu.monitor.state import drift_scores, outlier_flags
 from mlops_tpu.parallel.sharding import batch_sharding, replicated
@@ -53,10 +65,74 @@ from mlops_tpu.schema import SCHEMA
 # buffers.
 FETCH_WAVE = 32
 
-# A bulk job's phases, in order: scorer + transfer build; the in-call
-# warm-up call (trace, lower, compile or cache load, one run); the
-# pipelined sweep; the drift sample.
+# A bulk job's phases, in order: scorer + transfer build (the chunk
+# program taken from the keep); making sure that program is compiled for
+# the job's signature (`warm_chunk_scorer`: microseconds where it is, else
+# trace, lower, compile or cache load and one run on a chunk of zeros);
+# the pipelined sweep; the drift sample.
 PHASES = ("build", "warmup", "sweep", "drift")
+
+# tpulint Layer-3 manifest: one leaf lock around the keep's table.
+TPULINT_LOCK_ORDER = {"ChunkProgramKeep": ("_lock",)}
+
+# Chunk programs kept compiled from job to job: one per (program body,
+# model architecture, mesh) a process scores with. A process serves one
+# bundle, or a few tenants' bundles; beyond that the least recently used
+# program goes, and a later job of it compiles again.
+KEPT_CHUNK_PROGRAMS = 4
+
+
+@dataclasses.dataclass(eq=False)
+class KeptProgram:
+    """One chunk program of the keep: its ``jax.jit`` object, whose own
+    cache holds the executables, and the signatures it has run to
+    completion (`warm_chunk_scorer` reads and adds them: single set
+    operations, atomic under the GIL)."""
+
+    jitted: Callable
+    compiled_for: set = dataclasses.field(default_factory=set)
+
+
+class ChunkProgramKeep:
+    """The bulk chunk programs this process has built, least recently used
+    out. The key is what a builder closes over, never an array: weights,
+    monitor and temperature are arguments of the program, so every bundle
+    of one architecture shares an entry. Dropping an entry drops the last
+    reference the keep holds to the ``jax.jit`` object, and with it (once
+    no running job holds it) JAX's executables for it."""
+
+    def __init__(self, capacity: int) -> None:
+        self.capacity = capacity
+        self._lock = threading.Lock()
+        self._programs: collections.OrderedDict[Hashable, KeptProgram] = (
+            collections.OrderedDict()
+        )
+
+    def get(self, key: Hashable, build: Callable[[], Callable]) -> KeptProgram:
+        with self._lock:
+            kept = self._programs.get(key)
+            if kept is None:
+                kept = self._programs[key] = KeptProgram(build())
+                while len(self._programs) > self.capacity:
+                    self._programs.popitem(last=False)
+            self._programs.move_to_end(key)
+            return kept
+
+    def holding(self, jitted: Callable) -> KeptProgram | None:
+        """The entry whose program ``jitted`` is; ``None`` for a
+        ``jax.jit`` the keep never held or has dropped."""
+        with self._lock:
+            for kept in self._programs.values():
+                if kept.jitted is jitted:
+                    return kept
+        return None
+
+    def clear(self) -> None:
+        with self._lock:
+            self._programs.clear()
+
+
+CHUNK_PROGRAMS = ChunkProgramKeep(KEPT_CHUNK_PROGRAMS)
 
 _JOB_IDS = itertools.count(1)
 
@@ -104,7 +180,7 @@ class BulkScoreResult:
     feature_drift: dict[str, float]  # per-feature 1 - p_val on the sample
     rows: int
     elapsed_s: float  # the pipelined sweep's wall time: no scorer build, no
-    # warm-up chunk, no drift sample (``phases`` has those)
+    # compile or warm-up chunk, no drift sample (``phases`` has those)
     path: str = "exact"  # "exact" | "distilled" | "quant" — which params scored
     pipeline: dict[str, Any] | None = None  # per-stage busy/occupancy
     # timings from the streaming executor (None for the empty dataset)
@@ -114,7 +190,8 @@ class BulkScoreResult:
     phases: dict[str, float] | None = None  # seconds of each of PHASES
     compile_events: dict[str, Any] | None = None  # what the job traced,
     # lowered, compiled and loaded (`compilecache/events.py
-    # CompileCounter.delta`); a job that re-traces its chunk program says so
+    # CompileCounter.delta`), and ``chunk_program_reused``: 1 where it found
+    # its chunk program compiled for its signature (`warm_chunk_scorer`)
 
     @property
     def rows_per_s(self) -> float:
@@ -209,11 +286,19 @@ def make_chunk_scorer(
     routes the int8/bf16 quant student (``use_quant_bulk``) and, when it
     routes, takes precedence over the exact/distilled pair.
 
+    The jitted program comes from the process's keep (``make_bulk_jit``,
+    ``make_bulk_quant_jit``): a second job of the same architecture and
+    mesh, whatever its bundle's weights, dispatches the executable the
+    first one compiled. The returned scorer carries what
+    ``warm_chunk_scorer`` needs to know whether that has happened:
+    ``kept`` (the keep's entry) and ``avals`` (of variables and monitor).
+
     With ``compile_cache`` + ``chunk_rows``, the chunk program is AOT
     loaded through the persistent executable cache (`compilecache/` entry
-    ``bulk-score-chunk``: deserialize on hit, compile+persist on miss);
-    chunks at any OTHER shape fall back to the jitted program, so the
-    cached executable can never be fed a signature it was not built for.
+    ``bulk-score-chunk``: deserialize on hit, compile+persist on miss)
+    in every job, with the kept jit as the job's ``jitted``; chunks at
+    any OTHER shape fall back to the jitted program, so the cached
+    executable can never be fed a signature it was not built for.
     """
     monitor = bundle.monitor
     temperature = bundle.temperature  # calibration (train/calibrate.py):
@@ -289,24 +374,80 @@ def make_chunk_scorer(
         probs, flags = run(variables, monitor, t, cat, num, mask)
         return probs, flags
 
+    # an AOT executable is loaded anew in every job and runs in the jit's
+    # place: nothing of it is kept, so nothing is recorded
+    score_chunk.kept = CHUNK_PROGRAMS.holding(fn) if aot is None else None
+    score_chunk.avals = abstract_signature((variables, monitor))
     return score_chunk
+
+
+def warm_chunk_scorer(
+    scorer, transfer, chunk_rows: int, host_model: bool = False
+) -> bool:
+    """THE one warm-up rule of the bulk callers (``score_dataset``,
+    `data/stream.py score_csv_stream`): make sure the chunk program is
+    compiled before the timed sweep starts, so that no compile lands in
+    ``elapsed_s`` / ``rows_per_s``. Returns whether it already was.
+
+    A job's signature is its chunk rows and the avals of ``variables`` and
+    ``monitor`` (what `compilecache/warmup.py bulk_chunk_job` lists in
+    ``abstract_args``; temperature and the chunk's columns follow from
+    them). Where the keep's entry has run that signature to completion,
+    nothing is made and nothing runs: the job's first device work is its
+    first real chunk. Otherwise (the first job of a process, another chunk
+    size, a monitor of another reference length, an AOT executable, a
+    scorer that says nothing about itself) one chunk of zeros goes through
+    ``transfer`` (`make_chunk_transfer`) and the scorer, the way every
+    chunk of the sweep will, and the signature is recorded once it has
+    come back.
+    ``host_model``: the sklearn flavour scores on the host and has nothing
+    to compile but the outlier program, so its warm-up scores one row."""
+    kept = getattr(scorer, "kept", None)
+    signature = (chunk_rows, getattr(scorer, "avals", None))
+    if kept is not None and signature in kept.compiled_for:
+        return True
+    cat = np.zeros(
+        (chunk_rows, SCHEMA.num_categorical), np.int32 if host_model else np.int8
+    )
+    num = np.zeros((chunk_rows, SCHEMA.num_numeric), np.float32)
+    mask = np.arange(chunk_rows) < (1 if host_model else chunk_rows)
+    jax.block_until_ready(scorer(*transfer(cat, num, mask))[0])
+    if kept is not None:
+        kept.compiled_for.add(signature)
+    return False
 
 
 def make_bulk_jit(model, mesh: Mesh | None):
     """The jitted (and, with a mesh, data-sharded) bulk chunk program —
     the ONE jit site the compile cache warms (`compilecache/warmup.py
-    bulk_chunk_job`) and ``make_chunk_scorer`` dispatches."""
-    fused = make_bulk_fused(model)
-    if mesh is None:
-        return jax.jit(fused)
+    bulk_chunk_job`) and ``make_chunk_scorer`` dispatches. The SAME
+    ``jax.jit`` object for the same flax module (a dataclass: two
+    ``build_model`` of equal configs are equal and hash alike) and mesh,
+    from the process's keep, so `jax.jit`'s own cache serves every later
+    job: a call with avals it has seen dispatches with no trace, no
+    lowering and no read of the persistent cache. A sharded and an
+    unsharded program never share an entry."""
+
+    def build():
+        fused = make_bulk_fused(model)
+        if mesh is None:
+            return jax.jit(fused)
+        return jax.jit(fused, **_data_parallel(mesh))
+
+    return CHUNK_PROGRAMS.get(("fused", model, mesh), build).jitted
+
+
+def _data_parallel(mesh: Mesh) -> dict[str, Any]:
+    """The chunk programs' shardings under a mesh: variables, monitor and
+    temperature replicate, the chunk's rows (and both answers) lie over
+    'data'."""
     data_in = batch_sharding(mesh)
-    mask_in = batch_sharding(mesh, ndim=1)
+    rows = batch_sharding(mesh, ndim=1)
     rep = replicated(mesh)
-    return jax.jit(
-        fused,
-        in_shardings=(rep, rep, rep, data_in, data_in, mask_in),
-        out_shardings=(batch_sharding(mesh, ndim=1), batch_sharding(mesh, ndim=1)),
-    )
+    return {
+        "in_shardings": (rep, rep, rep, data_in, data_in, rows),
+        "out_shardings": (rows, rows),
+    }
 
 
 def make_bulk_fused(model):
@@ -352,18 +493,17 @@ def make_bulk_quant_jit(mesh: Mesh | None):
     chunk program (whitelisted in `compilecache/registry.py
     CACHED_JIT_BUILDERS`). Data-parallel like the exact path: rows shard
     over 'data', the quant tree replicates (its int8/bf16 leaves are a few
-    KB — replication is free; there is no model axis in this tier)."""
-    fused = make_bulk_quant_fused()
-    if mesh is None:
-        return jax.jit(fused)
-    data_in = batch_sharding(mesh)
-    mask_in = batch_sharding(mesh, ndim=1)
-    rep = replicated(mesh)
-    return jax.jit(
-        fused,
-        in_shardings=(rep, rep, rep, data_in, data_in, mask_in),
-        out_shardings=(batch_sharding(mesh, ndim=1), batch_sharding(mesh, ndim=1)),
-    )
+    KB — replication is free; there is no model axis in this tier). Kept
+    from job to job like `make_bulk_jit`'s: the body closes over nothing,
+    so the mesh is the whole key."""
+
+    def build():
+        fused = make_bulk_quant_fused()
+        if mesh is None:
+            return jax.jit(fused)
+        return jax.jit(fused, **_data_parallel(mesh))
+
+    return CHUNK_PROGRAMS.get(("quant_fused", None, mesh), build).jitted
 
 
 def make_chunk_transfer(bundle: Bundle, mesh: Mesh | None):
@@ -469,16 +609,9 @@ def score_dataset(
         predictions = np.empty(n, np.float32)
         outliers = np.empty(n, np.float32)
 
-        # Warm the executable before the timed run. The host tree ensemble
-        # has nothing to compile, so sklearn-flavor warmup scores a single
-        # row.
-        warm_rows = 1 if bundle.flavor == "sklearn" else chunk
-        warm_dtype = np.int8 if bundle.flavor != "sklearn" else np.int32
-        cat0 = np.zeros((chunk, SCHEMA.num_categorical), warm_dtype)
-        num0 = np.zeros((chunk, SCHEMA.num_numeric), np.float32)
         with _phase(phases, "warmup", job):
-            jax.block_until_ready(
-                scorer(cat0, num0, np.arange(chunk) < warm_rows)[0]
+            reused = warm_chunk_scorer(
+                scorer, transfer, chunk, host_model=bundle.flavor == "sklearn"
             )
 
         narrow = (
@@ -578,7 +711,10 @@ def score_dataset(
                     np.ones(take, bool),
                 )
             )
-        compile_events = CompileCounter.delta(traced_before, counter.snapshot())
+        compile_events = {
+            **CompileCounter.delta(traced_before, counter.snapshot()),
+            "chunk_program_reused": int(reused),
+        }
         # a marker at the job's end: what the job traced, on the trace's clock
         with jax.profiler.TraceAnnotation(
             "mlops:bulk.compile_events",

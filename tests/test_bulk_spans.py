@@ -1,9 +1,12 @@
 """A bulk job says where its time went, and what it re-traced, from inside
 the program (ISSUE 25): ``mlops:bulk.*`` and ``mlops:pipe.*`` spans in a
 profiler trace, `BulkScoreResult.phases` and ``compile_events`` always,
-device scopes on what flax does not name. All on the CPU: what is asserted
-is what the program writes, never a time."""
+device scopes on what flax does not name. And it re-traces nothing it has
+compiled before (ISSUE 28): the chunk program is kept from job to job.
+All on the CPU: what is asserted is what the program writes, never a
+time."""
 
+import functools
 import math
 import time
 
@@ -19,38 +22,53 @@ from mlops_tpu.config import ModelConfig
 from mlops_tpu.data.encode import EncodedDataset, Preprocessor
 from mlops_tpu.models import build_model, init_params
 from mlops_tpu.monitor.state import fit_monitor
-from mlops_tpu.parallel.bulk import PHASES, make_bulk_jit, score_dataset
+from mlops_tpu.parallel import bulk
+from mlops_tpu.parallel.bulk import (
+    CHUNK_PROGRAMS,
+    PHASES,
+    make_bulk_jit,
+    score_dataset,
+)
 from mlops_tpu.schema import SCHEMA
 
 ROWS, CHUNK = 700, 256  # 2 whole chunks and a padded tail
 CHUNKS = math.ceil(ROWS / CHUNK)
 
 
+TINY_BERT = ModelConfig(family="bert", token_dim=32, depth=2, heads=2)
+
+
+def _bundle(ds, config=TINY_BERT, weights_seed=0, reference_rows=ROWS):
+    """A bundle made by hand (no training run): a NEW model object of
+    ``config`` every time, weights from ``weights_seed``, a monitor fitted
+    on the first ``reference_rows`` rows."""
+    model = build_model(config)
+    zeros = np.zeros(SCHEMA.num_numeric, np.float32)
+    return Bundle(
+        manifest={"flavor": "flax", "model_config": {},
+                  "calibration": {"temperature": 1.5}},
+        model=model,
+        variables=init_params(model, jax.random.PRNGKey(weights_seed)),
+        preprocessor=Preprocessor(zeros, zeros, zeros + 1, SCHEMA.fingerprint()),
+        monitor=fit_monitor(ds.slice(np.arange(reference_rows))),
+    )
+
+
 @pytest.fixture(scope="module")
 def tiny_bert():
-    """A 2-layer `bert` bundle made by hand (no training run), and rows."""
+    """A 2-layer `bert` bundle, and rows."""
     rng = np.random.default_rng(0)
     cat = np.stack([rng.integers(0, c, ROWS) for c in SCHEMA.cards], 1)
     ds = EncodedDataset(
         cat.astype(np.int32),
         rng.normal(size=(ROWS, SCHEMA.num_numeric)).astype(np.float32),
     )
-    model = build_model(ModelConfig(family="bert", token_dim=32, depth=2, heads=2))
-    zeros = np.zeros(SCHEMA.num_numeric, np.float32)
-    bundle = Bundle(
-        manifest={"flavor": "flax", "model_config": {},
-                  "calibration": {"temperature": 1.5}},
-        model=model,
-        variables=init_params(model, jax.random.PRNGKey(0)),
-        preprocessor=Preprocessor(zeros, zeros, zeros + 1, SCHEMA.fingerprint()),
-        monitor=fit_monitor(ds),
-    )
-    return bundle, ds
+    return _bundle(ds), ds
 
 
-def _job(bundle, ds, depth=2):
+def _job(bundle, ds, depth=2, chunk_rows=CHUNK):
     start = time.perf_counter()
-    result = score_dataset(bundle, ds, chunk_rows=CHUNK, exact=True,
+    result = score_dataset(bundle, ds, chunk_rows=chunk_rows, exact=True,
                            pipeline_depth=depth)
     return result, time.perf_counter() - start
 
@@ -155,15 +173,21 @@ def test_phases_sum_to_the_jobs_wall_time(traced_jobs, which):
     assert summary["compile_events"] == result.compile_events
 
 
-def job_names_its_chunk_program(traced_jobs, which):
+def job_reuses_its_chunk_program(traced_jobs, which):
     events = traced_jobs["jobs"][which][0].compile_events
-    # the per-job jax.jit: every job re-traces its chunk program
-    assert events["programs_traced"] >= 1 and "fused" in events["programs"]
-    assert events["trace_s"] > 0 and events["lower_s"] > 0
+    # both follow an untraced job of the same bundle: the chunk program is
+    # kept, so neither traces, lowers nor compiles it again
+    assert events["chunk_program_reused"] == 1
+    assert "fused" not in events["programs"]
+    assert events["lower_s"] == 0 and events["backend_compile_s"] == 0
     marker = _named(traced_jobs["spans"], "mlops:bulk.compile_events")[which][3]
+    assert marker["chunk_program_reused"] == 1
     assert marker["programs_traced"] == events["programs_traced"]
     assert marker["programs"].split("|") == events["programs"]
-    assert marker["trace_s"] == pytest.approx(events["trace_s"])
+    for key in ("trace_s", "lower_s", "backend_compile_s", "cache_retrieval_s"):
+        assert marker[key] == pytest.approx(events[key])
+    assert (marker["cache_hits"], marker["cache_misses"]) == (
+        events["cache_hits"], events["cache_misses"])
 
 
 def process_wide_sums_are_the_jobs_deltas(traced_jobs, _):
@@ -175,12 +199,142 @@ def process_wide_sums_are_the_jobs_deltas(traced_jobs, _):
 
 
 @pytest.mark.parametrize("check,which", [
-    (job_names_its_chunk_program, 0),
-    (job_names_its_chunk_program, 1),
+    (job_reuses_its_chunk_program, 0),
+    (job_reuses_its_chunk_program, 1),
     (process_wide_sums_are_the_jobs_deltas, None),
 ], ids=["first_job", "second_job", "process_wide_sums"])
 def test_compile_events(traced_jobs, check, which):
     check(traced_jobs, which)
+
+
+# ------------------------------------------- the chunk program is kept
+@pytest.fixture
+def watched(monkeypatch):
+    """What a job does where: the compile counter's delta around its
+    warm-up and around its sweep, and how often its scorer is called."""
+    from mlops_tpu.data import pipeline_exec
+
+    counter = compile_counter()
+    seen = {"calls": 0}
+
+    def around(name, fn):
+        def watched_fn(*args, **kwargs):
+            before = counter.snapshot()
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                seen[name] = counter.delta(before, counter.snapshot())
+        return watched_fn
+
+    make = bulk.make_chunk_scorer
+
+    def counted(*args, **kwargs):
+        scorer = make(*args, **kwargs)
+
+        @functools.wraps(scorer)  # what the scorer says of itself goes along
+        def score_chunk(cat, num, mask):
+            seen["calls"] += 1
+            return scorer(cat, num, mask)
+        return score_chunk
+
+    monkeypatch.setattr(bulk, "make_chunk_scorer", counted)
+    monkeypatch.setattr(
+        bulk, "warm_chunk_scorer", around("warmup", bulk.warm_chunk_scorer))
+    monkeypatch.setattr(
+        pipeline_exec, "run_pipeline", around("sweep", pipeline_exec.run_pipeline))
+    return seen
+
+
+def _compiled_inside_warmup(result, seen):
+    """The job compiled its chunk program, all of it before the sweep."""
+    events = result.compile_events
+    assert events["chunk_program_reused"] == 0 and "fused" in events["programs"]
+    assert "fused" in seen["warmup"]["programs"]
+    assert seen["warmup"]["lower_s"] > 0
+    # the phase holds the compile: JAX times it inside the warm-up call
+    assert result.phases["warmup"] >= sum(
+        seen["warmup"][key] for key in ("trace_s", "lower_s", "backend_compile_s"))
+    _sweep_compiled_nothing(seen)
+
+
+def _sweep_compiled_nothing(seen):
+    """``elapsed_s`` holds no trace, lowering or compile."""
+    sweep = seen["sweep"]
+    assert sweep["programs"] == [] and sweep["programs_traced"] == 0
+    assert sweep["lower_s"] == 0 and sweep["backend_compile_s"] == 0
+
+
+def test_first_job_of_a_new_model_compiles_inside_warmup(tiny_bert, watched):
+    _, ds = tiny_bert
+    CHUNK_PROGRAMS.clear()  # whatever ran before: this process has not seen it
+    result, _ = _job(_bundle(ds), ds)
+    _compiled_inside_warmup(result, watched)
+    assert watched["calls"] == 1 + CHUNKS  # a chunk of zeros, then the sweep
+
+
+def test_other_weights_reuse_the_program_and_answer_the_same_bits(
+    tiny_bert, watched
+):
+    bundle, ds = tiny_bert
+    first, _ = _job(bundle, ds)
+    watched["calls"] = 0
+    other = _bundle(ds, weights_seed=1)  # a promoted model: a new module
+    assert other.model is not bundle.model and other.model == bundle.model
+    reused, _ = _job(other, ds)
+    assert reused.compile_events["chunk_program_reused"] == 1
+    assert "fused" not in reused.compile_events["programs"]
+    assert watched["warmup"]["programs"] == []
+    _sweep_compiled_nothing(watched)
+    assert watched["calls"] == CHUNKS, "no run of the program before the sweep"
+    assert np.abs(reused.predictions - first.predictions).max() > 1e-3
+    CHUNK_PROGRAMS.clear()  # a jax.jit made for this bundle alone
+    fresh, _ = _job(other, ds)
+    assert fresh.compile_events["chunk_program_reused"] == 0
+    np.testing.assert_array_equal(reused.predictions, fresh.predictions)
+    np.testing.assert_array_equal(reused.outliers, fresh.outliers)
+    assert reused.feature_drift == fresh.feature_drift
+
+
+@pytest.mark.parametrize("what", ["chunk_rows", "reference_length"])
+def test_a_new_signature_compiles_inside_warmup_never_inside_sweep(
+    tiny_bert, watched, what
+):
+    bundle, ds = tiny_bert
+    _job(bundle, ds)
+    if what == "chunk_rows":
+        job = functools.partial(_job, bundle, ds, chunk_rows=CHUNK // 2)
+    else:  # a monitor of another reference length: other avals
+        shorter = _bundle(ds, reference_rows=ROWS // 2)
+        assert (shorter.monitor.num_ref_sorted.shape
+                != bundle.monitor.num_ref_sorted.shape)
+        job = functools.partial(_job, shorter, ds)
+    result, _ = job()
+    _compiled_inside_warmup(result, watched)
+    again, _ = job()
+    assert again.compile_events["chunk_program_reused"] == 1
+    _sweep_compiled_nothing(watched)
+    # and the first signature is still compiled: one jit, two executables
+    back, _ = _job(bundle, ds)
+    assert back.compile_events["chunk_program_reused"] == 1
+    np.testing.assert_array_equal(result.predictions, again.predictions)
+
+
+def test_a_reused_job_launches_its_chunks_and_nothing_before(
+    tiny_bert, watched, tmp_path
+):
+    bundle, ds = tiny_bert
+    _job(bundle, ds)
+    watched["calls"] = 0
+    with program_spans(tmp_path) as spans:
+        result, _ = _job(bundle, ds)
+    assert result.compile_events["chunk_program_reused"] == 1
+    assert watched["calls"] == CHUNKS
+    compute = _named(spans, "mlops:pipe.compute")
+    (warmup,) = _named(spans, "mlops:bulk.warmup")
+    (sweep,) = _named(spans, "mlops:bulk.sweep")
+    assert len(compute) == CHUNKS
+    assert warmup[2] <= sweep[1] <= min(span[1] for span in compute)
+    assert tuple(result.phases) == PHASES
 
 
 def test_nested_traces_are_counted_once():
