@@ -26,7 +26,7 @@ package keeps the codebase honest on every PR:
   ``analyze --concurrency`` (CI runs it). The RUNTIME half (`lockcheck`)
   swaps real locks for instrumented wrappers in tests: per-thread
   acquisition stacks asserted against the same declared order, lock-wait
-  accounting (bench's ``lock_wait_ms``), and seeded schedule perturbation.
+  accounting (``total_wait_ms``), and seeded schedule perturbation.
 - **Layer 4** (`contracts` + `seriesreg`): cross-process CONTRACT rules,
   analyzed project-wide rather than per file — shm ring fields checked
   against the declared writer-role manifest (``TPULINT_SHM_OWNERSHIP``),

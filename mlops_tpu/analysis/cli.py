@@ -57,7 +57,7 @@ def _run_analyze(args: argparse.Namespace) -> int:
 
     # Per-layer wall time, reported under --strict: the gate grows a
     # layer per review epoch, and a slow layer should show up in CI
-    # output (and bench.py's analysis_wall_s), not in folklore.
+    # output, not in folklore.
     from time import perf_counter
 
     timings: list[tuple[str, float]] = []

@@ -12,17 +12,16 @@ wrappers that
   two checks can never disagree about intent) — violations are collected,
   never raised mid-test, so the assertion happens once at the end with the
   full evidence;
-- account blocked time per lock (``total_wait_ms`` — `bench.py` exports it
-  as the ``lock_wait_ms`` satellite key so contention regressions show in
-  the BENCH_* trajectory);
+- account blocked time per lock (``total_wait_ms``): near zero when
+  uncontended, so a lock held across blocking work shows as soon as
+  anything else wants it;
 - optionally perturb the schedule: a seeded random pre-acquire delay
   shifts thread interleavings run to run, so three seeds explore three
   schedules while the deterministic stage graphs must still produce
   BIT-IDENTICAL outputs (`tests/test_batcher.py`,
   `tests/test_pipeline_exec.py`).
 
-No JAX import — usable on any machine, including inside `bench.py` before
-a backend exists.
+No JAX import — usable on any machine, before a backend exists.
 """
 
 from __future__ import annotations

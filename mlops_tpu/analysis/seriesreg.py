@@ -12,9 +12,8 @@ source itself: f-strings in every function reachable from each declared
 plane root are reconstructed (formatted values become ``\\x00``
 placeholders), scanned for ``# TYPE`` declarations, ``name{label="..."}``
 emissions and bare-name emissions, and folded into one registry the
-Layer-4 contract rules (TPU502, `analysis/contracts.py`) and the bench
-gate (`scripts/bench_check.py`) both consume — the static and CI halves
-can never disagree about which series exist.
+Layer-4 contract rules (TPU502, `analysis/contracts.py`) consume, the
+check of the committed alert rules among them.
 
 Declarations are plain literals in the renderer module (`serve/metrics.py`),
 read from source and never imported:
@@ -319,9 +318,9 @@ def build_registry(
 def registry_from_paths(
     paths: Iterable[str | Path],
 ) -> SeriesRegistry | None:
-    """Registry over every ``.py`` under ``paths`` — the entry point
-    `scripts/bench_check.py` uses to validate the committed alert rules
-    against the renderers actually shipped."""
+    """Registry over every ``.py`` under ``paths`` — the entry point for
+    validating the committed alert rules against the renderers actually
+    shipped (`tests/test_analysis.py`)."""
     from mlops_tpu.analysis.astrules import iter_py_files
     from mlops_tpu.analysis.findings import file_skipped
 

@@ -1,4 +1,4 @@
-"""Command-line entry point: train | tune | register | serve | bench | predict-file.
+"""Command-line entry point: train | tune | register | serve | predict-file.
 
 Replaces the reference's operational surface (Databricks bundle job runs,
 `databricks bundle run train_register_model_job` — `deploy-kubernetes.yml:61`
@@ -40,7 +40,6 @@ def build_parser() -> argparse.ArgumentParser:
                       "(lifecycle.labeled_path), grade it against the "
                       "incumbent through the AUC/calibration gates, and "
                       "register it when it passes"),
-        ("bench", "run the inference benchmark"),
         ("predict-file", "batch-score a CSV offline"),
         ("score-batch", "bulk-score 1M-scale rows data-parallel over the mesh"),
         ("warmup", "pre-populate the AOT executable cache (compilecache/) "

@@ -465,18 +465,6 @@ def _score_batch(config) -> int:
     return 0
 
 
-def _bench(config) -> int:
-    """Run the repo-root inference benchmark (the driver's headline number)."""
-    import runpy
-    from pathlib import Path
-
-    for candidate in (Path.cwd() / "bench.py", Path(__file__).parents[1] / "bench.py"):
-        if candidate.is_file():
-            runpy.run_path(str(candidate), run_name="__main__")
-            return 0
-    raise SystemExit("bench.py not found (run from the repo root)")
-
-
 def _looks_like_dir(value: str) -> bool:
     from pathlib import Path
 
@@ -943,7 +931,6 @@ _HANDLERS = {
     "validate": _validate,
     "predict-file": _predict_file,
     "score-batch": _score_batch,
-    "bench": _bench,
     "serve": _serve,
     "lifecycle": _lifecycle,
     "autotune": _autotune,

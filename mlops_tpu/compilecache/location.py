@@ -6,7 +6,7 @@ Two stores share one root:
   the root itself;
 - this repo's AOT executable store (`cache.py`, ``cache.dir``), which is
   off unless a caller switches it on, at ``<root>/aot-executables`` when
-  chip_smoke.py or bench.py do.
+  chip_smoke.py does.
 
 The root is ``$JAX_COMPILATION_CACHE_DIR`` where that is set — JAX reads
 the variable itself, and this module then sets no directory at all — and
@@ -15,7 +15,7 @@ temporary name, a pid or a time: the path is part of JAX's cache key, so
 a directory that moves never hits.
 
 `enable_persistent_cache` is called before the first compile by the CLI
-entry (`commands.py`), bench.py, chip_smoke.py's children and
+entry (`commands.py`), chip_smoke.py's children and
 tests/conftest.py. This module imports jax only inside that function, so
 jax-free parents (chip_smoke.py, the serve supervisor) can ask for the
 paths.

@@ -323,7 +323,7 @@ class ServeConfig:
     # process is down and the parking partition is full — the estimated
     # detect -> fork -> cached-warmup -> replay wall time, minus however
     # long the engine has already been down. Tune to the measured warm
-    # re-attach on the deployment box (bench: engine_respawn_gap_ms);
+    # re-attach on the deployment box;
     # too low hammers retries into the still-full parking lot, too high
     # parks well-behaved clients longer than the outage
     engine_replicas: int = 1  # engine replica set (ISSUE 13,
@@ -747,8 +747,7 @@ class TraceConfigError(ValueError):
 class TraceConfig:
     """tracewire (`mlops_tpu/trace/`): end-to-end request tracing +
     shape/goodput telemetry on both serving planes. Disabled by default —
-    disarmed, the hot path pays one ``is None`` check per request (bench
-    pins ``trace_overhead_pct`` ~0 disarmed, <= 2 armed)."""
+    disarmed, the hot path pays one ``is None`` check per request."""
 
     enabled: bool = False
     dir: str = "traces"  # span JSONL root: the single-process server
@@ -803,8 +802,7 @@ class SLOConfig:
     """sloscope (`mlops_tpu/slo/`): SLO/error-budget accounting with
     multi-window multi-burn-rate alerts, the anomaly-triggered flight
     recorder, and the per-entry device-time cost ledger. Disabled by
-    default — disarmed, every hot path pays one ``is None`` check
-    (bench key ``slo_overhead_pct``)."""
+    default — disarmed, every hot path pays one ``is None`` check."""
 
     enabled: bool = False
     # ------------------------------------------------------------- targets

@@ -454,7 +454,8 @@ def score_csv_stream(
     # would otherwise serialize the whole pipeline on the GIL). Output is
     # parity-pinned bit-identical to the Python path (tests/test_native.py).
     # ``native=None`` auto-detects; ``False`` forces the Python csv parse
-    # (the pre-executor serial baseline — bench uses it for before/after).
+    # (the pre-executor serial baseline, the reference of
+    # tests/test_native.py and tests/test_pipeline_exec.py).
     from mlops_tpu.data import parquet
     from mlops_tpu.native import encode_csv_bytes, native_available
 

@@ -31,8 +31,7 @@ Arming:
   var, no code changes.
 
 Zero overhead disarmed: the module-level plan is ``None`` and both
-entry points return after one global load + identity check — the bench
-pins the armed-off cost as ``fault_overhead_pct`` (~0). The module
+entry points return after one global load + identity check. The module
 imports no jax and starts no threads.
 
 TOML plan format (``[[fault]]`` tables, see docs/operations.md):

@@ -174,7 +174,7 @@ class LifecycleController:
     # ------------------------------------------------------------- run_once
     def run_once(self, now: float | None = None) -> dict:
         """One controller step: drain observations, then at most one
-        state-machine transition. Tests and the bench drive this
+        state-machine transition. Tests drive this
         directly; the background loop calls it every tick."""
         now = self._clock() if now is None else now
         self._drain_observations()

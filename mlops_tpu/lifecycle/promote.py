@@ -131,8 +131,8 @@ def quant_tier_gates(
     quant manifest block, and `serve/engine.py` refuses to serve (or
     auto-route to) a quant tier whose stamped decision failed — the gate
     runs once where the labels are, not on every engine boot. Latency has
-    no gate here: the tier exists to be faster, and the bench round
-    measures it directly."""
+    no gate here: the tier exists to be faster, and no cell of the
+    benchmark measures it yet (ROADMAP C6)."""
     reasons: list[str] = []
     delta = fidelity.get("roc_auc_delta")
     if delta is None:

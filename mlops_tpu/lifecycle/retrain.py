@@ -59,7 +59,7 @@ class SampleReservoir:
 
     Thread-safe: ``add_batch`` is called from the controller's drain of
     the tee queue (one thread in production), but the lock keeps direct
-    feeding from tests/bench harnesses safe too. The RNG is seeded, so a
+    feeding from test harnesses safe too. The RNG is seeded, so a
     single-threaded feed is deterministic.
     """
 

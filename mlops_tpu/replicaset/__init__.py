@@ -5,9 +5,9 @@ One shm ring, E engine REPLICA processes: the router half lives here
 transport half is the per-replica queue/doorbell/stats axes grown onto
 `serve/ipc.py`; the process half is the supervisor forking E engine
 children in `serve/frontend.py`. `replicaset.sim` builds an in-process
-E-replica plane over simulated-device engines for the bench's scaling
-stage and the unit tests (imported explicitly — it pulls serve.ipc,
-which this package's import-light half must not).
+E-replica plane over simulated-device engines for the unit tests
+(imported explicitly — it pulls serve.ipc, which this package's
+import-light half must not).
 
 Jax-free: front ends import the router; nothing here touches a device.
 """

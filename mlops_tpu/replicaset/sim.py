@@ -1,11 +1,11 @@
-"""In-process E-replica plane over simulated devices (bench + tests).
+"""In-process E-replica plane over simulated devices (tests).
 
 The replica set's scaling claim is about DEVICE-TIME-bound serving: on
 the TPU path every dispatch pays a flat device round trip (its size on
 the chip: not measured), and data-parallel replicas hide exactly that
 wait behind each other. A CPU CI box cannot demonstrate it with real compute — one core
 runs one matmul at a time no matter how many processes ask — so the
-bench's replica stage (and the unit tests) drive the REAL ring, router,
+unit tests drive the REAL ring, router,
 and E REAL `RingService` consumers over engines whose device time is a
 simulated constant-latency round trip. Host-side work (descriptor
 queues, coalescing, scatter, slab writes, doorbells) is all real and
@@ -14,7 +14,7 @@ models. ``XLA_FLAGS=--xla_force_host_platform_device_count=E`` is the
 companion knob for runs that want E visible jax devices too; this
 module itself is jax-free.
 
-Everything here is test/bench harness, not serving code — the
+Everything here is test harness, not serving code — the
 production fleet is `serve_multi_worker` with ``serve.engine_replicas``.
 """
 
@@ -122,8 +122,8 @@ def build_sim_plane(
     start: bool = True,
 ) -> SimPlane:
     """The production ring + E production `RingService` consumers over
-    simulated-device engines, all in this process (no forks — the bench
-    measures fan-out mechanics and device-time overlap, not HTTP)."""
+    simulated-device engines, all in this process (no forks: what it
+    exercises is fan-out mechanics and device-time overlap, not HTTP)."""
     from mlops_tpu.serve.ipc import RequestRing, RingService
 
     ring = RequestRing(

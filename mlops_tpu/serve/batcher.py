@@ -105,8 +105,8 @@ class MicroBatcher:
         # gave co-travelers time to accumulate — that accumulation IS the
         # window, paid for free); only an empty pipe waits, and then for
         # ``admit_fraction`` of the EWMA-measured dispatch-stage seconds
-        # (the span stage that dominates batch-1 latency — BENCH_r08:
-        # fetch_sync ~1.59 of 2.02 ms p50), capped by window_s. Group
+        # (the span stage a lone request waits in longest: the blocking
+        # fetch), capped by window_s. Group
         # geometry never changes per-request math, so responses are
         # bit-identical across modes at any load.
         self.batch_mode = batch_mode

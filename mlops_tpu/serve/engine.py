@@ -1004,7 +1004,7 @@ class InferenceEngine:
         (entry, requested rows, padded rows, dispatch->fetch seconds)
         under ``<entry>@<model-tag>``. Disarmed = None = one branch on
         the dispatch path and one on the fetch path (the faultline
-        overhead discipline; bench key ``slo_overhead_pct``)."""
+        overhead discipline)."""
         if ledger is not None:
             self._cost_tag = self._model_tag(self.bundle)
         self.cost_ledger = ledger
@@ -1262,8 +1262,7 @@ class InferenceEngine:
     ) -> bytes:
         """`predict_records` straight to wire bytes: the whole
         encode→dispatch→fetch→json pipeline stays in the executor thread,
-        so the event loop only ever writes pre-encoded bytes (the
-        encode-bound residue the bench's http_vs_engine_ratio measured)."""
+        so the event loop only ever writes pre-encoded bytes."""
         columns = records_to_columns(records)
         ds = self.bundle.preprocessor.encode(columns)
         if span is not None:

@@ -19,7 +19,7 @@ Three cooperating pieces, threaded through BOTH serving planes:
   autotuner consumes.
 
 Everything here follows the faultline discipline: disarmed, every hot
-path pays one ``is None`` check (bench key ``slo_overhead_pct``).
+path pays one ``is None`` check.
 """
 
 from mlops_tpu.slo.engine import (  # noqa: F401

@@ -14,7 +14,7 @@ dispatch. Warmup therefore compiles (or deserializes) each distinct
 architecture ONCE and every architecture-twin adopts the donor's exec
 table by reference (`InferenceEngine.adopt_executables`) — N tenants at
 K distinct architectures pay K warmups, and ``shared_exec_count`` is the
-provable sharing the bench/tests pin.
+provable sharing the tests pin.
 
 Concurrency (tpulint Layer 3): the registry itself holds NO locks — the
 tenant list is immutable after construction and ``warmup`` runs once,
@@ -123,8 +123,7 @@ class TenantRegistry:
             )
             for bundle in self.bundles
         ]
-        # Tenants served through another tenant's compiled entries (the
-        # sharing proof the bench's tenants_shared_exec_count reports).
+        # Tenants served through another tenant's compiled entries.
         self.shared_exec_count = 0
 
     def __len__(self) -> int:

@@ -29,8 +29,7 @@ operations' clock (docs/observability.md "Bulk jobs").
 
 Everything here is jax-free (front-end processes import it) and gated
 behind the ``trace`` config section: disarmed, the serving hot path pays
-one ``is None`` check per request (the faultline discipline — bench pins
-``trace_overhead_pct``).
+one ``is None`` check per request (the faultline discipline).
 """
 
 from mlops_tpu.trace.recorder import TraceRecorder

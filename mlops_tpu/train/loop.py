@@ -62,7 +62,7 @@ def packaged_or_raw(ema: Any, params: Any, decay: float, step) -> Any:
 
 def debias_ema(ema: Any, decay: float, step) -> Any:
     """Bias-corrected Polyak average: ``ema / (1 - decay^step)`` — exact
-    from step 1, so short runs (bench trains 600 steps) are not dragged
+    from step 1, so short runs (a few hundred steps) are not dragged
     toward the zero init the raw accumulator starts from. ``step`` may be
     a traced array or a plain int (the layout loops' Python counter)."""
     correction = 1.0 - decay ** jnp.asarray(step, jnp.float32)

@@ -3,7 +3,7 @@
 Standard JAX fake-backend trick (SURVEY.md SS4 build obligation (d)): all
 multi-chip logic is exercised without a TPU via
 ``--xla_force_host_platform_device_count=8``. The chip is reached through
-``chip_smoke.py`` and ``bench.py``, never from the tests.
+``chip_smoke.py`` and ``benchmark/run.py``, never from the tests.
 """
 
 import os

@@ -461,33 +461,3 @@ def test_regrid_aborts_when_promotion_races_warm(
     with pytest.raises(RegridAborted):
         apply_plan(regrid_engine, (1, 2, 8))
     assert regrid_engine.grid_generation == generation  # no swap happened
-
-
-# ----------------------------------------------------- bench key contract
-def test_bench_autotune_stage_key_contract(tiny_pipeline, sample_request):
-    """BENCH_r10+ rounds carry the gridtuner keys: the measured goodput
-    gain of the autotuned grid over the hand grid on the skewed trace,
-    the hammer-observed swap downtime, and the plan's own prediction
-    (so every committed round carries the predicted-vs-measured audit).
-    Runs the REAL stage — its engine is private, so the shared fixtures
-    are untouched."""
-    import bench
-    from mlops_tpu.bundle import load_bundle
-
-    _, result = tiny_pipeline
-    out = bench._autotune_stage(
-        load_bundle(result.bundle_dir), sample_request[0]
-    )
-    assert set(out) >= {
-        "autotune_goodput_gain_pct",
-        "regrid_downtime_ms",
-        "autotune_predicted_gain_pct",
-        "autotune_buckets",
-        "autotune_baseline_waste_pct",
-        "autotune_waste_pct",
-    }
-    assert out["regrid_downtime_ms"] >= 0.0
-    # The incumbent grid is inside the searched space, so the plan's
-    # own claim is non-negative by construction.
-    assert out["autotune_predicted_gain_pct"] >= 0.0
-    assert out["autotune_buckets"][-1] == 4096  # ceiling never shrinks

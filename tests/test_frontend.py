@@ -1234,31 +1234,6 @@ def test_ring_loop_lag_bounded_under_burst(
         assert payload == json.loads(json.dumps(expected))
 
 
-# ----------------------------------------------------- bench key contract
-@pytest.mark.slow
-def test_bench_http_multi_stage_key_contract(engine, sample_request):
-    """The CI contract for the new bench keys: the http_workers axis
-    (http_w{2,4}_req_per_s_c{...}), the http_vs_engine_ratio derived key,
-    and shed_503_pct from the overload burst — asserted against the real
-    stage function over the session engine."""
-    import bench
-
-    base = {"engine_group_req_per_s": 100.0, "http_req_per_s_c8": 1.0}
-    out = bench._http_multi_stage(
-        engine, engine.bundle, sample_request[0], base
-    )
-    for workers in (2, 4):
-        for c in (1, 8, 32, 128):
-            key = f"http_w{workers}_req_per_s_c{c}"
-            assert out.get(key, 0) > 0, (key, out)
-    assert out["shed_burst_offered"] == 640
-    assert 0.0 <= out["shed_503_pct"] <= 100.0
-    assert out["shed_burst_errors"] == 0
-    assert out["http_vs_engine_ratio"] == pytest.approx(
-        out["http_req_per_s_best"] / 100.0, rel=1e-6
-    )
-
-
 # ------------------------------------------------------ config validation
 def test_serveconfig_rejects_inconsistent_geometry_with_named_errors():
     cfg = ServeConfig(max_workers=4, max_inflight=4)

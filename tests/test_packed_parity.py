@@ -1,8 +1,7 @@
 """PR 4 hot-path contract: packed single-buffer responses are BIT-IDENTICAL
 to the seed dict-path responses (every bucket, every group slot shape), the
-device monitor accumulator counts exactly what was scored, overlapped
-batcher fetches never cross-wire requests, and the bench emits the new
-breakdown/monitor keys.
+device monitor accumulator counts exactly what was scored, and overlapped
+batcher fetches never cross-wire requests.
 """
 
 import asyncio
@@ -385,53 +384,3 @@ class _Proxy:
 
     def __getattr__(self, name):
         return getattr(self._engine, name)
-
-
-# ------------------------------------------------------------- bench keys
-def test_bench_breakdown_and_monitor_keys(engine, sample_request):
-    """The CI contract for the new bench keys: breakdown_ms carries
-    fetch/fetch_copy/fetch_sync (fetch = copy + sync), the batch-1 stage
-    emits lock_wait_ms (instrumented lock contention, PR 5), and the
-    monitor stage emits monitor_fetch_per_s — asserted against the real
-    stage functions, tier-1 (no subprocess bench run)."""
-    import bench
-
-    batch1 = bench._batch1_stage(engine, sample_request[0])
-    bd = batch1["breakdown_ms"]
-    assert {"encode", "dispatch", "fetch", "fetch_copy", "fetch_sync"} <= set(bd)
-    # Instrumented lock wait: finite, non-negative, and small on this
-    # uncontended single-caller loop (seconds would mean a lock held
-    # across blocking work leaked back into the hot path).
-    assert 0.0 <= batch1["lock_wait_ms"] < 1000.0
-    # fetch is the median of per-rep (copy + sync) while the sub-keys are
-    # per-stage medians — the two statistics drift apart whenever copy and
-    # sync jitter is correlated across reps, by tens of µs under load. The
-    # tolerance only needs to catch a STRUCTURAL break (a sub-stage
-    # dropped from the sum ≈ ms-scale), not scheduler noise.
-    assert bd["fetch"] == pytest.approx(
-        bd["fetch_copy"] + bd["fetch_sync"], abs=0.2
-    )
-    monitor = bench._monitor_stage(engine)
-    assert monitor["monitor_fetch_per_s"] > 0
-    # Robustness keys (ISSUE 9): armed-off overhead ~0 (generous noise
-    # bound — the pin is the KEY and its order of magnitude, not the
-    # scheduler), and the degraded path measurably served requests
-    # through the next warmed bucket, with the engine restored after.
-    faults_stats = bench._faults_stage(engine, sample_request[0])
-    assert -50.0 < faults_stats["fault_overhead_pct"] < 50.0
-    assert faults_stats["degraded_p99_ms"] > 0
-    assert faults_stats["degraded_dispatch_total"] == 50
-    from mlops_tpu import faults as faults_mod
-
-    assert not faults_mod.armed()  # the stage disarms on every path
-    assert ("bucket", 8) in engine._exec  # the popped entry was restored
-    # tracewire keys (ISSUE 10): armed-vs-disarmed overhead is a real
-    # percentage (generous noise bound, same discipline as the faults
-    # key), and the skewed synthetic trace produces a nonzero padding
-    # waste with a positive goodput rate. The stage must disarm the
-    # engine's shape stats on every path.
-    trace_stats = bench._trace_stage(engine, sample_request[0])
-    assert -50.0 < trace_stats["trace_overhead_pct"] < 50.0
-    assert 0.0 < trace_stats["padding_waste_pct"] < 100.0
-    assert trace_stats["useful_rows_per_s"] > 0
-    assert engine.shape_stats is None  # disarmed after the stage

@@ -430,10 +430,9 @@ def test_device_fault_drains_pipeline_and_propagates(
 # --------------------------------------------------------- throughput smoke
 @pytest.mark.slow
 def test_pipelined_throughput_beats_old_serial_path(tiny_pipeline, tmp_path):
-    """The bench acceptance, in-suite: on a synthetic 200k-row dataset the
-    pipelined path (native chunk encode, depth 2) must beat the
-    pre-executor serial path (Python csv parse, depth 1) on rows/s —
-    the bench records the same comparison as ``bulk_stream_speedup``."""
+    """On a synthetic 200k-row dataset the pipelined path (native chunk
+    encode, depth 2) must beat the pre-executor serial path (Python csv
+    parse, depth 1) on rows/s."""
     from mlops_tpu.bundle import load_bundle
     from mlops_tpu.data.stream import score_csv_stream
 

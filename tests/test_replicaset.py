@@ -352,24 +352,6 @@ def test_replica_plane_lock_discipline_under_perturbed_schedules(seed):
         plane.stop()
 
 
-# ----------------------------------------------------- bench key contract
-@pytest.mark.slow  # drives three sim planes (~8 s): CI's parallel job
-def test_bench_replica_stage_key_contract():
-    """BENCH_r07+ rounds carry the replica-scaling keys: per-E grouped
-    req/s, the headline efficiency, per-replica goodput/depth splits at
-    E=4, and the zero-wrong-responses pin."""
-    import bench
-
-    out = bench._replica_stage()
-    for e in (1, 2, 4):
-        assert out[f"replica_req_per_s_e{e}"] > 0
-    assert 0.0 < out["replica_scaling_efficiency"] <= 1.5
-    assert out["replica_wrong_responses"] == 0
-    for r in range(4):
-        assert out[f"replica_rows_r{r}_e4"] > 0
-        assert out[f"replica_ring_depth_peak_r{r}_e4"] > 0
-
-
 # ------------------------------------------------ partition-rule sharding
 def test_mlp_engine_serves_through_sharded_params(tiny_pipeline, sample_request):
     """Fast tier-1 pin: serve.model_shards=2 lays the mlp trunk out over

@@ -761,17 +761,3 @@ def test_load_spans_accepts_glob(tmp_path):
     assert len(spans) == 2
     spans = load_spans(str(tmp_path / "spans-w1.jsonl"))  # file form
     assert len(spans) == 1
-
-
-# ------------------------------------------------------- bench key contract
-def test_bench_slo_stage_key_contract(warm_engine, sample_request):
-    """BENCH_r08+ rounds carry the sloscope keys: disarmed-vs-armed
-    batch-1 overhead plus the armed p50 (the documented armed delta)."""
-    import bench
-
-    out = bench._slo_stage(warm_engine, sample_request[0])
-    assert set(out) >= {"slo_overhead_pct", "slo_armed_p50_ms"}
-    assert isinstance(out["slo_overhead_pct"], float)
-    assert out["slo_armed_p50_ms"] > 0
-    # The stage restores the engine's disarmed state.
-    assert warm_engine.cost_ledger is None

@@ -960,25 +960,6 @@ def test_trace_report_tenant_filter(tmp_path, capsys):
     assert _trace_report(config) == 2
 
 
-# ----------------------------------------------------- bench key contract
-@pytest.mark.slow
-def test_bench_tenancy_stage_key_contract(registry, sample_request):
-    """The CI contract for the tenancy bench keys: shared-exec count,
-    per-tenant goodput under a 10x hot flood, and the starvation ratio
-    — asserted against the real stage function over a warmed engine."""
-    import bench
-
-    engine = registry.engines[0]
-    out = bench._tenancy_stage(engine, engine.bundle, sample_request[0])
-    assert out["tenants_shared_exec_count"] == 1
-    assert out["tenant_req_per_s_hot"] > 0
-    assert out["tenant_req_per_s_cold"] > 0
-    assert out["tenant_cold_solo_p99_ms"] > 0
-    assert out["tenant_cold_contended_p99_ms"] > 0
-    assert out["starvation_cold_p99_ratio"] > 0
-    assert out["tenant_quota_shed_hot"] >= 0
-
-
 def test_serve_cli_tenants_flag_maps_to_config():
     from mlops_tpu.cli import build_parser
 

@@ -259,30 +259,6 @@ def test_grouped_demotion_bit_identical(quant_bundle, routed_engine):
         assert d["outliers"] == n["outliers"]
 
 
-# ----------------------------------------------------- bench key contract
-@pytest.mark.slow
-def test_bench_tierroute_stage_key_contract(quant_bundle):
-    """The CI contract for the ISSUE 19 bench keys: per-class routed
-    throughput, the tier_routed_req_per_s headline, and the
-    brownout-vs-shed A/B keys — asserted against the real stage function
-    over a gated quant bundle."""
-    import bench
-    from mlops_tpu.schema import LoanApplicant
-
-    out = bench._tierroute_stage(
-        quant_bundle, LoanApplicant().model_dump()
-    )
-    assert out["tier_ladder"] == ["quant", "exact"]
-    for label in ("default", "cheap", "accurate"):
-        assert out[f"tier_req_per_s_{label}"] > 0, (label, out)
-    assert out["tier_routed_req_per_s"] == out["tier_req_per_s_cheap"]
-    for arm in ("on", "off"):
-        assert out[f"brownout_{arm}_ok"] >= 0
-        assert out[f"brownout_{arm}_goodput_req_per_s"] >= 0
-    assert "brownout_goodput_gain_pct" in out
-    assert out["brownout_demotions"] >= 0
-
-
 def test_ring_replay_resolves_the_same_tier_from_shm(routed_engine):
     """The engine-side tier resolver reads the CLASS back out of the shm
     slot header — a respawned engine's replay therefore re-derives the
