@@ -22,7 +22,14 @@ from mlops_tpu.data.encode import EncodedDataset, Preprocessor
 from mlops_tpu.models import FAMILIES, abstract_variables, build_model
 from mlops_tpu.models.evabyte import FIELD_NAMES, RECORD_BYTES, RECORD_END, render_bytes
 from mlops_tpu.monitor.state import fit_monitor
-from mlops_tpu.ops.eva_attention import eva_attend, eva_prep_kv, rope
+from mlops_tpu.ops.eva_attention import (
+    eva_attend,
+    eva_attend_blockwise,
+    eva_attend_xla,
+    eva_prep_kv,
+    rope,
+    wants_eva_kernel,
+)
 from mlops_tpu.parallel.bulk import make_bulk_jit, mesh_chunk_rows, score_dataset
 from mlops_tpu.schema import SCHEMA
 
@@ -186,6 +193,117 @@ def test_a_summary_shows_only_once_its_window_is_past():
     assert np.abs(base[0, 32:] - after[0, 32:]).max() > 1e-4  # through v~ of chunk 2
     cut, cut_after = run(v, False), run(moved, False)
     assert (cut[0, 32:] == cut_after[0, 32:]).all()  # and through nothing else
+
+
+# ------------------------------ the blockwise kernel, against the XLA form
+KERNEL = dict(window=512, chunk=4)  # 128 summaries a window, as EvaByte has
+# blocks of 128 at window 512: four query blocks a window, so a grid step
+# meets local blocks before its own, its diagonal and past windows' summaries
+
+
+def kernel_operands(windows, dtype, batch=2, heads=2, seed=0, window=512, chunk=4):
+    seq = int(windows * window)
+    rng = np.random.default_rng(seed)
+    q, k, v = (
+        jnp.asarray(rng.normal(size=(batch, seq, heads, 128)), jnp.float32).astype(dtype)
+        for _ in range(3)
+    )
+    phi, mu = (rng.normal(size=(heads, 128)).astype(np.float32) for _ in range(2))
+    k_sum, v_sum = eva_prep_kv(k, v, phi, mu, chunk)
+    return q, k, v, k_sum, v_sum
+
+
+@pytest.mark.parametrize("dtype,atol", [("float32", 1e-5), ("bfloat16", 2.0**-7)])
+@pytest.mark.parametrize("windows", [1, 2, 3.5])
+def test_the_kernel_matches_the_xla_form(windows, dtype, atol):
+    """float32 to 1e-5 (the order of a row's sum is all that differs);
+    bfloat16 to the rounding of one bfloat16 weight or output: both forms
+    round the weights once, to 8 bits, before the second product."""
+    operands = kernel_operands(windows, jnp.dtype(dtype), seed=int(10 * windows))
+    with jax.default_matmul_precision("highest"):
+        expected = eva_attend_xla(*operands, **KERNEL)
+        out = eva_attend_blockwise(*operands, **KERNEL, block=128, interpret=True)
+    assert out.shape == expected.shape and out.dtype == expected.dtype
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(expected, np.float32), atol=atol
+    )
+
+
+def test_the_kernel_at_its_own_block_size_matches_the_xla_form():
+    """The default block against a window of two of them: the widest
+    tiles the kernel forms, one history, one head."""
+    window, chunk = 1024, 8
+    q, k, v, k_sum, v_sum = kernel_operands(
+        2, jnp.float32, batch=1, heads=1, seed=5, window=window, chunk=chunk
+    )
+    with jax.default_matmul_precision("highest"):
+        expected = eva_attend_xla(q, k, v, k_sum, v_sum, window, chunk)
+        out = eva_attend_blockwise(q, k, v, k_sum, v_sum, window, chunk, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expected), atol=1e-5)
+
+
+def test_the_kernel_never_visits_a_summary_block_before_its_window_is_past():
+    """The block-skipping twin of the test above: the summaries of window
+    ``j`` change no answer in windows ``0..j`` (their block is not visited
+    there) and every answer after them; the last window's change none."""
+    q, k, v, k_sum, v_sum = kernel_operands(3, jnp.float32, batch=1, seed=7)
+    per_window = KERNEL["window"] // KERNEL["chunk"]
+
+    def run(ks, vs):
+        return np.asarray(
+            eva_attend_blockwise(q, k, v, ks, vs, **KERNEL, block=128, interpret=True)
+        )
+
+    base = run(k_sum, v_sum)
+    for j in range(3):
+        blanked = slice(j * per_window, (j + 1) * per_window)
+        moved = run(k_sum.at[:, blanked].add(1.0), v_sum.at[:, blanked].add(1.0))
+        seen_from = (j + 1) * KERNEL["window"]
+        assert (moved[:, :seen_from] == base[:, :seen_from]).all(), j
+        if j < 2:  # every later position, through that one block
+            later = np.abs(moved[:, seen_from:] - base[:, seen_from:])
+            assert later.max(axis=(0, 2, 3)).min() > 1e-4, j
+
+
+def test_which_shapes_take_the_kernel():
+    mc = REAL["model_config"]
+    head = mc["token_dim"] // mc["heads"]
+    assert (head, mc["attn_window"], mc["attn_chunk"]) == (128, 2048, 16)
+    for seq in (256, 3072, 16384, 32768):  # any history of the real configuration
+        assert wants_eva_kernel(seq, head, mc["attn_window"], mc["attn_chunk"])
+    # each tiny configuration of this file takes the XLA form, everywhere
+    for config in (tiny_config(), tiny_config(window=512), tiny_config(records=2, window=128),
+                   tiny_config(token_dim=16, heads=2, window=128)):
+        assert not wants_eva_kernel(
+            config.doc_records * RECORD_BYTES, config.token_dim // config.heads,
+            config.attn_window, config.attn_chunk,
+        )
+    assert wants_eva_kernel(1792, 128, 512, 4)  # the kernel tests' shape
+    assert not wants_eva_kernel(1792, 64, 512, 4)  # half a lane tile a head
+    assert not wants_eva_kernel(1792, 128, 512, 8)  # 64 summaries a window
+    assert not wants_eva_kernel(1792, 128, 640, 5)  # no whole blocks in a window
+    assert not wants_eva_kernel(65536, 128, 2048, 16)  # 4,096 summaries: past VMEM
+    assert not wants_eva_kernel(8192, 128, 4096, 32)  # a 4,096-key window: past VMEM
+    with pytest.raises(ValueError, match="no tiling"):
+        eva_attend_blockwise(*kernel_operands(1, jnp.float32), 512, 8, interpret=True)
+
+
+def test_the_backward_of_the_kernels_shape_is_the_xla_forms():
+    """At a shape the kernel takes, `eva_attend` is a `custom_vjp` whose
+    backward differentiates the XLA form: the same gradients as autodiff
+    of the XLA form itself (on the CPU the forward is that form too)."""
+    operands = kernel_operands(1.5, jnp.float32, batch=1, heads=1, seed=3)
+    weights = jnp.asarray(np.random.default_rng(4).normal(size=operands[0].shape), jnp.float32)
+
+    def loss(attend):
+        return lambda *xs: (attend(*xs, **KERNEL) * weights).sum()
+
+    got = jax.grad(loss(eva_attend), argnums=(0, 1, 2, 3, 4))(*operands)
+    expected = jax.grad(loss(eva_attend_xla), argnums=(0, 1, 2, 3, 4))(*operands)
+    for g, e in zip(got, expected):
+        assert np.abs(np.asarray(e)).max() > 0
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=1e-6, atol=1e-7)
+    assert "custom_vjp" in str(jax.make_jaxpr(loss(eva_attend))(*operands))
 
 
 @pytest.mark.parametrize("records,window", [(1, 256), (2, 256), (7, 512)])
