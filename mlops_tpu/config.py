@@ -38,14 +38,24 @@ class DataConfig:
     # random_state=2024 (`01-train-model.ipynb` cell 7)
 
 
+# Causal families that take the zoo's 2-D rows, read every `doc_records`
+# consecutive rows as one history and answer every record.
+HISTORY_FAMILIES = ("evabyte", "kimi_k2")
+
+
 @dataclasses.dataclass
 class ModelConfig:
     family: str = "mlp"  # mlp | ft_transformer | moe | linear | bert |
-    # evabyte | gbm | rf
+    # evabyte | kimi_k2 | gbm | rf
     hidden_dims: tuple[int, ...] = (256, 256, 128)
     embed_dim: int = 16
     dropout: float = 0.1
     precision: str = "bf16"  # compute dtype on MXU: bf16 | f32 (params stay f32)
+    param_dtype: str = "f32"  # the dtype parameters are STORED in, on disk
+    # and on the device: f32 | bf16. Family kimi_k2 alone takes bf16 (its
+    # published share of a layer does not fit a chip at four bytes a
+    # parameter); nothing casts the tree in the program, a product reads
+    # its leaf as stored
     ensemble_size: int = 1  # >1 wraps the Flax family in a vmapped deep
     # ensemble (models/ensemble.py) — the MXU-native answer to the
     # reference's RandomForest variance reduction; 1 = single model
@@ -93,21 +103,43 @@ class ModelConfig:
     attn_window: int = 2048
     attn_chunk: int = 16
     rope_theta: float = 100000.0
+    # Family kimi_k2 (models/kimi_k2.py; latent attention `ops/mla.py`, the
+    # sparse expert layer `ops/moe_dispatch.py`). Hidden size, heads, depth,
+    # the dense layer's width, the rotary base and the routed experts'
+    # count are `token_dim`, `heads`, `depth`, `ffn_dim`, `rope_theta`,
+    # `num_experts`. The latent ranks and the three head widths (query/key
+    # = nope + rope, value = v); the experts' width and how many a token
+    # chooses; the experts THIS process holds, `experts_held` of them from
+    # `first_expert` (0 held = all: the layer uncut), as one chip of an
+    # expert-parallel layer does; the rows of the embedding held (a slice
+    # of the vocabulary). The source's constants (norm eps, YaRN factor and
+    # betas, routed scaling, one leading dense layer) are the module's.
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    moe_ffn_dim: int = 2048
+    experts_per_token: int = 8
+    first_expert: int = 0
+    experts_held: int = 0
+    vocab_rows: int = 20480
 
     @property
     def reads_documents(self) -> bool:
         """True for the 3-D ``doc`` flavour: ``[D, R, C]`` record histories
         in, ONE answer a document out (bundle flavour ``doc``; refused by
         `score-batch` and the serving engine). False for every 2-D,
-        answer-a-row family, evabyte's histories included."""
-        return self.family != "evabyte" and self.doc_records > 1
+        answer-a-row family, the history scorers' (evabyte, kimi_k2)
+        included."""
+        return self.family not in HISTORY_FAMILIES and self.doc_records > 1
 
     @property
     def history_rows(self) -> int:
         """Consecutive ROWS a 2-D model reads as one sequence (1 = rows
         are independent): what a bulk chunk must hold whole
         (`parallel/bulk.py mesh_chunk_rows`)."""
-        return self.doc_records if self.family == "evabyte" else 1
+        return self.doc_records if self.family in HISTORY_FAMILIES else 1
 
     @property
     def uses_layout_trainer(self) -> bool:

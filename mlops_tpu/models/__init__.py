@@ -13,6 +13,11 @@ the TPU-native zoo is:
 - ``evabyte``         byte-level causal decoder (EVA chunked linear
   attention) over record HISTORIES rendered as text in-jit: consecutive
   rows are one history, every record gets its own answer
+- ``kimi_k2``         token-level causal decoder with latent attention
+  (MLA) and sparse routed experts beside a shared one, told which experts
+  it holds (one chip's share of an expert-parallel layer); histories as
+  ``evabyte`` reads them, a record the 48 tokens ``bert`` reads; the one
+  family whose parameters may be stored in bfloat16
 
 All families share one calling convention:
 ``model.apply(vars, cat_ids[int32 N,C], numeric[f32 N,M], train=...) ->
@@ -32,11 +37,12 @@ from mlops_tpu.models.bert import BertEncoder
 from mlops_tpu.models.ensemble import DeepEnsemble
 from mlops_tpu.models.evabyte import EvaByteScorer
 from mlops_tpu.models.ft_transformer import FTTransformer
+from mlops_tpu.models.kimi_k2 import KimiK2Scorer
 from mlops_tpu.models.mlp import MLP, LinearModel
 from mlops_tpu.models.moe import MoETransformer
 from mlops_tpu.schema.features import SCHEMA
 
-FAMILIES = ("linear", "mlp", "ft_transformer", "moe", "bert", "evabyte")
+FAMILIES = ("linear", "mlp", "ft_transformer", "moe", "bert", "evabyte", "kimi_k2")
 
 
 def build_model(config: ModelConfig) -> nn.Module:
@@ -50,6 +56,11 @@ def build_model(config: ModelConfig) -> nn.Module:
         single = dataclasses.replace(config, ensemble_size=1)
         return DeepEnsemble(member=build_model(single), size=config.ensemble_size)
     dtype = {"bf16": jnp.bfloat16, "f32": jnp.float32}[config.precision]
+    if config.param_dtype != "f32" and config.family != "kimi_k2":
+        raise ValueError(
+            f"family {config.family!r} keeps float32 parameters; "
+            f"model.param_dtype={config.param_dtype!r} is family kimi_k2's"
+        )
     if config.family == "linear":
         return LinearModel(cards=SCHEMA.cards, dtype=dtype)
     if config.family == "mlp":
@@ -105,6 +116,30 @@ def build_model(config: ModelConfig) -> nn.Module:
             records_per_history=config.doc_records,
             dtype=dtype,
         )
+    if config.family == "kimi_k2":
+        return KimiK2Scorer(
+            cards=SCHEMA.cards,
+            num_numeric=SCHEMA.num_numeric,
+            hidden=config.token_dim,
+            depth=config.depth,
+            heads=config.heads,
+            q_lora_rank=config.q_lora_rank,
+            kv_lora_rank=config.kv_lora_rank,
+            qk_nope_head_dim=config.qk_nope_head_dim,
+            qk_rope_head_dim=config.qk_rope_head_dim,
+            v_head_dim=config.v_head_dim,
+            ffn_dim=config.ffn_dim,
+            moe_ffn_dim=config.moe_ffn_dim,
+            num_experts=config.num_experts,
+            experts_per_token=config.experts_per_token,
+            first_expert=config.first_expert,
+            experts_held=config.experts_held or config.num_experts,
+            vocab_rows=config.vocab_rows,
+            records_per_history=config.doc_records,
+            rope_theta=config.rope_theta,
+            dtype=dtype,
+            param_dtype={"bf16": jnp.bfloat16, "f32": jnp.float32}[config.param_dtype],
+        )
     from mlops_tpu.models.gbm import SKLEARN_FAMILIES
 
     if config.family in SKLEARN_FAMILIES:
@@ -143,6 +178,7 @@ __all__ = [
     "DeepEnsemble",
     "EvaByteScorer",
     "FTTransformer",
+    "KimiK2Scorer",
     "LinearModel",
     "MLP",
     "MoETransformer",

@@ -97,14 +97,21 @@ def render_bytes(cat_ids: jnp.ndarray, numeric: jnp.ndarray) -> jnp.ndarray:
 
 class RMSNorm(nn.Module):
     """``x / rms(x) * (1 + g)`` in float32 (the source's
-    ``norm_add_unit_offset``); ``g`` is the parameter ``scale``."""
+    ``norm_add_unit_offset``); ``g`` is the parameter ``scale``. Without
+    ``unit_offset`` the weight is ``g`` itself, initialised to one
+    (`models/kimi_k2.py`)."""
+
+    unit_offset: bool = True
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        g = self.param("scale", nn.initializers.zeros_init(), (x.shape[-1],))
+        init = nn.initializers.zeros_init() if self.unit_offset else nn.initializers.ones_init()
+        g = self.param("scale", init, (x.shape[-1],), self.param_dtype)
+        g = g.astype(jnp.float32)
         x = x.astype(jnp.float32)
         rms = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + RMS_EPS)
-        return x * rms * (1.0 + g)
+        return x * rms * (1.0 + g if self.unit_offset else g)
 
 
 class _HeadVectors(nn.Module):

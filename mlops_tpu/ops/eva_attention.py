@@ -8,7 +8,8 @@ in the windows before it, it sees only through one learned summary a
 chunk. One softmax runs over both key sets.
 
 - ``rope``: rotary positions, rotate-half convention, applied to q and k
-  before anything else;
+  before anything else; the caller says which frequencies (a base, or the
+  inverse frequencies themselves);
 - ``eva_prep_kv``: per head and chunk, ``alpha = softmax_m(s * k_m . phi)``
   over the chunk's positions, ``k~ = sum_m alpha_m k_m + mu``, ``v~ =
   sum_m alpha_m v_m`` (``phi``, ``mu`` learned, one pair a head; ``s =
@@ -61,28 +62,45 @@ from mlops_tpu.ops.attention import NEG_INF
 from mlops_tpu.ops.kernel_gate import tpu_kernel_or
 
 
-def _rope_tables(seq: int, head_dim: int, theta: float):
-    """cos, sin ``[seq, head_dim // 2]`` float32. The inverse frequencies
-    are worked out on the host in float64 and rounded once, so that the
-    angle of a late position does not depend on a device's ``pow``."""
+def rope_inv_freq(head_dim: int, theta: float) -> np.ndarray:
+    """The plain rotary inverse frequencies ``theta ** (-i / half)``, float32
+    ``[head_dim // 2]``: worked out on the host in float64 and rounded once,
+    so that the angle of a late position does not depend on a device's
+    ``pow``."""
     half = head_dim // 2
-    inv_freq = (float(theta) ** (-np.arange(half, dtype=np.float64) / half)).astype(
+    return (float(theta) ** (-np.arange(half, dtype=np.float64) / half)).astype(
         np.float32
     )
-    angle = jnp.arange(seq, dtype=jnp.float32)[:, None] * jnp.asarray(inv_freq)[None, :]
-    return jnp.cos(angle), jnp.sin(angle)
 
 
 @jax.named_scope("rope")
-def rope(x: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """Rotary positions 0..S-1 on ``[B, S, H, D]``, rotate-half: with ``x =
-    (x1, x2)`` the two halves of a head, ``(x1 cos - x2 sin, x2 cos + x1
-    sin)``, computed in float32 and returned in ``x``'s dtype."""
+def rope(x: jnp.ndarray, freqs, positions: np.ndarray | None = None) -> jnp.ndarray:
+    """Rotary positions 0..S-1 (or the S ``positions`` given, where ``x``
+    holds some positions of a longer sequence) on ``[B, S, H, D]``,
+    rotate-half: with ``x = (x1, x2)`` the two halves of a head, ``(x1 cos
+    - x2 sin, x2 cos + x1 sin)``, computed in float32 and returned in
+    ``x``'s dtype.
+
+    ``freqs`` is the caller's: a bare base ``theta`` (the plain frequencies,
+    ``rope_inv_freq``) or the ``[D // 2]`` inverse frequencies themselves,
+    however scaled (`ops/mla.py yarn_inv_freq`)."""
     _, seq, _, head_dim = x.shape
     if head_dim % 2:
         raise ValueError(f"rope needs an even head size, got {head_dim}")
-    cos, sin = _rope_tables(seq, head_dim, theta)
-    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+    inv_freq = (
+        rope_inv_freq(head_dim, freqs)
+        if np.ndim(freqs) == 0
+        else np.asarray(freqs, np.float32)
+    )
+    if inv_freq.shape != (head_dim // 2,):
+        raise ValueError(f"{inv_freq.shape} frequencies for a head of {head_dim}")
+    at = (
+        jnp.arange(seq, dtype=jnp.float32)
+        if positions is None
+        else jnp.asarray(np.asarray(positions, np.float32))
+    )
+    angle = at[:, None] * jnp.asarray(inv_freq)[None, :]
+    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

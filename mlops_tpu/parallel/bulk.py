@@ -32,6 +32,15 @@ inside one ``mlops:bulk.job``, the pipeline's stage executions are
 ``mlops:pipe.<stage>`` spans on their own threads, and every one of them
 carries the job's ``job`` number, all on the device operations' clock.
 With no profiler session open a span is one flag test.
+
+A model with sparse experts (one that names a ``routing_collection``
+and gives ``routing_counts(state)``, as `models/kimi_k2.py` does) also
+counts, per expert layer, the assignments each expert it holds received.
+The chunk program returns the counts as a third output; the scorer keeps
+each run's on the device and the job sums them there and fetches the sum
+once, with the drift sample: ``BulkScoreResult.routing`` and the marker
+``mlops:bulk.routing``. Families without experts return two outputs,
+write nothing and pay nothing.
 """
 
 from __future__ import annotations
@@ -39,7 +48,9 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import itertools
+import operator
 import os
 import threading
 import time
@@ -192,6 +203,14 @@ class BulkScoreResult:
     # lowered, compiled and loaded (`compilecache/events.py
     # CompileCounter.delta`), and ``chunk_program_reused``: 1 where it found
     # its chunk program compiled for its signature (`warm_chunk_scorer`)
+    routing: dict[str, Any] | None = None  # a model with sparse experts:
+    # ``tokens`` the job's chunk runs read (padding included; a layer that
+    # computes the read positions alone routes only those),
+    # ``assignments_held`` of the (token, slot) choices that fell on the
+    # experts held here, ``max_expert_load`` / ``mean_expert_load`` a held
+    # expert and layer, ``per_layer`` ``[expert layers][experts held]``,
+    # and ``expert_runs`` (``expert_runs_per_layer``): the (chunk run,
+    # expert) pairs in which a held expert got a token, so had to be read
 
     @property
     def rows_per_s(self) -> float:
@@ -228,7 +247,35 @@ class BulkScoreResult:
                 if self.phases is not None
                 else {}
             ),
+            **({"routing": self.routing} if self.routing is not None else {}),
         }
+
+
+class RoutingTally:
+    """A job's routing counts: each chunk run's (``fused_counting``'s third
+    output) stays on the device as it came, nothing is dispatched or
+    fetched for it run by run; ``total`` sums them there, once, at the
+    job's end. The scorer's closure holds it, and it holds nothing of the
+    scorer: a scorer that referred to itself would be a cycle, and the
+    weights a job replicates over a mesh would outlive the job until a
+    collection."""
+
+    def __init__(self) -> None:
+        self.reset()
+
+    def reset(self) -> None:
+        self.runs, self.rows = [], 0
+
+    def add(self, counts, rows: int) -> None:
+        self.runs.append(counts)
+        self.rows += rows
+
+    def total(self) -> np.ndarray | None:
+        if not self.runs:
+            return None
+        # adds of one shape whatever the number of runs: nothing compiles
+        # in a job whose file is of another length
+        return np.asarray(functools.reduce(operator.add, self.runs))
 
 
 def use_distilled_bulk(bundle: Bundle, exact: bool | None = None) -> bool:
@@ -369,10 +416,16 @@ def make_chunk_scorer(
             )
         aot = compile_cache.load_or_compile(job)
 
+    tally = RoutingTally()
+
     def score_chunk(cat, num, mask):
         run = aot if (aot is not None and cat.shape[0] == chunk_rows) else fn
-        probs, flags = run(variables, monitor, t, cat, num, mask)
+        probs, flags, *counts = run(variables, monitor, t, cat, num, mask)
+        if counts:  # a model with sparse experts
+            tally.add(counts[0], cat.shape[0])
         return probs, flags
+
+    score_chunk.tally = tally
 
     # an AOT executable is loaded anew in every job and runs in the jit's
     # place: nothing of it is kept, so nothing is recorded
@@ -412,6 +465,8 @@ def warm_chunk_scorer(
     num = np.zeros((chunk_rows, SCHEMA.num_numeric), np.float32)
     mask = np.arange(chunk_rows) < (1 if host_model else chunk_rows)
     jax.block_until_ready(scorer(*transfer(cat, num, mask))[0])
+    if getattr(scorer, "tally", None) is not None:
+        scorer.tally.reset()  # a chunk of zeros is no job's tokens
     if kept is not None:
         kept.compiled_for.add(signature)
     return False
@@ -432,21 +487,26 @@ def make_bulk_jit(model, mesh: Mesh | None):
         fused = make_bulk_fused(model)
         if mesh is None:
             return jax.jit(fused)
-        return jax.jit(fused, **_data_parallel(mesh))
+        return jax.jit(fused, **_data_parallel(mesh, counts=_counts_routing(model)))
 
     return CHUNK_PROGRAMS.get(("fused", model, mesh), build).jitted
 
 
-def _data_parallel(mesh: Mesh) -> dict[str, Any]:
+def _counts_routing(model) -> bool:
+    return getattr(model, "routing_collection", None) is not None
+
+
+def _data_parallel(mesh: Mesh, counts: bool = False) -> dict[str, Any]:
     """The chunk programs' shardings under a mesh: variables, monitor and
     temperature replicate, the chunk's rows (and both answers) lie over
-    'data'."""
+    'data'; the routing counts of a model with sparse experts, summed
+    over the shards, replicate."""
     data_in = batch_sharding(mesh)
     rows = batch_sharding(mesh, ndim=1)
     rep = replicated(mesh)
     return {
         "in_shardings": (rep, rep, rep, data_in, data_in, rows),
-        "out_shardings": (rows, rows),
+        "out_shardings": (rows, rows, rep) if counts else (rows, rows),
     }
 
 
@@ -467,7 +527,25 @@ def make_bulk_fused(model):
         logits = model.apply(variables, cat.astype(jnp.int32), num, train=False)
         return jax.nn.sigmoid(logits / temperature), outlier_flags(monitor, num, mask)
 
-    return fused
+    if not _counts_routing(model):
+        return fused
+
+    def fused_counting(variables, monitor, temperature, cat, num, mask):
+        """``fused`` with a third output, int32 ``[2, expert layers,
+        experts held]``: the assignments each held expert got in this run,
+        and 1 where it got any (summed over runs: the runs it was read in)."""
+        logits, state = model.apply(
+            variables, cat.astype(jnp.int32), num, train=False,
+            mutable=[model.routing_collection],
+        )
+        counts = model.routing_counts(state)
+        return (
+            jax.nn.sigmoid(logits / temperature),
+            outlier_flags(monitor, num, mask),
+            jnp.stack([counts, (counts > 0).astype(counts.dtype)]),
+        )
+
+    return fused_counting
 
 
 def make_bulk_quant_fused():
@@ -711,6 +789,7 @@ def score_dataset(
                     np.ones(take, bool),
                 )
             )
+            routing = _routing_summary(scorer, bundle.model, job)
         compile_events = {
             **CompileCounter.delta(traced_before, counter.snapshot()),
             "chunk_program_reused": int(reused),
@@ -737,4 +816,33 @@ def score_dataset(
         ),
         phases=phases,
         compile_events=compile_events,
+        routing=routing,
     )
+
+
+def _routing_summary(scorer, model, job: int) -> dict[str, Any] | None:
+    """The job's routing counts, summed on the device and fetched once, and
+    the marker ``mlops:bulk.routing`` on the trace's clock;
+    ``None`` for a scorer that counted nothing."""
+    tally = getattr(scorer, "tally", None)
+    counts = tally.total() if tally is not None else None
+    if counts is None:
+        return None
+    per_layer, active = counts
+    routing = {
+        "tokens": int(tally.rows * model.tokens_per_row),
+        "assignments_held": int(per_layer.sum()),
+        "max_expert_load": int(per_layer.max()),
+        "mean_expert_load": float(per_layer.mean()),
+        "expert_runs": int(active.sum()),
+        "per_layer": per_layer.tolist(),
+        "expert_runs_per_layer": active.tolist(),
+    }
+    with jax.profiler.TraceAnnotation(
+        "mlops:bulk.routing",
+        job=job,
+        **{k: v for k, v in routing.items() if not k.endswith("per_layer")},
+        **{f"layer_{i}": "|".join(map(str, row)) for i, row in enumerate(per_layer)},
+    ):
+        pass
+    return routing

@@ -400,3 +400,54 @@ def test_eva_attention_compiles_for_v5e_at_evabytes_shape(one_chip, no_persisten
         lambda *xs: eva_attend(*xs, 2048, 16), long, long, long, summaries, summaries
     )
     assert "eva_attend_fwd" in text
+
+
+def test_kimi_k2_chunk_program_compiles_for_v5e_at_published_widths(
+    one_chip, no_persistent_cache
+):
+    """The bulk chunk program of `kimi-k2-5l.bulk-hist` as the cell runs it
+    (`parallel/bulk.py make_bulk_fused` over `models/kimi_k2.py` at the
+    configuration file's widths, one history of 64 records a run,
+    bfloat16 parameters): it fits the 75% rule its chunk was sized by
+    (`benchmark/compile_check.py`), nothing holds a float32 copy of a held
+    expert's stacked weights or of the embedding, the routed experts'
+    products are the compiler's grouped kernel (twelve calls: three
+    products in four expert layers), and the counter is the program's third
+    output."""
+    import json
+    from pathlib import Path
+
+    from mlops_tpu.config import ModelConfig
+    from mlops_tpu.models import abstract_variables, build_model
+    from mlops_tpu.monitor.state import abstract_monitor_state
+    from mlops_tpu.parallel.bulk import make_bulk_fused
+    from mlops_tpu.schema import SCHEMA
+
+    real = json.loads(
+        (Path(__file__).resolve().parents[1] / "benchmark/configs/kimi-k2-5l.json").read_text()
+    )
+    fields = dict(real["model_config"])
+    fields["hidden_dims"] = tuple(fields["hidden_dims"])
+    model = build_model(ModelConfig(**fields))
+    rows = real["deployment"]["score_chunk_rows"]
+    compiled = (
+        jax.jit(make_bulk_fused(model))
+        .lower(
+            _on(abstract_variables(model), one_chip),
+            _on(abstract_monitor_state(), one_chip),
+            S((), jnp.float32, sharding=one_chip),
+            S((rows, SCHEMA.num_categorical), jnp.int8, sharding=one_chip),
+            S((rows, SCHEMA.num_numeric), jnp.float32, sharding=one_chip),
+            S((rows,), jnp.bool_, sharding=one_chip),
+        )
+        .compile()
+    )
+    memory = compiled.memory_analysis()
+    needed = memory.temp_size_in_bytes + memory.argument_size_in_bytes
+    assert 10.9e9 < memory.argument_size_in_bytes < 11.0e9  # 5.46 B parameters, 2 bytes each
+    assert needed <= 0.75 * 15.75 * 2**30, needed
+    text = compiled.as_text()
+    assert not re.search(r"f32\[24,7168,2048\]|f32\[24,2048,7168\]|f32\[20480,7168\]", text)
+    grouped = [line for line in text.splitlines() if "ragged-dot" in line and "custom-call(" in line]
+    assert len([line for line in grouped if "metadata" not in line.split("custom-call(")[0]]) >= 12
+    assert re.search(r"s32\[2,4,24\]", text), "the routing counter is not an output"
