@@ -21,8 +21,12 @@ under the zoo's calling convention and the repo's own read-out.
   keys and values expanded from a normed latent (``kv_a``, norm,
   ``kv_b``), a rotary key part shared by all heads, YaRN frequencies
   through `ops/eva_attention.py rope`, query/key width ``nope + rope``
-  against value width ``v``. The source's de-interleaving of rotary pairs
-  is a relabelling of weight columns and is left out.
+  against value width ``v``. `mla_attend` takes the projections as they
+  are written (``kv_b``'s output whole, the rotary key once a position):
+  on a TPU, at shapes its rule admits, it is one Pallas kernel a layer;
+  in the last layer (``read``) and everywhere else plain XLA. The
+  source's de-interleaving of rotary pairs is a relabelling of weight
+  columns and is left out.
 - **The expert layer** (`ops/moe_dispatch.py`) is told ``(first_expert,
   experts_held)``: it routes over all ``num_experts``, weighs over all the
   ``experts_per_token`` chosen, and adds its own experts' part and the
@@ -56,7 +60,7 @@ from mlops_tpu.models.bert import TokenLayout, tokenize
 from mlops_tpu.models.evabyte import RMSNorm
 from mlops_tpu.ops import moe_dispatch
 from mlops_tpu.ops.eva_attention import rope
-from mlops_tpu.ops.mla import causal_attend, softmax_scale, yarn_inv_freq
+from mlops_tpu.ops.mla import mla_attend, softmax_scale, yarn_inv_freq
 
 ROUTING = "routing"  # the collection the expert layers count into
 
@@ -135,7 +139,7 @@ class KimiBlock(nn.Module):
         return self._dense(h.shape[-1], f"{prefix}down")(gated.astype(self.dtype))
 
     def _attention(self, x: jnp.ndarray, read: np.ndarray | None) -> jnp.ndarray:
-        b, seq, dim = x.shape
+        b, _, dim = x.shape
         nope, rot, wide = self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim
         freqs = yarn_inv_freq(
             rot, self.rope_theta, self.rope_factor, self.rope_original_positions
@@ -146,24 +150,19 @@ class KimiBlock(nn.Module):
             c_q = self._norm("q_norm")(self._dense(self.q_lora_rank, "q_a")(asked))
             q = self._dense(self.heads * (nope + rot), "q_b")(c_q.astype(self.dtype))
             q = q.reshape(b, -1, self.heads, nope + rot)
-            q = jnp.concatenate(
-                [q[..., :nope], rope(q[..., nope:], freqs, positions=read)], axis=-1
-            )
+            q_nope, q_rot = q[..., :nope], rope(q[..., nope:], freqs, positions=read)
         with jax.named_scope("mla_kv"):
             latent = self._dense(self.kv_lora_rank + rot, "kv_a")(h)
             c_kv = self._norm("kv_norm")(latent[..., : self.kv_lora_rank])
-            k_pe = rope(latent[..., None, self.kv_lora_rank :], freqs)  # one a position
+            # one rotary key a position, every head's
+            k_rot = rope(latent[..., None, self.kv_lora_rank :], freqs)[:, :, 0]
+            # [B, S, H * (nope + wide)], a head's keys then its values: handed
+            # on as written, `mla_attend`'s kernel reads its column blocks
             kv = self._dense(self.heads * (nope + wide), "kv_b")(c_kv.astype(self.dtype))
-            kv = kv.reshape(b, seq, self.heads, nope + wide)
-            k = jnp.concatenate(
-                [kv[..., :nope], jnp.broadcast_to(k_pe, (b, seq, self.heads, rot))],
-                axis=-1,
-            )
-            v = kv[..., nope:]
         scale = softmax_scale(nope + rot, self.rope_factor)
-        mixed = causal_attend(q, k, v, scale, read=read)
+        mixed = mla_attend(q_nope, q_rot, kv, k_rot, scale, read=read)
         with jax.named_scope("mla_o"):
-            return self._dense(dim, "o")(mixed.reshape(b, -1, self.heads * wide))
+            return self._dense(dim, "o")(mixed)
 
     def _experts(self, h: jnp.ndarray) -> jnp.ndarray:
         """``h`` float32 ``[T, dim]`` -> float32 ``[T, dim]``."""

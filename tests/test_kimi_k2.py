@@ -26,7 +26,15 @@ from mlops_tpu.models import FAMILIES, abstract_variables, build_model
 from mlops_tpu.monitor.state import fit_monitor
 from mlops_tpu.ops import moe_dispatch
 from mlops_tpu.ops.eva_attention import rope, rope_inv_freq
-from mlops_tpu.ops.mla import causal_attend, softmax_scale, yarn_inv_freq
+from mlops_tpu.ops.mla import (
+    causal_attend,
+    mla_attend,
+    mla_attend_blockwise,
+    mla_attend_xla,
+    softmax_scale,
+    wants_mla_kernel,
+    yarn_inv_freq,
+)
 from mlops_tpu.parallel.bulk import make_bulk_jit, score_dataset
 from mlops_tpu.schema import SCHEMA
 
@@ -198,6 +206,122 @@ def test_causal_attention_matches_a_per_head_loop(block):
     read = np.array([47, 95, 143])
     some = causal_attend(jnp.asarray(q[:, read]), jnp.asarray(k), jnp.asarray(v), 0.3, read=read)
     np.testing.assert_allclose(some, out[:, read], atol=2e-6)
+
+
+# ------------------------------ the blockwise kernel, against the XLA form
+SCALE = softmax_scale(192, 64.0)  # the published 0.14468
+
+
+def kernel_operands(blocks, dtype, batch=2, heads=2, seed=0, block=128):
+    """``q_nope``, ``q_rot``, ``kv``, ``k_rot`` of the published head widths
+    (128 + 64 against 128), as the projections write them."""
+    seq = blocks * block
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32).astype(dtype)
+
+    return (
+        draw(batch, seq, heads, 128), draw(batch, seq, heads, 64),
+        draw(batch, seq, heads * 256), draw(batch, seq, 64),
+    )
+
+
+@pytest.mark.parametrize("dtype,atol", [("float32", 1e-5), ("bfloat16", 2.0**-7)])
+@pytest.mark.parametrize("blocks,heads", [(1, 2), (2, 3), (3, 4), (6, 2)])
+def test_the_kernel_matches_the_xla_form(blocks, heads, dtype, atol):
+    """float32 to 1e-5 (the order of a score's and a row's sums is all
+    that differs); bfloat16 to the rounding of one bfloat16 weight or
+    output: both forms round the weights once before the second product."""
+    operands = kernel_operands(blocks, jnp.dtype(dtype), heads=heads, seed=blocks)
+    with jax.default_matmul_precision("highest"):
+        expected = mla_attend_xla(*operands, SCALE)
+        out = mla_attend_blockwise(*operands, SCALE, block=128, interpret=True)
+    assert out.shape == expected.shape == (2, blocks * 128, heads * 128)
+    assert out.dtype == expected.dtype == jnp.dtype(dtype)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(expected, np.float32), atol=atol
+    )
+
+
+def test_the_kernel_never_lets_a_query_see_a_later_key():
+    """A key and value moved at position ``j`` change no answer before
+    ``j``, bit for bit (in the block of ``j`` by the mask, in the blocks
+    before it because the kernel never reads past a block's own keys), and
+    every answer from ``j`` on."""
+    q_nope, q_rot, kv, k_rot = kernel_operands(3, jnp.float32, batch=1, seed=7)
+
+    def run(kv, k_rot):
+        return np.asarray(
+            mla_attend_blockwise(q_nope, q_rot, kv, k_rot, SCALE, block=128, interpret=True)
+        )
+
+    base = run(kv, k_rot)
+    for j in (1, 127, 128, 200, 383):
+        moved = run(kv.at[:, j].add(1.0), k_rot.at[:, j].add(1.0))
+        assert (moved[:, :j] == base[:, :j]).all(), j
+        assert np.abs(moved[:, j:] - base[:, j:]).max(axis=(0, 2)).min() > 1e-6, j
+
+
+def test_which_shapes_take_the_mla_kernel():
+    mc = REAL["model_config"]
+    widths = (mc["qk_nope_head_dim"], mc["qk_rope_head_dim"], mc["v_head_dim"])
+    assert widths == (128, 64, 128)
+    seq = REAL["records_per_history"] * REAL["tokens_per_record"]
+    assert seq == 3072 and wants_mla_kernel(seq, *widths)
+    assert wants_mla_kernel(512, *widths) and wants_mla_kernel(4096, *widths)
+    # each tiny configuration of this file takes the XLA form, everywhere
+    for config in (tiny_config(), tiny_config(doc_records=64), tiny_config(heads=2)):
+        assert not wants_mla_kernel(
+            config.doc_records * 48, config.qk_nope_head_dim, config.qk_rope_head_dim,
+            config.v_head_dim,
+        )
+    assert wants_mla_kernel(384, 128, 64, 128, block=128)  # the kernel tests' shape
+    assert not wants_mla_kernel(3072, 64, 64, 64)  # half a lane tile a head
+    assert not wants_mla_kernel(3072, 128, 64, 256)  # values of two lane tiles
+    assert not wants_mla_kernel(3072, 128, 192, 128)  # a rotary part past a lane tile
+    assert not wants_mla_kernel(3072, 128, 64, 128, block=192)  # no whole lane tiles a block
+    assert not wants_mla_kernel(3072 - 48, *widths)  # a ragged history: no whole blocks
+    assert not wants_mla_kernel(8192, *widths)  # one visit of 8,192 keys: past VMEM
+    with pytest.raises(ValueError, match="no tiling"):
+        mla_attend_blockwise(*kernel_operands(3, jnp.float32), SCALE, interpret=True)
+
+
+def test_the_read_path_is_the_xla_form_and_the_full_results_rows():
+    """With ``read`` the queries are those positions' alone and the form is
+    XLA's whatever the shape (no `custom_vjp`, no kernel in the trace);
+    the answers are the full result's at those positions."""
+    q_nope, q_rot, kv, k_rot = kernel_operands(1, jnp.float32, batch=1, seed=5, block=512)
+    read = np.array([47, 95, 300, 511])
+    full = mla_attend(q_nope, q_rot, kv, k_rot, SCALE)
+    some = mla_attend(q_nope[:, read], q_rot[:, read], kv, k_rot, SCALE, read=read)
+    assert some.shape == (1, 4, 2 * 128)
+    np.testing.assert_allclose(np.asarray(some), np.asarray(full[:, read]), atol=2e-6)
+    traced = str(jax.make_jaxpr(
+        lambda *xs: mla_attend(*xs, SCALE, read=read)
+    )(q_nope[:, read], q_rot[:, read], kv, k_rot))
+    assert "custom_vjp" not in traced and "pallas_call" not in traced
+    # without ``read`` the same shape is the kernel's wherever a TPU is lowered for
+    assert "custom_vjp" in str(jax.make_jaxpr(
+        lambda *xs: mla_attend(*xs, SCALE)
+    )(q_nope, q_rot, kv, k_rot))
+
+
+def test_the_backward_of_the_mla_kernels_shape_is_the_xla_forms():
+    """At a shape the kernel takes, `mla_attend` is a `custom_vjp` whose
+    backward differentiates the XLA form: the same gradients as autodiff
+    of the XLA form itself (on the CPU the forward is that form too)."""
+    operands = kernel_operands(1, jnp.float32, batch=1, seed=3, block=512)
+    weights = jnp.asarray(np.random.default_rng(4).normal(size=(1, 512, 256)), jnp.float32)
+
+    def loss(attend):
+        return lambda *xs: (attend(*xs, SCALE) * weights).sum()
+
+    got = jax.grad(loss(mla_attend), argnums=(0, 1, 2, 3))(*operands)
+    expected = jax.grad(loss(mla_attend_xla), argnums=(0, 1, 2, 3))(*operands)
+    for g, e in zip(got, expected):
+        assert np.abs(np.asarray(e)).max() > 0
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=1e-6, atol=1e-7)
 
 
 # ------------------------------------------------------- the expert layer

@@ -413,7 +413,12 @@ def test_kimi_k2_chunk_program_compiles_for_v5e_at_published_widths(
     expert's stacked weights or of the embedding, the routed experts'
     products are the compiler's grouped kernel (twelve calls: three
     products in four expert layers), and the counter is the program's third
-    output."""
+    output. `mla_attend` is the Mosaic kernel in the four layers that run
+    whole sequences (the last layer's ``read`` form stays XLA), under the
+    scope the trace's readers key on; no buffer holds a block's scores of
+    64 heads or the keys joined over heads, and the program's temporaries
+    are no more than they were with the XLA form (1.611 GB, PERF.md
+    section 4)."""
     import json
     from pathlib import Path
 
@@ -451,3 +456,45 @@ def test_kimi_k2_chunk_program_compiles_for_v5e_at_published_widths(
     grouped = [line for line in text.splitlines() if "ragged-dot" in line and "custom-call(" in line]
     assert len([line for line in grouped if "metadata" not in line.split("custom-call(")[0]]) >= 12
     assert re.search(r"s32\[2,4,24\]", text), "the routing counter is not an output"
+    kernels = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "mla_attend_fwd" in line.split(" = ")[0]
+    ]
+    assert len(kernels) == 4, len(kernels)
+    for call in kernels:
+        op_name = re.search(r'op_name="([^"]*)"', call).group(1)
+        assert "mla_attend" in op_name.split("/"), op_name  # the scope a trace reads
+    assert not re.search(r"f32\[2,64,(512|256),", text), "a block's scores of 64 heads"
+    # keys joined over heads (``[2, 3072, 64, 192]``) are the last layer's
+    # alone, whose ``read`` form is XLA's; elsewhere the shape is `q_b`'s
+    # output viewed by head
+    for line in text.splitlines():
+        if re.search(r"= bf16\[2,3072,64,192\]", line) and "op_name=" in line:
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert "/block_4/" in op_name or op_name.endswith("mla_q/reshape"), op_name
+    assert memory.temp_size_in_bytes <= 1.611e9, memory.temp_size_in_bytes
+
+
+def test_mla_attention_compiles_for_v5e_at_the_longest_sequence_its_rule_admits(
+    one_chip, no_persistent_cache
+):
+    """`ops/mla.py mla_attend` at 4,096 positions (`MAX_VISIT_KEYS`): the
+    last query block's one visit is 512 x 4,096 float32 scores beside the
+    head's keys and values, the most VMEM the rule lets a step ask for.
+    One position more than whole blocks takes the XLA form: no kernel."""
+    from mlops_tpu.ops.mla import MAX_VISIT_KEYS, mla_attend, softmax_scale
+
+    scale = softmax_scale(192, 64.0)
+
+    def operands(seq):
+        return (
+            S((1, seq, 2, 128), jnp.bfloat16, sharding=one_chip),
+            S((1, seq, 2, 64), jnp.bfloat16, sharding=one_chip),
+            S((1, seq, 2 * 256), jnp.bfloat16, sharding=one_chip),
+            S((1, seq, 64), jnp.bfloat16, sharding=one_chip),
+        )
+
+    attend = lambda *xs: mla_attend(*xs, scale)
+    assert "mla_attend_fwd" in _compile(attend, *operands(MAX_VISIT_KEYS))
+    assert "tpu_custom_call" not in _compile(attend, *operands(1025))
+
