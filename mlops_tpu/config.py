@@ -40,22 +40,30 @@ class DataConfig:
 
 # Causal families that take the zoo's 2-D rows, read every `doc_records`
 # consecutive rows as one history and answer every record.
-HISTORY_FAMILIES = ("evabyte", "kimi_k2")
+HISTORY_FAMILIES = ("evabyte", "kimi_k2", "lfm2_moe")
+# LFM2-8B-A1B's published `layer_types`: which token mixer each of its 24
+# layers runs (family lfm2_moe's default)
+LFM2_LAYER_TYPES = (
+    *("conv", "conv", "full_attention", "conv") * 4,
+    *("conv", "conv", "full_attention") * 2,
+    "conv",
+    "conv",
+)
 
 
 @dataclasses.dataclass
 class ModelConfig:
     family: str = "mlp"  # mlp | ft_transformer | moe | linear | bert |
-    # evabyte | kimi_k2 | gbm | rf
+    # evabyte | kimi_k2 | lfm2_moe | gbm | rf
     hidden_dims: tuple[int, ...] = (256, 256, 128)
     embed_dim: int = 16
     dropout: float = 0.1
     precision: str = "bf16"  # compute dtype on MXU: bf16 | f32 (params stay f32)
     param_dtype: str = "f32"  # the dtype parameters are STORED in, on disk
-    # and on the device: f32 | bf16. Family kimi_k2 alone takes bf16 (its
-    # published share of a layer does not fit a chip at four bytes a
-    # parameter); nothing casts the tree in the program, a product reads
-    # its leaf as stored
+    # and on the device: f32 | bf16. The sparse decoders (kimi_k2,
+    # lfm2_moe) alone take bf16 (what a chip holds of them does not fit it
+    # at four bytes a parameter); nothing casts the tree in the program, a
+    # product reads its leaf as stored
     ensemble_size: int = 1  # >1 wraps the Flax family in a vmapped deep
     # ensemble (models/ensemble.py) — the MXU-native answer to the
     # reference's RandomForest variance reduction; 1 = single model
@@ -75,7 +83,8 @@ class ModelConfig:
     # the LAST record's default from the history (training path
     # `train/long_context.py`); family evabyte is causal, takes the zoo's
     # 2-D rows and answers EVERY record, conditioned on the records before
-    # it in its history. `seq_parallel` routes bert's attention through the
+    # it in its history, as do the token-level decoders kimi_k2 and
+    # lfm2_moe. `seq_parallel` routes bert's attention through the
     # ppermute ring (`parallel.make_ring_attention`) over the mesh's 'seq'
     # axis.
     doc_records: int = 1
@@ -124,13 +133,27 @@ class ModelConfig:
     first_expert: int = 0
     experts_held: int = 0
     vocab_rows: int = 20480
+    # Family lfm2_moe (models/lfm2_moe.py; the gated short convolution
+    # `ops/short_conv.py`, grouped-query attention over
+    # `ops/causal_attention.py`, the expert layer above with no shared
+    # expert). Beside `token_dim`, `heads`, `depth`, `ffn_dim`,
+    # `rope_theta`, `num_experts` and the expert fields above: the
+    # key/value heads the `heads` query heads are grouped over (0 = one a
+    # query head: no grouping); each layer's token mixer, "conv" |
+    # "full_attention", at least `depth` entries (layer i takes the i-th,
+    # so the published list, the default, serves any cut of depth); the
+    # leading layers whose FFN is dense; the convolution's taps.
+    kv_heads: int = 0
+    layer_types: tuple[str, ...] = LFM2_LAYER_TYPES
+    dense_layers: int = 2
+    conv_width: int = 3
 
     @property
     def reads_documents(self) -> bool:
         """True for the 3-D ``doc`` flavour: ``[D, R, C]`` record histories
         in, ONE answer a document out (bundle flavour ``doc``; refused by
         `score-batch` and the serving engine). False for every 2-D,
-        answer-a-row family, the history scorers' (evabyte, kimi_k2)
+        answer-a-row family, the history scorers' (`HISTORY_FAMILIES`)
         included."""
         return self.family not in HISTORY_FAMILIES and self.doc_records > 1
 

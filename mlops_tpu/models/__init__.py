@@ -16,8 +16,14 @@ the TPU-native zoo is:
 - ``kimi_k2``         token-level causal decoder with latent attention
   (MLA) and sparse routed experts beside a shared one, told which experts
   it holds (one chip's share of an expert-parallel layer); histories as
-  ``evabyte`` reads them, a record the 48 tokens ``bert`` reads; the one
-  family whose parameters may be stored in bfloat16
+  ``evabyte`` reads them, a record the 48 tokens ``bert`` reads; the
+  first family whose parameters may be stored in bfloat16
+- ``lfm2_moe``        token-level causal hybrid decoder: each layer's token
+  mixer, by a published per-layer list, a double-gated short convolution
+  or grouped-query attention with normed heads; two dense layers, then
+  sparse routed experts with no shared one (the expert layer of
+  ``kimi_k2``, told which experts it holds); histories, tokens and
+  bfloat16 parameters as ``kimi_k2``
 
 All families share one calling convention:
 ``model.apply(vars, cat_ids[int32 N,C], numeric[f32 N,M], train=...) ->
@@ -38,11 +44,16 @@ from mlops_tpu.models.ensemble import DeepEnsemble
 from mlops_tpu.models.evabyte import EvaByteScorer
 from mlops_tpu.models.ft_transformer import FTTransformer
 from mlops_tpu.models.kimi_k2 import KimiK2Scorer
+from mlops_tpu.models.lfm2_moe import Lfm2MoeScorer
 from mlops_tpu.models.mlp import MLP, LinearModel
 from mlops_tpu.models.moe import MoETransformer
 from mlops_tpu.schema.features import SCHEMA
 
-FAMILIES = ("linear", "mlp", "ft_transformer", "moe", "bert", "evabyte", "kimi_k2")
+FAMILIES = (
+    "linear", "mlp", "ft_transformer", "moe", "bert", "evabyte", "kimi_k2", "lfm2_moe",
+)
+# the sparse decoders, whose parameters may be STORED in bfloat16
+BF16_PARAM_FAMILIES = ("kimi_k2", "lfm2_moe")
 
 
 def build_model(config: ModelConfig) -> nn.Module:
@@ -56,11 +67,12 @@ def build_model(config: ModelConfig) -> nn.Module:
         single = dataclasses.replace(config, ensemble_size=1)
         return DeepEnsemble(member=build_model(single), size=config.ensemble_size)
     dtype = {"bf16": jnp.bfloat16, "f32": jnp.float32}[config.precision]
-    if config.param_dtype != "f32" and config.family != "kimi_k2":
+    if config.param_dtype != "f32" and config.family not in BF16_PARAM_FAMILIES:
         raise ValueError(
             f"family {config.family!r} keeps float32 parameters; "
-            f"model.param_dtype={config.param_dtype!r} is family kimi_k2's"
+            f"model.param_dtype={config.param_dtype!r} is for {BF16_PARAM_FAMILIES}"
         )
+    param_dtype = {"bf16": jnp.bfloat16, "f32": jnp.float32}[config.param_dtype]
     if config.family == "linear":
         return LinearModel(cards=SCHEMA.cards, dtype=dtype)
     if config.family == "mlp":
@@ -138,7 +150,30 @@ def build_model(config: ModelConfig) -> nn.Module:
             records_per_history=config.doc_records,
             rope_theta=config.rope_theta,
             dtype=dtype,
-            param_dtype={"bf16": jnp.bfloat16, "f32": jnp.float32}[config.param_dtype],
+            param_dtype=param_dtype,
+        )
+    if config.family == "lfm2_moe":
+        return Lfm2MoeScorer(
+            cards=SCHEMA.cards,
+            num_numeric=SCHEMA.num_numeric,
+            layer_types=tuple(config.layer_types),
+            hidden=config.token_dim,
+            depth=config.depth,
+            heads=config.heads,
+            kv_heads=config.kv_heads or config.heads,
+            conv_width=config.conv_width,
+            ffn_dim=config.ffn_dim,
+            moe_ffn_dim=config.moe_ffn_dim,
+            num_experts=config.num_experts,
+            experts_per_token=config.experts_per_token,
+            first_expert=config.first_expert,
+            experts_held=config.experts_held or config.num_experts,
+            vocab_rows=config.vocab_rows,
+            records_per_history=config.doc_records,
+            dense_layers=config.dense_layers,
+            rope_theta=config.rope_theta,
+            dtype=dtype,
+            param_dtype=param_dtype,
         )
     from mlops_tpu.models.gbm import SKLEARN_FAMILIES
 
@@ -179,6 +214,7 @@ __all__ = [
     "EvaByteScorer",
     "FTTransformer",
     "KimiK2Scorer",
+    "Lfm2MoeScorer",
     "LinearModel",
     "MLP",
     "MoETransformer",

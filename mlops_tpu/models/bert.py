@@ -143,6 +143,25 @@ def tokenize(
     return jnp.concatenate([cls, pairs, sep], axis=1)
 
 
+def tokenize_histories(
+    cat_ids: jnp.ndarray, numeric: jnp.ndarray, layout: TokenLayout, records_per_history: int
+) -> tuple[jnp.ndarray, np.ndarray]:
+    """Rows as token-level HISTORIES, the history scorers' rule: every
+    ``records_per_history`` consecutive rows (from row 0) are one history,
+    fewer rows than one are one shorter history, and the last is padded
+    with zero rows to a whole one. (int32[N,C], f32[N,M]) -> (int32
+    [histories, records * S] token ids, the position of each record's last
+    token ``[records]``, where a causal model reads that record's answer)."""
+    n = cat_ids.shape[0]
+    records = min(records_per_history, n)
+    histories = -(-n // records)
+    pad = histories * records - n
+    tokens = tokenize(
+        jnp.pad(cat_ids, ((0, pad), (0, 0))), jnp.pad(numeric, ((0, pad), (0, 0))), layout
+    ).reshape(histories, records * layout.seq_len)
+    return tokens, layout.seq_len * np.arange(1, records + 1) - 1
+
+
 @jax.named_scope("embed")
 def apply_embed_front(
     mod: nn.Module,

@@ -27,7 +27,8 @@ under the zoo's calling convention and the repo's own read-out.
   in the last layer (``read``) and everywhere else plain XLA. The
   source's de-interleaving of rotary pairs is a relabelling of weight
   columns and is left out.
-- **The expert layer** (`ops/moe_dispatch.py`) is told ``(first_expert,
+- **The expert layer** (`models/routed_experts.py` over
+  `ops/moe_dispatch.py`) is told ``(first_expert,
   experts_held)``: it routes over all ``num_experts``, weighs over all the
   ``experts_per_token`` chosen, and adds its own experts' part and the
   shared expert; what absent experts would have added is left out. It
@@ -56,48 +57,18 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from mlops_tpu.models.bert import TokenLayout, tokenize
+from mlops_tpu.models.bert import TokenLayout, tokenize_histories
 from mlops_tpu.models.evabyte import RMSNorm
-from mlops_tpu.ops import moe_dispatch
+from mlops_tpu.models.routed_experts import (
+    ROUTING,
+    check_share,
+    routed_experts,
+    routing_counts,
+)
 from mlops_tpu.ops.eva_attention import rope
 from mlops_tpu.ops.mla import mla_attend, softmax_scale, yarn_inv_freq
 
-ROUTING = "routing"  # the collection the expert layers count into
-
-
-class _Stacked(nn.Module):
-    """The held experts' weights of one projection, ``kernel`` ``[held,
-    inputs, outputs]``."""
-
-    experts: int
-    inputs: int
-    outputs: int
-    param_dtype: jnp.dtype
-
-    @nn.compact
-    def __call__(self) -> jnp.ndarray:
-        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
-        return self.param(
-            "kernel", init, (self.experts, self.inputs, self.outputs), self.param_dtype
-        )
-
-
-class _Router(nn.Module):
-    """``kernel`` ``[hidden, experts]`` and the selection ``bias``
-    ``[experts]`` (the source's ``e_score_correction_bias``)."""
-
-    experts: int
-    param_dtype: jnp.dtype
-
-    @nn.compact
-    def __call__(self, hidden: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-        kernel = self.param(
-            "kernel", nn.initializers.lecun_normal(), (hidden, self.experts), self.param_dtype
-        )
-        bias = self.param(
-            "bias", nn.initializers.zeros_init(), (self.experts,), self.param_dtype
-        )
-        return kernel, bias
+ROUTE_EPS = 1e-20  # the router's normaliser (DeepSeek-V3)
 
 
 class KimiBlock(nn.Module):
@@ -166,29 +137,9 @@ class KimiBlock(nn.Module):
 
     def _experts(self, h: jnp.ndarray) -> jnp.ndarray:
         """``h`` float32 ``[T, dim]`` -> float32 ``[T, dim]``."""
-        tokens, dim = h.shape
-        held, width = self.experts_held, self.moe_ffn_dim
-        kernel, bias = _Router(self.num_experts, self.param_dtype, name="router")(dim)
-        routing = moe_dispatch.route(
-            h, kernel, bias, self.experts_per_token, self.routed_scaling
-        )
-        planned = moe_dispatch.plan(routing.experts, self.first_expert, held)
-        if not self.is_initializing():
-            self.sow(ROUTING, "assignments", planned.counts)
-        lowered = h.astype(self.dtype)
-        routed = moe_dispatch.grouped_swiglu(
-            lowered,
-            routing,
-            planned,
-            _Stacked(held, dim, width, self.param_dtype, name="experts_gate")(),
-            _Stacked(held, dim, width, self.param_dtype, name="experts_up")(),
-            _Stacked(held, width, dim, self.param_dtype, name="experts_down")(),
-            moe_dispatch.segment_rows(
-                tokens, self.experts_per_token, self.num_experts, held
-            ),
-        )
+        routed = routed_experts(self, h, scaling=self.routed_scaling, eps=ROUTE_EPS)
         with jax.named_scope("shared_expert"):
-            shared = self._swiglu(lowered, width, "shared_")
+            shared = self._swiglu(h.astype(self.dtype), self.moe_ffn_dim, "shared_")
         return routed + shared.astype(jnp.float32)
 
     @nn.compact
@@ -256,26 +207,17 @@ class KimiK2Scorer(nn.Module):
     ) -> jnp.ndarray:
         layout = self.layout
         stride = self.vocab_rows // layout.vocab_size
-        held_last = self.first_expert + self.experts_held
-        if not stride or not 0 < self.experts_held or held_last > self.num_experts:
+        if not stride:
             raise ValueError(
-                f"{self.vocab_rows} embedding rows for {layout.vocab_size} tokens; "
-                f"experts {self.first_expert}..{held_last} of {self.num_experts}"
+                f"{self.vocab_rows} embedding rows for {layout.vocab_size} tokens"
             )
-        if self.experts_per_token > self.num_experts:
-            raise ValueError(
-                f"{self.experts_per_token} experts a token of {self.num_experts}"
-            )
+        check_share(
+            self.first_expert, self.experts_held, self.num_experts, self.experts_per_token
+        )
         n = cat_ids.shape[0]
-        # whole histories; fewer rows than one history are one shorter history
-        records = min(self.records_per_history, n)
-        histories = -(-n // records)
-        pad = histories * records - n
-        per = layout.seq_len
-        tokens = tokenize(
-            jnp.pad(cat_ids, ((0, pad), (0, 0))), jnp.pad(numeric, ((0, pad), (0, 0))),
-            layout,
-        ).reshape(histories, records * per)
+        tokens, read = tokenize_histories(
+            cat_ids, numeric, layout, self.records_per_history
+        )
         with jax.named_scope("embed"):
             # rows are looked up as stored and widened after: the residual
             # stream is float32, the table is never cast whole
@@ -283,7 +225,6 @@ class KimiK2Scorer(nn.Module):
                 self.vocab_rows, self.hidden, dtype=self.param_dtype,
                 param_dtype=self.param_dtype, name="tok_embed",
             )(tokens * stride).astype(jnp.float32)
-        read = per * np.arange(1, records + 1) - 1  # each record's last token
         for i in range(self.depth):
             x = KimiBlock(
                 heads=self.heads,
@@ -310,12 +251,6 @@ class KimiK2Scorer(nn.Module):
             logits = nn.Dense(
                 1, dtype=jnp.float32, param_dtype=self.param_dtype, name="head"
             )(RMSNorm(unit_offset=False, param_dtype=self.param_dtype, name="final_norm")(x))
-        return logits.reshape(histories * records)[:n]
+        return logits.reshape(-1)[:n]
 
-    @staticmethod
-    def routing_counts(state: dict) -> jnp.ndarray:
-        """int32 ``[expert layers, experts_held]`` from the ``routing``
-        collection one ``apply`` filled, the layers in order."""
-        blocks = state[ROUTING]
-        ordered = sorted(blocks, key=lambda name: int(name.rsplit("_", 1)[1]))
-        return jnp.stack([blocks[name]["assignments"][0] for name in ordered])
+    routing_counts = staticmethod(routing_counts)

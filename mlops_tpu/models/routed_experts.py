@@ -1,0 +1,109 @@
+"""The routed-expert part of a sparse decoder layer, as the token-level
+history scorers build it (`models/kimi_k2.py`, `models/lfm2_moe.py`): the
+router's and the held experts' parameters under the CALLING block's own
+names (``router/{kernel,bias}``, ``experts_{gate,up,down}/kernel``), the
+dropless dispatch of `ops/moe_dispatch.py`, and the routing counter.
+
+A family differs in its block's fields (how many experts a token chooses,
+which experts this process holds) and in what it passes: the scaling and
+the normaliser's epsilon of its router. What else a layer adds (a shared
+expert) is the block's.
+"""
+
+from __future__ import annotations
+
+import jax.numpy as jnp
+from flax import linen as nn
+
+from mlops_tpu.ops import moe_dispatch
+
+ROUTING = "routing"  # the collection the expert layers count into
+
+
+class _Stacked(nn.Module):
+    """The held experts' weights of one projection, ``kernel`` ``[held,
+    inputs, outputs]``."""
+
+    experts: int
+    inputs: int
+    outputs: int
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self) -> jnp.ndarray:
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
+        return self.param(
+            "kernel", init, (self.experts, self.inputs, self.outputs), self.param_dtype
+        )
+
+
+class _Router(nn.Module):
+    """``kernel`` ``[hidden, experts]`` and the selection ``bias``
+    ``[experts]`` (the sources' ``e_score_correction_bias`` /
+    ``expert_bias``)."""
+
+    experts: int
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, hidden: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+        kernel = self.param(
+            "kernel", nn.initializers.lecun_normal(), (hidden, self.experts), self.param_dtype
+        )
+        bias = self.param(
+            "bias", nn.initializers.zeros_init(), (self.experts,), self.param_dtype
+        )
+        return kernel, bias
+
+
+def check_share(
+    first_expert: int, experts_held: int, num_experts: int, experts_per_token: int
+) -> None:
+    """The experts a process holds are some of the layer's, and a token
+    chooses no more experts than there are."""
+    held_last = first_expert + experts_held
+    if not 0 < experts_held or first_expert < 0 or held_last > num_experts:
+        raise ValueError(f"experts {first_expert}..{held_last} of {num_experts}")
+    if experts_per_token > num_experts:
+        raise ValueError(f"{experts_per_token} experts a token of {num_experts}")
+
+
+def routed_experts(
+    block: nn.Module, h: jnp.ndarray, *, scaling: float, eps: float
+) -> jnp.ndarray:
+    """The held experts' weighted part of every token's result: ``h``
+    float32 ``[T, dim]`` -> float32 ``[T, dim]``. Called inside ``block``'s
+    compact ``__call__``: the parameters become the block's, the sizes are
+    the block's own fields (``moe_ffn_dim``, ``num_experts``,
+    ``experts_per_token``, ``first_expert``, ``experts_held``, ``dtype``,
+    ``param_dtype``), and the assignments each held expert got are sown
+    into its ``routing`` collection. ``scaling`` and ``eps`` are the
+    family's router constants."""
+    tokens, dim = h.shape
+    held, width, top_k = block.experts_held, block.moe_ffn_dim, block.experts_per_token
+    kernel, bias = _Router(block.num_experts, block.param_dtype, name="router")(dim)
+    routing = moe_dispatch.route(h, kernel, bias, top_k, scaling, eps)
+    planned = moe_dispatch.plan(routing.experts, block.first_expert, held)
+    if not block.is_initializing():
+        block.sow(ROUTING, "assignments", planned.counts)
+
+    def stacked(inputs: int, outputs: int, name: str) -> jnp.ndarray:
+        return _Stacked(held, inputs, outputs, block.param_dtype, name=name)()
+
+    return moe_dispatch.grouped_swiglu(
+        h.astype(block.dtype),
+        routing,
+        planned,
+        stacked(dim, width, "experts_gate"),
+        stacked(dim, width, "experts_up"),
+        stacked(width, dim, "experts_down"),
+        moe_dispatch.segment_rows(tokens, top_k, block.num_experts, held),
+    )
+
+
+def routing_counts(state: dict) -> jnp.ndarray:
+    """int32 ``[expert layers, experts_held]`` from the ``routing``
+    collection one ``apply`` filled, the layers (``block_<i>``) in order."""
+    blocks = state[ROUTING]
+    ordered = sorted(blocks, key=lambda name: int(name.rsplit("_", 1)[1]))
+    return jnp.stack([blocks[name]["assignments"][0] for name in ordered])

@@ -49,7 +49,8 @@ float32:
   maximum, the exponentials and their sum are float32 and never leave
   VMEM. Only ``o`` is written, ``[B, S, H * 128]``, as the output
   projection reads it.
-- ``mla_attend_xla`` over ``causal_attend``, plain XLA, the definition:
+- ``mla_attend_xla`` over `ops/causal_attention.py causal_attend` (the
+  one causal XLA form of both token-level decoders), the definition:
   whole ``q``, ``k``, ``v`` put together, then one block of queries at a
   time against the keys up to the block's end (the blocks above the
   diagonal are never computed, and one block's scores, heads x block x
@@ -76,9 +77,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from mlops_tpu.ops.attention import NEG_INF
+from mlops_tpu.ops.causal_attention import causal_attend
 from mlops_tpu.ops.kernel_gate import tpu_kernel_or
-
-QUERY_BLOCK = 512
 
 
 def _yarn_correction_dim(rotations: float, dim: int, theta: float, positions: int) -> float:
@@ -111,51 +111,6 @@ def yarn_inv_freq(
 def softmax_scale(qk_head_dim: int, factor: float, mscale_all_dim: float = 1.0) -> float:
     mscale = 0.1 * mscale_all_dim * math.log(factor) + 1.0 if factor > 1 else 1.0
     return qk_head_dim**-0.5 * mscale * mscale
-
-
-def _attend_block(q, k, v, scale: float, query_at: np.ndarray):
-    """One block: q ``[B, Q, H, E]`` at positions ``query_at`` ``[Q]``
-    against k ``[B, K, H, E]``, v ``[B, K, H, D]`` at positions 0..K-1 ->
-    ``[B, Q, H, D]``."""
-    scores = jnp.einsum("bqhe,bkhe->bhqk", q, k, preferred_element_type=jnp.float32)
-    scores = scores * scale
-    visible = np.arange(k.shape[1])[None, :] <= query_at[:, None]
-    scores = jnp.where(jnp.asarray(visible)[None, None], scores, NEG_INF)
-    top = scores.max(axis=-1, keepdims=True)
-    weights = jnp.exp(scores - top)
-    total = weights.sum(axis=-1)  # [B, H, Q]
-    mixed = jnp.einsum(
-        "bhqk,bkhd->bqhd", weights.astype(v.dtype), v, preferred_element_type=jnp.float32
-    )
-    return (mixed / total.transpose(0, 2, 1)[..., None]).astype(v.dtype)
-
-
-@jax.named_scope("mla_attend")
-def causal_attend(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    scale: float,
-    read: np.ndarray | None = None,
-    query_block: int = QUERY_BLOCK,
-) -> jnp.ndarray:
-    """``q``, ``k`` ``[B, S, H, E]``, ``v`` ``[B, S, H, D]`` -> ``[B, S, H,
-    D]`` in ``v``'s dtype. With ``read``, ``q`` holds those positions'
-    queries alone, ``[B, len(read), H, E]``, and so does the result."""
-    seq = q.shape[1]
-    if read is not None:
-        read = np.asarray(read)
-        stop = int(read.max()) + 1
-        return _attend_block(q, k[:, :stop], v[:, :stop], scale, read)
-    out = []
-    for start in range(0, seq, query_block):
-        stop = min(start + query_block, seq)
-        out.append(
-            _attend_block(
-                q[:, start:stop], k[:, :stop], v[:, :stop], scale, np.arange(start, stop)
-            )
-        )
-    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
 
 
 # --------------------------------------------------------------------------

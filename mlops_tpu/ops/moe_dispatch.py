@@ -12,7 +12,8 @@ other chips or for their exchange.
   noaux_tc, one group): ``s = sigmoid(h W_g)`` in float32; the ``k``
   experts chosen are ``top_k(s + b)``, ``b`` being the selection bias (it
   chooses, it never weighs); ``w_i = scaling * s_i / (sum over the chosen
-  of s + 1e-20)``.
+  of s + eps)``, ``eps`` the family's own (DeepSeek-V3's 1e-20 for
+  `models/kimi_k2.py`, 1e-6 for `models/lfm2_moe.py`).
 - ``plan``: the (token, slot) assignments that fell on held experts,
   sorted by expert (a stable sort: tokens ascend within an expert), and
   how many each held expert got. No capacity is set and no token is
@@ -40,8 +41,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-ROUTE_EPS = 1e-20
-
 
 class Routing(NamedTuple):
     experts: jnp.ndarray  # int32 [T, k]: the experts each token chose
@@ -58,7 +57,12 @@ class Plan(NamedTuple):
 
 @jax.named_scope("router")
 def route(
-    h: jnp.ndarray, gate: jnp.ndarray, bias: jnp.ndarray, top_k: int, scaling: float
+    h: jnp.ndarray,
+    gate: jnp.ndarray,
+    bias: jnp.ndarray,
+    top_k: int,
+    scaling: float,
+    eps: float,
 ) -> Routing:
     """``h`` ``[T, d]``, ``gate`` ``[d, E]``, ``bias`` ``[E]`` -> the
     choices and their weights, all in float32."""
@@ -71,7 +75,7 @@ def route(
     )
     _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
-    weights = scaling * chosen / (chosen.sum(axis=-1, keepdims=True) + ROUTE_EPS)
+    weights = scaling * chosen / (chosen.sum(axis=-1, keepdims=True) + eps)
     return Routing(experts.astype(jnp.int32), weights, scores)
 
 

@@ -25,9 +25,9 @@ from mlops_tpu.data.encode import EncodedDataset, Preprocessor
 from mlops_tpu.models import FAMILIES, abstract_variables, build_model
 from mlops_tpu.monitor.state import fit_monitor
 from mlops_tpu.ops import moe_dispatch
+from mlops_tpu.ops.causal_attention import causal_attend
 from mlops_tpu.ops.eva_attention import rope, rope_inv_freq
 from mlops_tpu.ops.mla import (
-    causal_attend,
     mla_attend,
     mla_attend_blockwise,
     mla_attend_xla,
@@ -348,7 +348,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     as the reference's own expert function computes it expert by expert."""
     h, router, gate, up, down = expert_inputs()
     bias = jnp.asarray(np.random.default_rng(4).normal(size=16) * 0.1, jnp.float32)
-    routing = moe_dispatch.route(h, router, bias, 4, 2.827)
+    routing = moe_dispatch.route(h, router, bias, 4, 2.827, 1e-20)
     shares = [routed_part(h, routing, gate, up, down, first, 4)[0] for first in (0, 4, 8, 12)]
     uncut, planned = routed_part(h, routing, gate, up, down, 0, 16)
     assert int(planned.counts.sum()) == 150 * 4  # all held: every choice lands
@@ -369,7 +369,7 @@ def test_no_token_is_dropped_under_a_skewed_router():
     segments are walked to the end and every assignment is computed."""
     h, router, gate, up, down = expert_inputs()
     bias = jnp.zeros(16).at[5].set(5.0).at[6].set(-5.0)  # 5 always chosen, 6 never
-    routing = moe_dispatch.route(h, router, bias, 4, 2.827)
+    routing = moe_dispatch.route(h, router, bias, 4, 2.827, 1e-20)
     rows = 128  # far under the 4 x 150 worst case: several segments
     got, planned = routed_part(h, routing, gate, up, down, 4, 4, rows=rows)
     counts = np.asarray(planned.counts)
@@ -387,9 +387,9 @@ def test_no_token_is_dropped_under_a_skewed_router():
 
 def test_the_bias_moves_the_choice_and_not_the_weights():
     h, router, *_ = expert_inputs()
-    plain = moe_dispatch.route(h, router, jnp.zeros(16), 4, 2.827)
+    plain = moe_dispatch.route(h, router, jnp.zeros(16), 4, 2.827, 1e-20)
     bias = jnp.zeros(16).at[3].set(5.0)
-    biased = moe_dispatch.route(h, router, bias, 4, 2.827)
+    biased = moe_dispatch.route(h, router, bias, 4, 2.827, 1e-20)
     assert bool((biased.experts == 3).any(axis=-1).all())
     assert not bool((plain.experts == 3).any(axis=-1).all())
     scores = np.asarray(jax.nn.sigmoid(h @ router))

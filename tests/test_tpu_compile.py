@@ -475,6 +475,66 @@ def test_kimi_k2_chunk_program_compiles_for_v5e_at_published_widths(
     assert memory.temp_size_in_bytes <= 1.611e9, memory.temp_size_in_bytes
 
 
+def test_lfm2_moe_chunk_program_compiles_for_v5e_at_published_widths(
+    one_chip, no_persistent_cache
+):
+    """The bulk chunk program of `lfm2-8b-a1b.bulk-hist` as the cell runs it
+    (`parallel/bulk.py make_bulk_fused` over `models/lfm2_moe.py` at the
+    configuration file's widths, four histories of 64 records a run,
+    bfloat16 parameters, all 32 experts held): it fits the 75% rule its
+    chunk was sized by (`benchmark/compile_check.py`), nothing holds a
+    float32 copy of the stacked experts, of a convolution's input
+    projection or of the embedding, the routed experts' products are the
+    compiler's grouped kernel (three products in fourteen expert layers),
+    the counter is the program's third output, and grouped attention holds
+    no keys or values repeated to the query heads' count."""
+    import json
+    from pathlib import Path
+
+    from mlops_tpu.config import ModelConfig
+    from mlops_tpu.models import abstract_variables, build_model
+    from mlops_tpu.monitor.state import abstract_monitor_state
+    from mlops_tpu.parallel.bulk import make_bulk_fused
+    from mlops_tpu.schema import SCHEMA
+
+    real = json.loads(
+        (Path(__file__).resolve().parents[1] / "benchmark/configs/lfm2-8b-a1b.json").read_text()
+    )
+    fields = dict(real["model_config"])
+    fields["hidden_dims"] = tuple(fields["hidden_dims"])
+    model = build_model(ModelConfig(**fields))
+    rows = real["deployment"]["score_chunk_rows"]
+    assert rows == 256
+    compiled = (
+        jax.jit(make_bulk_fused(model))
+        .lower(
+            _on(abstract_variables(model), one_chip),
+            _on(abstract_monitor_state(), one_chip),
+            S((), jnp.float32, sharding=one_chip),
+            S((rows, SCHEMA.num_categorical), jnp.int8, sharding=one_chip),
+            S((rows, SCHEMA.num_numeric), jnp.float32, sharding=one_chip),
+            S((rows,), jnp.bool_, sharding=one_chip),
+        )
+        .compile()
+    )
+    memory = compiled.memory_analysis()
+    needed = memory.temp_size_in_bytes + memory.argument_size_in_bytes
+    assert 10.79e9 < memory.argument_size_in_bytes < 10.81e9  # 5.40 B parameters, 2 bytes each
+    assert needed <= 0.75 * 15.75 * 2**30, needed
+    text = compiled.as_text()
+    assert not re.search(
+        r"f32\[32,2048,1792\]|f32\[32,1792,2048\]|f32\[65536,2048\]|f32\[2048,6144\]", text
+    )
+    grouped = [line for line in text.splitlines() if "ragged-dot" in line and "custom-call(" in line]
+    assert len([line for line in grouped if "metadata" not in line.split("custom-call(")[0]]) >= 42
+    assert re.search(r"s32\[2,14,32\]", text), "the routing counter is not an output"
+    # 8 key/value heads stay 8: the only 32-head buffers of a whole run are q's and o's
+    for line in text.splitlines():
+        if re.search(r"= bf16\[4,3072,32,64\]", line) and "op_name=" in line:
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert "/k/" not in op_name and "/v/" not in op_name and "repeat" not in op_name, op_name
+
+
 def test_mla_attention_compiles_for_v5e_at_the_longest_sequence_its_rule_admits(
     one_chip, no_persistent_cache
 ):
