@@ -19,19 +19,36 @@ other chips or for their exchange.
   how many each held expert got. No capacity is set and no token is
   dropped.
 - ``grouped_swiglu``: ``sum_i w_i * down_i(silu(gate_i(h)) * up_i(h))``
-  over the held experts as grouped (ragged) matrix products,
-  `jax.lax.ragged_dot`, over exactly the assignments that fell here. The
-  sorted assignments are walked in SEGMENTS of a fixed number of rows
-  (shapes stay static): a segment gathers its tokens' rows, runs the three
-  products with its own slice of the group sizes, and adds its weighted
-  rows into the result; a segment past the last held assignment is
-  skipped (`lax.cond`), so the work follows the load. The worst case
-  (every token choosing only held experts) is ``min(k, held) * tokens``
-  rows and is walked in full: routing skew costs time, never an answer.
+  over the held experts as grouped (ragged) matrix products over exactly
+  the assignments that fell here. The sorted assignments are walked in
+  SEGMENTS of a fixed number of rows (shapes stay static): a segment
+  gathers its tokens' rows, runs the three products with its own slice of
+  the group sizes (`segment_products`), and adds its weighted rows into
+  the result; a segment past the last held assignment is skipped
+  (`lax.cond`), so the work follows the load. The worst case (every token
+  choosing only held experts) is ``min(k, held) * tokens`` rows and is
+  walked in full: routing skew costs time, never an answer.
+
+Which form of a segment's products runs where:
+
+- `ops/expert_products.py`, two Pallas kernels (Mosaic): where the
+  computation is lowered for a TPU (`kernel_gate.tpu_kernel_or`) and
+  `wants_grouped_kernel` admits the segment (widths of whole 128-lane
+  tiles, rows of whole row tiles; both cells' published shapes are such).
+  ``experts_gate_up_fwd`` makes ``gate`` and ``up`` in one pass over the
+  rows with the SwiGLU in its epilogue (the two float32 ``[rows, f]``
+  never reach HBM), ``experts_down_fwd`` the third product; the tiles
+  follow the rows an expert gets (`grouped_tiles`).
+- ``segment_products_xla``, three `jax.lax.ragged_dot` calls and the
+  activation between them, the definition: every other platform, every
+  other shape (each tiny configuration of the tests), and the backward
+  everywhere (`segment_products` is a ``custom_vjp`` where the kernels are
+  the forward; the XLA form is recomputed and differentiated).
 
 The scopes ``router``, ``moe_dispatch`` (sort, gather), ``experts`` (the
-three grouped products) and ``moe_combine`` are what a device trace
-carries (`benchmark/layer_metrics/`).
+three grouped products: the kernels' calls carry it; the compiler's own
+``ragged-dot`` call carries none) and ``moe_combine`` are what a device
+trace carries (`benchmark/layer_metrics/`).
 """
 
 from __future__ import annotations
@@ -40,6 +57,10 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from mlops_tpu.ops.expert_products import grouped_swiglu_kernels, wants_grouped_kernel
+from mlops_tpu.ops.kernel_gate import tpu_kernel_or
 
 
 class Routing(NamedTuple):
@@ -100,6 +121,78 @@ def segment_rows(tokens: int, top_k: int, num_experts: int, experts_held: int) -
     return min(worst, -(-2 * even // 128) * 128)
 
 
+def segment_products_xla(
+    taken: jnp.ndarray,
+    gate: jnp.ndarray,
+    up: jnp.ndarray,
+    down: jnp.ndarray,
+    sizes: jnp.ndarray,
+) -> jnp.ndarray:
+    """A segment's three grouped products in plain XLA, the definition:
+    ``taken`` ``[rows, d]`` sorted by expert, ``sizes`` ``[held]`` rows an
+    expert -> float32 ``[rows, d]``; the rows past the sizes' sum are
+    undefined."""
+    dtype = taken.dtype
+    gated = jax.lax.ragged_dot(
+        taken, gate.astype(dtype), sizes, preferred_element_type=jnp.float32
+    )
+    lifted = jax.lax.ragged_dot(
+        taken, up.astype(dtype), sizes, preferred_element_type=jnp.float32
+    )
+    return jax.lax.ragged_dot(
+        (jax.nn.silu(gated) * lifted).astype(dtype),
+        down.astype(dtype),
+        sizes,
+        preferred_element_type=jnp.float32,
+    )
+
+
+@jax.jit
+def _kernel_or_xla(taken, gate, up, down, sizes):
+    """Jitted so that a model traces and lowers the kernels ONCE for all
+    its layers (`ops/mla.py _kernel_or_xla`: unjitted, a kernel's body is
+    traced layer by layer, and a process's set-up pays)."""
+    return tpu_kernel_or(grouped_swiglu_kernels, segment_products_xla, taken, gate, up, down, sizes)
+
+
+_segment_products = jax.custom_vjp(_kernel_or_xla)
+
+
+def _segment_products_fwd(*operands):
+    return _kernel_or_xla(*operands), operands
+
+
+def _segment_products_bwd(operands, g):
+    """No backward kernel: the XLA form, recomputed, is differentiated
+    (the sizes are whole numbers and get no cotangent)."""
+    *arrays, sizes = operands
+    _, pull = jax.vjp(lambda *xs: segment_products_xla(*xs, sizes), *arrays)
+    return (*pull(g), np.zeros(sizes.shape, jax.dtypes.float0))
+
+
+_segment_products.defvjp(_segment_products_fwd, _segment_products_bwd)
+
+
+def segment_products(
+    taken: jnp.ndarray,
+    gate: jnp.ndarray,
+    up: jnp.ndarray,
+    down: jnp.ndarray,
+    sizes: jnp.ndarray,
+) -> jnp.ndarray:
+    """`segment_products_xla`'s answer. Where `wants_grouped_kernel`
+    admits the shape and the computation is lowered for a TPU
+    (`kernel_gate`), the forward is `ops/expert_products.py`'s two kernels
+    and the backward the XLA form's; everywhere else the XLA form is
+    both."""
+    rows, d = taken.shape
+    held, _, f = gate.shape
+    dtype = taken.dtype
+    if not wants_grouped_kernel(rows, held, d, f, dtype.itemsize):
+        return segment_products_xla(taken, gate, up, down, sizes)
+    return _segment_products(taken, gate.astype(dtype), up.astype(dtype), down.astype(dtype), sizes)
+
+
 def grouped_swiglu(
     h: jnp.ndarray,
     routing: Routing,
@@ -116,7 +209,6 @@ def grouped_swiglu(
     tokens, top_k = routing.experts.shape
     total = planned.offsets[-1]
     flat_weights = routing.weights.reshape(-1)
-    dtype = h.dtype
     segments = -(-min(top_k, gate.shape[0]) * tokens // rows)
 
     def segment(index, result):
@@ -132,18 +224,7 @@ def grouped_swiglu(
                 edges = jnp.clip(planned.offsets, start, start + rows)
                 sizes = edges[1:] - edges[:-1]
             with jax.named_scope("experts"):
-                gated = jax.lax.ragged_dot(
-                    taken, gate.astype(dtype), sizes, preferred_element_type=jnp.float32
-                )
-                lifted = jax.lax.ragged_dot(
-                    taken, up.astype(dtype), sizes, preferred_element_type=jnp.float32
-                )
-                out = jax.lax.ragged_dot(
-                    (jax.nn.silu(gated) * lifted).astype(dtype),
-                    down.astype(dtype),
-                    sizes,
-                    preferred_element_type=jnp.float32,
-                )
+                out = segment_products(taken, gate, up, down, sizes)
             with jax.named_scope("moe_combine"):
                 # rows past the last held assignment belong to no group: a
                 # grouped product leaves them undefined, so they are zeroed

@@ -402,6 +402,21 @@ def test_eva_attention_compiles_for_v5e_at_evabytes_shape(one_chip, no_persisten
     assert "eva_attend_fwd" in text
 
 
+def _assert_the_experts_products_are_the_kernels(text: str, layers: int) -> None:
+    """A segment's grouped products are `ops/expert_products.py`'s two
+    Mosaic calls a layer (`wants_grouped_kernel` admits both cells'
+    shapes), each under the scope ``experts`` that a trace's readers key
+    on, and no call of the compiler's own grouped product is left."""
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    for kernel in ("experts_gate_up_fwd", "experts_down_fwd"):
+        mine = [line for line in calls if kernel in line.split(" = ")[0]]
+        assert len(mine) == layers, (kernel, len(mine))
+        for call in mine:
+            op_name = re.search(r'op_name="([^"]*)"', call).group(1)
+            assert "experts" in op_name.split("/"), op_name
+    assert "ragged-dot" not in text
+
+
 def test_kimi_k2_chunk_program_compiles_for_v5e_at_published_widths(
     one_chip, no_persistent_cache
 ):
@@ -411,14 +426,14 @@ def test_kimi_k2_chunk_program_compiles_for_v5e_at_published_widths(
     bfloat16 parameters): it fits the 75% rule its chunk was sized by
     (`benchmark/compile_check.py`), nothing holds a float32 copy of a held
     expert's stacked weights or of the embedding, the routed experts'
-    products are the compiler's grouped kernel (twelve calls: three
-    products in four expert layers), and the counter is the program's third
-    output. `mla_attend` is the Mosaic kernel in the four layers that run
+    products are the two grouped kernels (eight calls: gate and up with the
+    SwiGLU, then down, in four expert layers), and the counter is the
+    program's third output. `mla_attend` is the Mosaic kernel in the four layers that run
     whole sequences (the last layer's ``read`` form stays XLA), under the
     scope the trace's readers key on; no buffer holds a block's scores of
     64 heads or the keys joined over heads, and the program's temporaries
-    are no more than they were with the XLA form (1.611 GB, PERF.md
-    section 4)."""
+    are no more than they were before the grouped kernels (0.867 GB; 1.611
+    with the XLA form of `mla_attend`, PERF.md section 4)."""
     import json
     from pathlib import Path
 
@@ -453,8 +468,7 @@ def test_kimi_k2_chunk_program_compiles_for_v5e_at_published_widths(
     assert needed <= 0.75 * 15.75 * 2**30, needed
     text = compiled.as_text()
     assert not re.search(r"f32\[24,7168,2048\]|f32\[24,2048,7168\]|f32\[20480,7168\]", text)
-    grouped = [line for line in text.splitlines() if "ragged-dot" in line and "custom-call(" in line]
-    assert len([line for line in grouped if "metadata" not in line.split("custom-call(")[0]]) >= 12
+    _assert_the_experts_products_are_the_kernels(text, layers=4)
     assert re.search(r"s32\[2,4,24\]", text), "the routing counter is not an output"
     kernels = [
         line for line in text.splitlines()
@@ -472,7 +486,7 @@ def test_kimi_k2_chunk_program_compiles_for_v5e_at_published_widths(
         if re.search(r"= bf16\[2,3072,64,192\]", line) and "op_name=" in line:
             op_name = re.search(r'op_name="([^"]*)"', line).group(1)
             assert "/block_4/" in op_name or op_name.endswith("mla_q/reshape"), op_name
-    assert memory.temp_size_in_bytes <= 1.611e9, memory.temp_size_in_bytes
+    assert memory.temp_size_in_bytes <= 0.867e9, memory.temp_size_in_bytes
 
 
 def test_lfm2_moe_chunk_program_compiles_for_v5e_at_published_widths(
@@ -485,9 +499,10 @@ def test_lfm2_moe_chunk_program_compiles_for_v5e_at_published_widths(
     chunk was sized by (`benchmark/compile_check.py`), nothing holds a
     float32 copy of the stacked experts, of a convolution's input
     projection or of the embedding, the routed experts' products are the
-    compiler's grouped kernel (three products in fourteen expert layers),
-    the counter is the program's third output, and grouped attention holds
-    no keys or values repeated to the query heads' count."""
+    two grouped kernels (gate and up with the SwiGLU, then down, in
+    fourteen expert layers) and leave no float32 ``[rows, f]`` in HBM, the
+    counter is the program's third output, and grouped attention holds no
+    keys or values repeated to the query heads' count."""
     import json
     from pathlib import Path
 
@@ -525,8 +540,11 @@ def test_lfm2_moe_chunk_program_compiles_for_v5e_at_published_widths(
     assert not re.search(
         r"f32\[32,2048,1792\]|f32\[32,1792,2048\]|f32\[65536,2048\]|f32\[2048,6144\]", text
     )
-    grouped = [line for line in text.splitlines() if "ragged-dot" in line and "custom-call(" in line]
-    assert len([line for line in grouped if "metadata" not in line.split("custom-call(")[0]]) >= 42
+    _assert_the_experts_products_are_the_kernels(text, layers=14)
+    # gate's and up's float32 products of a run stay in VMEM, and the
+    # temporaries are no more than the XLA form's were (PERF.md section 4)
+    assert "f32[49152,1792]" not in text
+    assert memory.temp_size_in_bytes <= 1.145e9, memory.temp_size_in_bytes
     assert re.search(r"s32\[2,14,32\]", text), "the routing counter is not an output"
     # 8 key/value heads stay 8: the only 32-head buffers of a whole run are q's and o's
     for line in text.splitlines():
