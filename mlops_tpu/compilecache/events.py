@@ -6,8 +6,9 @@ while something traces or compiles, never when a compiled program runs.
 A caller that wants to know what ONE piece of work cost takes
 ``compile_counter().snapshot()`` before and after it and reads
 ``CompileCounter.delta``: `parallel/bulk.py score_dataset` does, so every
-bulk job says what it re-traced (`BulkScoreResult.compile_events`, and the
-``mlops:bulk.compile_events`` marker in a profiler trace).
+bulk job says what it re-traced (``compile_events`` of the job's record:
+`BulkScoreResult`, ``job_log()``, and the ``mlops:bulk.compile_events``
+marker in a profiler trace).
 
 A function traced inside another one's trace (every ``jnp`` function is
 its own ``jit``) reports a duration that its caller's duration already
@@ -22,6 +23,8 @@ JAX reads its persistent cache INSIDE the interval it reports as
 from __future__ import annotations
 
 import threading
+
+from mlops_tpu.utils.timing import sums_delta
 
 # tpulint Layer-3 manifest: two leaf locks, never held together. The
 # counter's lock guards the sums; the module's guards the one registration.
@@ -38,6 +41,12 @@ DURATIONS = {
 COUNTS = {
     "/jax/compilation_cache/cache_hits": "cache_hits",
     "/jax/compilation_cache/cache_misses": "cache_misses",
+    # every compile for which JAX asked its persistent cache: requests less
+    # hits is what the backend compiled. Misses will not do for that: JAX
+    # counts one only where it STORES the executable, so a program under
+    # the cache's minimum compile time, compiled anew in every process and
+    # never stored, is neither a hit nor a miss.
+    "/jax/compilation_cache/compile_requests_use_cache": "cache_requests",
 }
 
 
@@ -99,14 +108,7 @@ class CompileCounter:
     def delta(before: dict, after: dict) -> dict:
         """What happened between two snapshots: the totals' differences,
         and the names of the programs traced in between."""
-        out = {
-            key: (
-                round(value - before["totals"][key], 6)
-                if isinstance(value, float)
-                else value - before["totals"][key]
-            )
-            for key, value in after["totals"].items()
-        }
+        out = sums_delta(before["totals"], after["totals"])
         out["programs"] = [
             name
             for name, traced in after["programs"].items()

@@ -49,7 +49,13 @@ the CALLER's thread). Every link is a ``queue.Queue(maxsize=depth)``:
 Per-stage wall/occupancy timing (`utils/timing.py StageClock`) comes back
 in the returned ``PipelineStats`` so overlap wins are measured, not
 asserted: occupancies sum to ~1.0 when serial and exceed it when
-overlapped, and the largest occupancy names the bottleneck stage.
+overlapped, and the largest occupancy names the bottleneck stage. A
+worker that is not busy is blocked on its input queue (nothing to do:
+upstream is the pace) or on its output queue (downstream is): every
+``get`` and ``put`` of a worker is timed and summed per stage and side
+(``wait_in_s``, ``wait_out_s``), with the longest wait of each side and
+the ordinal of the stage execution it belongs to, so a stall is located
+by stage and chunk. The serial mode has no queues and reports zero waits.
 
 Concurrency discipline (tpulint Layer 3): this executor deliberately owns
 NO explicit locks — all cross-thread state rides the bounded
@@ -105,15 +111,8 @@ class PipelineStats:
     depth: int
     wall_s: float
     items: int  # items the sink consumed
-    stages: dict[str, dict[str, float]]  # name -> busy_s / items / occupancy
-
-    def as_dict(self) -> dict[str, Any]:
-        return {
-            "depth": self.depth,
-            "wall_s": round(self.wall_s, 4),
-            "items": self.items,
-            "stages": self.stages,
-        }
+    stages: dict[str, dict[str, float]]  # name -> busy_s / items /
+    # occupancy / waits by side (`utils/timing.py StageClock.report`)
 
 
 class _Failure:
@@ -127,6 +126,22 @@ class _Failure:
 
 
 _DONE = object()  # end-of-stream sentinel; exactly one per producer
+
+
+def _get(inq, clock, name):
+    """``inq.get()``, its blocked seconds into the stage's ``wait_in_s``."""
+    start = time.perf_counter()
+    item = inq.get()
+    clock.waited(name, "in", time.perf_counter() - start)
+    return item
+
+
+def _put(outq, item, clock, name) -> None:
+    """``outq.put(item)``, its blocked seconds into the stage's
+    ``wait_out_s``."""
+    start = time.perf_counter()
+    outq.put(item)
+    clock.waited(name, "out", time.perf_counter() - start)
 
 
 def run_pipeline(
@@ -215,7 +230,7 @@ def _run_threaded(
         # The sink loop consumes to _DONE UNCONDITIONALLY — even after a
         # failure — so upstream workers can always finish their drain.
         while True:
-            item = final.get()
+            item = _get(final, clock, sink_name)
             if item is _DONE:
                 break
             if isinstance(item, _Failure):
@@ -256,25 +271,25 @@ def _pump_source(source, out, stop, clock, name) -> None:
                 item = next(iterator, _DONE)
             if item is _DONE:
                 break
-            out.put(item)
+            _put(out, item, clock, name)
     # Captured as a _Failure and re-raised by the caller.  # tpulint: disable=TPU201
     except BaseException as exc:
         stop.set()
-        out.put(_Failure(name, exc))
+        _put(out, _Failure(name, exc), clock, name)
     finally:
-        out.put(_DONE)
+        _put(out, _DONE, clock, name)
 
 
 def _run_stage(stage: Stage, inq, outq, stop, clock) -> None:
     draining = False
     try:
         while True:
-            item = inq.get()
+            item = _get(inq, clock, stage.name)
             if item is _DONE:
                 break
             if isinstance(item, _Failure):
                 stop.set()
-                outq.put(item)
+                _put(outq, item, clock, stage.name)
                 draining = True
                 continue
             if draining or stop.is_set():
@@ -286,14 +301,14 @@ def _run_stage(stage: Stage, inq, outq, stop, clock) -> None:
                 else:
                     with clock.stage(stage.name):
                         out = stage.fn(item)
-                    outq.put(out)
+                    _put(outq, out, clock, stage.name)
             # Captured as a _Failure and re-raised by the caller.  # tpulint: disable=TPU201
             except BaseException as exc:
                 stop.set()
-                outq.put(_Failure(stage.name, exc))
+                _put(outq, _Failure(stage.name, exc), clock, stage.name)
                 draining = True
     finally:
-        outq.put(_DONE)
+        _put(outq, _DONE, clock, stage.name)
 
 
 def _run_batch(stage: Stage, first, inq, outq, stop, clock) -> bool:
@@ -323,12 +338,12 @@ def _run_batch(stage: Stage, first, inq, outq, stop, clock) -> bool:
     # Captured as a _Failure and re-raised by the caller.  # tpulint: disable=TPU201
     except BaseException as exc:
         stop.set()
-        outq.put(_Failure(stage.name, exc))
+        _put(outq, _Failure(stage.name, exc), clock, stage.name)
         outs = []
     for out in outs:
-        outq.put(out)
+        _put(outq, out, clock, stage.name)
     if pending is not None:
         stop.set()
-        outq.put(pending)
+        _put(outq, pending, clock, stage.name)
         # Keep draining on the normal loop; the failure is already forwarded.
     return saw_done

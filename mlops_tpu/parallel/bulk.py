@@ -22,11 +22,20 @@ Mechanics (scaling-book recipe):
   saturates (any tiny shift -> p≈0), so it is computed once over a bounded
   uniform row sample — same semantics as the serving monitor, bounded cost.
 
-Where a job's time goes (always on): ``BulkScoreResult.phases`` holds the
-seconds of its four phases and ``compile_events`` what it traced, lowered,
-compiled and took from JAX's persistent cache (`compilecache/events.py`),
-with ``chunk_program_reused``: 1 where the job found its chunk program
-compiled for its signature and so warmed nothing.
+Where a job's time goes (always on): at its end a job builds ONE plain
+dict, its record (``job_record``), and everything that reports the job
+reads it: ``BulkScoreResult`` (``record``; ``phases``, ``compile_events``
+and ``pipeline`` are views of it), ``summary()``, the
+``mlops:bulk.compile_events`` marker, and the process's bounded log
+``job_log()``, which outlives the call, so a caller that drops the result
+(a warm-up job, an untraced benchmark window) can still be asked where the
+time went. ``phases`` holds the seconds of the four phases;
+``compile_events`` what the job traced, lowered, compiled and took from
+JAX's persistent cache (`compilecache/events.py`), with
+``chunk_program_reused``: 1 where the job found its chunk program compiled
+for its signature and so warmed nothing; ``stages`` the executor's busy
+seconds and queue waits by side (`utils/timing.py StageClock`); ``pauses``
+the garbage collector's share (`utils/timing.py PauseCounter`).
 In a profiler trace the same phases are ``mlops:bulk.<phase>`` spans
 inside one ``mlops:bulk.job``, the pipeline's stage executions are
 ``mlops:pipe.<stage>`` spans on their own threads, and every one of them
@@ -69,6 +78,7 @@ from mlops_tpu.data.encode import EncodedDataset
 from mlops_tpu.monitor.state import drift_scores, outlier_flags
 from mlops_tpu.parallel.sharding import batch_sharding, replicated
 from mlops_tpu.schema import SCHEMA
+from mlops_tpu.utils.timing import PauseCounter, pause_counter
 
 # Chunks a batched fetch stage may drain in one device_get (and how far
 # the compute stage may dispatch ahead of it) — the wave bound that
@@ -83,8 +93,9 @@ FETCH_WAVE = 32
 # the pipelined sweep; the drift sample.
 PHASES = ("build", "warmup", "sweep", "drift")
 
-# tpulint Layer-3 manifest: one leaf lock around the keep's table.
-TPULINT_LOCK_ORDER = {"ChunkProgramKeep": ("_lock",)}
+# tpulint Layer-3 manifest: two leaf locks, never held together: one
+# around the keep's table, one around the job log.
+TPULINT_LOCK_ORDER = {"ChunkProgramKeep": ("_lock",), "JobLog": ("_lock",)}
 
 # Chunk programs kept compiled from job to job: one per (program body,
 # model architecture, mesh) a process scores with. A process serves one
@@ -145,6 +156,38 @@ class ChunkProgramKeep:
 
 CHUNK_PROGRAMS = ChunkProgramKeep(KEPT_CHUNK_PROGRAMS)
 
+# Job records a process keeps (``job_log``): a benchmark window is 4 to 21
+# jobs, a serving host's nightly sweep a few files.
+LOGGED_JOBS = 64
+
+
+class JobLog:
+    """The records of the newest jobs of this process, oldest out. A record
+    is numbers and strings alone (``job_record``): nothing of a job's
+    scorer, bundle or dataset is kept alive by it."""
+
+    def __init__(self, capacity: int) -> None:
+        self._lock = threading.Lock()
+        self._records: collections.deque[dict] = collections.deque(maxlen=capacity)
+
+    def append(self, record: dict) -> None:
+        with self._lock:
+            self._records.append(record)
+
+    def records(self) -> list[dict]:
+        with self._lock:
+            return list(self._records)
+
+
+JOB_LOG = JobLog(LOGGED_JOBS)
+
+
+def job_log() -> list[dict]:
+    """The records of this process's newest ``LOGGED_JOBS`` bulk jobs,
+    oldest first."""
+    return JOB_LOG.records()
+
+
 _JOB_IDS = itertools.count(1)
 
 
@@ -193,33 +236,61 @@ class BulkScoreResult:
     elapsed_s: float  # the pipelined sweep's wall time: no scorer build, no
     # compile or warm-up chunk, no drift sample (``phases`` has those)
     path: str = "exact"  # "exact" | "distilled" | "quant" — which params scored
-    pipeline: dict[str, Any] | None = None  # per-stage busy/occupancy
-    # timings from the streaming executor (None for the empty dataset)
     compile_cache: dict[str, Any] | None = None  # hit/miss/bypass counts +
     # per-program compile vs deserialize wall time (compilecache/cache.py)
     # when the sweep ran against a persistent executable cache
-    phases: dict[str, float] | None = None  # seconds of each of PHASES
-    compile_events: dict[str, Any] | None = None  # what the job traced,
-    # lowered, compiled and loaded (`compilecache/events.py
-    # CompileCounter.delta`), and ``chunk_program_reused``: 1 where it found
-    # its chunk program compiled for its signature (`warm_chunk_scorer`)
+    record: dict[str, Any] | None = None  # the job's own account of itself
+    # (``job_record``; also the newest entry of ``job_log()``); None for
+    # the empty dataset, which runs no job
     routing: dict[str, Any] | None = None  # a model with sparse experts:
     # ``tokens`` the job's chunk runs read (padding included; a layer that
     # computes the read positions alone routes only those),
     # ``assignments_held`` of the (token, slot) choices that fell on the
     # experts held here, ``max_expert_load`` / ``mean_expert_load`` a held
-    # expert and layer, ``per_layer`` ``[expert layers][experts held]``,
-    # and ``expert_runs`` (``expert_runs_per_layer``): the (chunk run,
-    # expert) pairs in which a held expert got a token, so had to be read
+    # expert and layer, ``expert_runs``: the (chunk run, expert) pairs in
+    # which a held expert got a token, so had to be read (these scalars
+    # are the record's ``routing``), and the two tables ``per_layer``
+    # ``[expert layers][experts held]`` and ``expert_runs_per_layer``
 
     @property
     def rows_per_s(self) -> float:
         return self.rows / max(self.elapsed_s, 1e-9)
 
+    @property
+    def phases(self) -> dict[str, float] | None:
+        """Seconds of each of PHASES."""
+        return self.record and self.record["phases"]
+
+    @property
+    def compile_events(self) -> dict[str, Any] | None:
+        """What the job traced, lowered, compiled and loaded
+        (`compilecache/events.py CompileCounter.delta`), and
+        ``chunk_program_reused``: 1 where it found its chunk program
+        compiled for its signature (`warm_chunk_scorer`)."""
+        return self.record and self.record["compile_events"]
+
+    @property
+    def pipeline(self) -> dict[str, Any] | None:
+        """The streaming executor's run (`data/pipeline_exec.py
+        PipelineStats`): its depth, the sweep's seconds, the chunks stored
+        and per stage busy seconds, occupancy and queue waits."""
+        return self.record and {
+            "depth": self.record["depth"],
+            "wall_s": round(self.elapsed_s, 4),
+            "items": self.record["chunks"],
+            "stages": self.record["stages"],
+        }
+
     def summary(self) -> dict[str, Any]:
+        """What `score-batch` prints: the answers' rates, and the job's
+        record whole (its ``stages`` under ``pipeline``)."""
+        job = dict(self.record or {"rows": self.rows, "path": self.path})
+        if job.pop("stages", None) is not None:
+            job["pipeline"] = self.pipeline
+        if self.routing is not None:
+            job["routing"] = self.routing  # the scalars and the two tables
         return {
-            "rows": self.rows,
-            "path": self.path,
+            **job,
             "elapsed_s": round(self.elapsed_s, 4),
             "rows_per_s": round(self.rows_per_s, 1),
             "default_rate": (
@@ -232,22 +303,10 @@ class BulkScoreResult:
                 k: round(v, 6) for k, v in self.feature_drift.items()
             },
             **(
-                {"pipeline": self.pipeline} if self.pipeline is not None else {}
-            ),
-            **(
                 {"compile_cache": self.compile_cache}
                 if self.compile_cache is not None
                 else {}
             ),
-            **(
-                {
-                    "phases": {k: round(v, 4) for k, v in self.phases.items()},
-                    "compile_events": self.compile_events,
-                }
-                if self.phases is not None
-                else {}
-            ),
-            **({"routing": self.routing} if self.routing is not None else {}),
         }
 
 
@@ -660,23 +719,30 @@ def score_dataset(
         )
     config = bundle.model_config
     chunk = mesh_chunk_rows(chunk_rows, mesh, config.history_rows)
-    histories = -(-n // config.history_rows)
-    job = next_job_id()
+    # what the job is, for its span and its record alike
+    head = {
+        "job": next_job_id(),
+        "rows": n,
+        "chunk_rows": chunk,
+        "chunks": -(-n // chunk),
+        "path": path,
+        "histories": -(-n // config.history_rows),
+    }
+    job = head["job"]
     phases: dict[str, float] = {}
-    counter = compile_counter()
-    traced_before = counter.snapshot()
+    counter, pauses = compile_counter(), pause_counter()
+    traced_before, paused_before = counter.snapshot(), pauses.snapshot()
+    started = time.perf_counter()
     with jax.profiler.TraceAnnotation(
         "mlops:bulk.job",
-        job=job,
+        **head,
         pid=os.getpid(),
-        rows=n,
-        chunk_rows=chunk,
-        chunks=-(-n // chunk),
-        path=path,
-        histories=histories,
         # the text the model reads of the job, padding apart (0 for a
         # model that reads no text)
         bytes=n * getattr(bundle.model, "bytes_per_row", 0),
+        # the record's clock (``time.perf_counter``) at the span's opening:
+        # ties a record of ``job_log()`` to the trace's clock
+        started=started,
     ):
         with _phase(phases, "build", job):
             scorer = make_chunk_scorer(
@@ -770,7 +836,6 @@ def score_dataset(
                 sink_name="store",
                 span_attrs={"job": job},
             )
-        elapsed = pipe.wall_s
 
         with _phase(phases, "drift", job):
             # Dataset-level drift on a bounded uniform sample (see module
@@ -790,15 +855,22 @@ def score_dataset(
                 )
             )
             routing = _routing_summary(scorer, bundle.model, job)
-        compile_events = {
-            **CompileCounter.delta(traced_before, counter.snapshot()),
-            "chunk_program_reused": int(reused),
-        }
+        record = job_record(
+            head, started, phases, pipe,
+            compile_events={
+                **CompileCounter.delta(traced_before, counter.snapshot()),
+                "chunk_program_reused": int(reused),
+            },
+            pauses=PauseCounter.delta(paused_before, pauses.snapshot()),
+            routing=routing,
+        )
+        JOB_LOG.append(record)
         # a marker at the job's end: what the job traced, on the trace's clock
+        events = record["compile_events"]
         with jax.profiler.TraceAnnotation(
             "mlops:bulk.compile_events",
             job=job,
-            **{**compile_events, "programs": "|".join(compile_events["programs"])},
+            **{**events, "programs": "|".join(events["programs"])},
         ):
             pass
     return BulkScoreResult(
@@ -808,16 +880,49 @@ def score_dataset(
             zip(SCHEMA.feature_names, drift.astype(float).tolist())
         ),
         rows=n,
-        elapsed_s=elapsed,
+        elapsed_s=pipe.wall_s,
         path=path,
-        pipeline=pipe.as_dict(),
         compile_cache=(
             compile_cache.stats() if compile_cache is not None else None
         ),
-        phases=phases,
-        compile_events=compile_events,
+        record=record,
         routing=routing,
     )
+
+
+def job_record(
+    head: dict, started: float, phases: dict[str, float], pipe,
+    compile_events: dict, pauses: dict, routing: dict | None,
+) -> dict[str, Any]:
+    """A job's own account of itself, built once at its end: numbers,
+    strings and lists and dicts of them, never an array nor a reference to
+    the scorer, the bundle or the dataset (a job's scorer dies with the
+    job: `tests/test_bulk_dp4.py`). ``head``: ``job``, ``rows``,
+    ``chunk_rows``, ``chunks``, ``path``, ``histories``, as on the
+    ``mlops:bulk.job`` span; ``started``: ``time.perf_counter()`` at that
+    span's opening, and ``wall_s`` from there to here; ``phases``;
+    ``compile_events``; the executor's ``depth`` and ``stages`` (busy
+    seconds, occupancy and queue waits by side, `utils/timing.py
+    StageClock.report`); ``pauses``; ``routing``'s scalars for a model
+    with sparse experts."""
+    record = {
+        **head,
+        "started": round(started, 6),
+        "wall_s": round(time.perf_counter() - started, 6),
+        "phases": {name: round(seconds, 6) for name, seconds in phases.items()},
+        "compile_events": compile_events,
+        "depth": pipe.depth,
+        "stages": pipe.stages,
+        "pauses": pauses,
+    }
+    if routing is not None:
+        record["routing"] = _routing_scalars(routing)
+    return record
+
+
+def _routing_scalars(routing: dict[str, Any]) -> dict[str, Any]:
+    """``BulkScoreResult.routing`` without its two tables."""
+    return {k: v for k, v in routing.items() if not k.endswith("per_layer")}
 
 
 def _routing_summary(scorer, model, job: int) -> dict[str, Any] | None:
@@ -841,7 +946,7 @@ def _routing_summary(scorer, model, job: int) -> dict[str, Any] | None:
     with jax.profiler.TraceAnnotation(
         "mlops:bulk.routing",
         job=job,
-        **{k: v for k, v in routing.items() if not k.endswith("per_layer")},
+        **_routing_scalars(routing),
         **{f"layer_{i}": "|".join(map(str, row)) for i, row in enumerate(per_layer)},
     ):
         pass

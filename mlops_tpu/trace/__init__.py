@@ -21,9 +21,9 @@ crosses three processes (front end -> shm ring -> engine -> device):
   p50/p99 per stage per compiled entry from the span JSONL.
 
 The bulk path (`score-batch`) is not traced here. A bulk job times its
-own phases and counts what it re-traced, always (`parallel/bulk.py
-BulkScoreResult.phases` / ``compile_events``, printed in the command's
-summary), and under a `jax.profiler` session it writes ``mlops:bulk.*``
+own phases, its executor's queue waits and what it re-traced, always
+(its record: `parallel/bulk.py job_record`, printed in the command's
+summary and kept in the process's ``job_log()``), and under a `jax.profiler` session it writes ``mlops:bulk.*``
 and ``mlops:pipe.*`` spans into the profiler's trace, on the device
 operations' clock (docs/observability.md "Bulk jobs").
 

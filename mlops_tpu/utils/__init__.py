@@ -1,6 +1,5 @@
 """Shared utilities: structured logging, jsonl metrics, timing."""
 
 from mlops_tpu.utils.jsonl import JsonlWriter
-from mlops_tpu.utils.timing import Timer
 
-__all__ = ["JsonlWriter", "Timer"]
+__all__ = ["JsonlWriter"]

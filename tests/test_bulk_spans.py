@@ -3,10 +3,13 @@ the program (ISSUE 25): ``mlops:bulk.*`` and ``mlops:pipe.*`` spans in a
 profiler trace, `BulkScoreResult.phases` and ``compile_events`` always,
 device scopes on what flax does not name. And it re-traces nothing it has
 compiled before (ISSUE 28): the chunk program is kept from job to job.
+Its own account of itself is ONE record that outlives the call (ISSUE 35):
+`BulkScoreResult`, the summary, the marker and ``job_log()`` read it.
 All on the CPU: what is asserted is what the program writes, never a
 time."""
 
 import functools
+import gc
 import math
 import time
 
@@ -30,6 +33,7 @@ from mlops_tpu.parallel.bulk import (
     score_dataset,
 )
 from mlops_tpu.schema import SCHEMA
+from mlops_tpu.utils.timing import pause_counter
 
 ROWS, CHUNK = 700, 256  # 2 whole chunks and a padded tail
 CHUNKS = math.ceil(ROWS / CHUNK)
@@ -168,6 +172,10 @@ def test_phases_sum_to_the_jobs_wall_time(traced_jobs, which):
     assert tuple(result.phases) == PHASES
     assert sum(result.phases.values()) == pytest.approx(wall, rel=0.05)
     assert result.phases["sweep"] == pytest.approx(result.elapsed_s, rel=0.05)
+    record = result.record
+    assert sum(record["phases"].values()) == pytest.approx(
+        record["wall_s"], abs=0.005 + 0.01 * record["wall_s"])
+    assert record["wall_s"] <= wall
     summary = result.summary()
     assert set(summary["phases"]) == set(PHASES)
     assert summary["compile_events"] == result.compile_events
@@ -205,6 +213,148 @@ def process_wide_sums_are_the_jobs_deltas(traced_jobs, _):
 ], ids=["first_job", "second_job", "process_wide_sums"])
 def test_compile_events(traced_jobs, check, which):
     check(traced_jobs, which)
+
+
+# ------------------------------------------------------ the job's record
+RECORD_KEYS = {
+    "job", "rows", "chunk_rows", "chunks", "path", "histories", "started",
+    "wall_s", "phases", "compile_events", "depth", "stages", "pauses",
+}
+
+
+def _logged(job: int) -> dict:
+    (record,) = [r for r in bulk.job_log() if r["job"] == job]
+    return record
+
+
+def _plain(value) -> bool:
+    """Numbers, strings and lists and dicts of them: no array, nothing
+    that could hold a scorer, a bundle or a dataset."""
+    if isinstance(value, dict):
+        return all(isinstance(k, str) and _plain(v) for k, v in value.items())
+    if isinstance(value, list):
+        return all(map(_plain, value))
+    return value is None or type(value) in (int, float, str, bool)
+
+
+def result_and_log_hold_the_one_record(traced_jobs, which):
+    result, _ = traced_jobs["jobs"][which]
+    record = result.record
+    assert set(record) == RECORD_KEYS  # a dense model: no ``routing``
+    assert _logged(record["job"]) is record
+    assert result.phases is record["phases"]
+    assert result.compile_events is record["compile_events"]
+    assert result.pipeline["stages"] is record["stages"]
+    assert (result.rows, result.path) == (record["rows"], record["path"])
+    assert (record["rows"], record["chunk_rows"], record["chunks"],
+            record["histories"], record["depth"]) == (ROWS, CHUNK, CHUNKS, ROWS, 2)
+    assert result.pipeline["items"] == CHUNKS
+    assert set(record["pauses"]) == {"gc_s", "gc_collections", "gc_gen2_s"}
+    assert _plain(record)
+    for name in ("span", "slice", "transfer", "compute", "fetch", "store"):
+        assert {"busy_s", "wait_in_s", "wait_out_s", "max_busy_at",
+                "max_wait_in_at", "max_wait_out_at"} <= set(record["stages"][name])
+    assert record["stages"]["compute"]["items"] == CHUNKS
+
+
+def summary_prints_the_record(traced_jobs, which):
+    result, _ = traced_jobs["jobs"][which]
+    record, summary = result.record, result.summary()
+    for key in RECORD_KEYS - {"stages", "depth"}:
+        assert summary[key] == record[key], key
+    assert summary["pipeline"]["stages"] == record["stages"]
+    assert summary["pipeline"]["depth"] == record["depth"]
+    assert {"elapsed_s", "rows_per_s", "default_rate", "outlier_rate",
+            "feature_drift_batch"} <= set(summary)
+
+
+def spans_read_the_record(traced_jobs, which):
+    record = traced_jobs["jobs"][which][0].record
+    span = _named(traced_jobs["spans"], "mlops:bulk.job")[which]
+    marker = _named(traced_jobs["spans"], "mlops:bulk.compile_events")[which][3]
+    for key in ("job", "rows", "chunk_rows", "chunks", "path", "histories"):
+        assert span[3][key] == record[key], key
+    # the record's clock at the span's opening, and the span's own length
+    assert span[3]["started"] == pytest.approx(record["started"], abs=1e-5)
+    assert (span[2] - span[1]) / 1e9 == pytest.approx(record["wall_s"], abs=0.005)
+    events = record["compile_events"]
+    assert set(marker) == {"job", *events}
+    assert marker["job"] == record["job"]
+    assert marker["programs"] == "|".join(events["programs"])
+    for key in set(events) - {"programs"}:
+        assert marker[key] == pytest.approx(events[key]), key
+    assert "cache_requests" in events
+
+
+@pytest.mark.parametrize("which", [0, 1], ids=["first_job", "second_job"])
+@pytest.mark.parametrize("check", [
+    result_and_log_hold_the_one_record,
+    summary_prints_the_record,
+    spans_read_the_record,
+], ids=lambda check: check.__name__)
+def test_everything_that_reports_a_job_reads_its_record(traced_jobs, check, which):
+    check(traced_jobs, which)
+
+
+def test_log_keeps_the_newest_records_oldest_first(traced_jobs):
+    assert bulk.LOGGED_JOBS == 64 and bulk.JOB_LOG._records.maxlen == 64
+    log = bulk.JobLog(bulk.LOGGED_JOBS)
+    for job in range(70):
+        log.append({"job": job})
+    assert [record["job"] for record in log.records()] == list(range(6, 70))
+    # the process's own log: every job of this module so far, in order,
+    # the untraced one (whose result the fixture could have dropped) among them
+    numbers = [record["job"] for record in bulk.job_log()]
+    assert numbers == sorted(numbers) and len(numbers) <= bulk.LOGGED_JOBS
+    assert traced_jobs["untraced"].record["job"] in numbers
+    assert all(map(_plain, bulk.job_log()))
+
+
+def test_log_tells_a_process_first_job_from_its_second(tiny_bert):
+    """What a driver's dropped warm-up job leaves behind: the first job of
+    an architecture compiled its chunk program and says so, the second
+    found it compiled."""
+    _, ds = tiny_bert
+    CHUNK_PROGRAMS.clear()  # whatever ran before: this process has not seen it
+    bundle = _bundle(ds)
+    _job(bundle, ds)
+    _job(bundle, ds)  # both results dropped
+    first, second = bulk.job_log()[-2:]
+    assert second["job"] == first["job"] + 1
+    assert first["compile_events"]["chunk_program_reused"] == 0
+    assert "fused" in first["compile_events"]["programs"]
+    assert first["compile_events"]["lower_s"] > 0
+    assert first["phases"]["warmup"] > second["phases"]["warmup"]
+    events = second["compile_events"]
+    assert events["chunk_program_reused"] == 1 and "fused" not in events["programs"]
+    assert events["trace_s"] + events["lower_s"] + events["backend_compile_s"] < 0.05
+    assert events["cache_requests"] >= events["cache_hits"]
+
+
+def test_pauses_count_the_collections_inside_a_job(tiny_bert, monkeypatch):
+    """One callback a process, and a collection that happens inside a job
+    is in that job's ``pauses``."""
+    bundle, ds = tiny_bert
+    counter = pause_counter()
+    assert pause_counter() is counter
+    assert gc.callbacks.count(counter._on_gc) == 1
+    before = counter.snapshot()
+    gc.collect()
+    delta = counter.delta(before, counter.snapshot())
+    assert delta["gc_collections"] >= 1
+    assert 0 < delta["gc_gen2_s"] <= delta["gc_s"]
+
+    real = bulk.drift_scores
+
+    def collecting(*args):
+        gc.collect()
+        return real(*args)
+
+    monkeypatch.setattr(bulk, "drift_scores", collecting)
+    result, _ = _job(bundle, ds)
+    pauses = result.record["pauses"]
+    assert pauses["gc_collections"] >= 1
+    assert 0 < pauses["gc_gen2_s"] <= pauses["gc_s"] <= result.phases["drift"]
 
 
 # ------------------------------------------- the chunk program is kept
@@ -390,3 +540,11 @@ def test_answers_are_the_same_bits_with_and_without_a_session(
         np.testing.assert_array_equal(result.predictions, plain.predictions)
         np.testing.assert_array_equal(result.outliers, plain.outliers)
         assert result.feature_drift == plain.feature_drift
+    # the record and its timed queues change no answer and hold none
+    for result in (plain, traced):
+        assert result.record["depth"] == depth and _plain(result.record)
+        waits = [
+            stage[key] for stage in result.record["stages"].values()
+            for key in ("wait_in_s", "wait_out_s")
+        ]
+        assert all(w == 0 for w in waits) if depth == 1 else any(w > 0 for w in waits)

@@ -477,6 +477,11 @@ def test_the_routing_marker_is_written_once_a_job(tiny_bundle, tmp_path):
     assert marker["tokens"] == 6 * PER * 48
     assert marker["layer_0"] == "|".join(map(str, result.routing["per_layer"][0]))
     assert "routing" in result.summary()
+    # the job's record (and so ``job_log()``) holds the scalars, no table
+    scalars = result.record["routing"]
+    assert set(scalars) == {"tokens", "assignments_held", "max_expert_load",
+                            "mean_expert_load", "expert_runs"}
+    assert all(scalars[k] == result.routing[k] == marker[k] for k in scalars)
 
 
 @pytest.mark.parametrize("scope", [

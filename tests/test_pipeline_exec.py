@@ -94,7 +94,6 @@ def test_executor_failure_propagates_and_drains(where):
             if where == "sink" and x == 10:
                 raise ValueError("boom in sink")
 
-        before = threading.active_count()
         with pytest.raises(ValueError, match="boom"):
             run_pipeline(
                 src(),
@@ -105,8 +104,12 @@ def test_executor_failure_propagates_and_drains(where):
                 perturber.wrap(sink),
                 depth=3,
             )
-        # run_pipeline joins its workers before re-raising.
-        assert threading.active_count() == before, f"seed {seed} leaked"
+        # run_pipeline joins its workers before re-raising: its own threads
+        # (an xdist worker's process has others, which come and go)
+        leaked = [
+            t.name for t in threading.enumerate() if t.name.startswith("pipeline-")
+        ]
+        assert not leaked, f"seed {seed} leaked {leaked}"
 
 
 def test_executor_perturbed_schedules_bit_identical_across_seeds():
@@ -161,7 +164,118 @@ def test_executor_stage_timing_reports_occupancy():
     assert work["items"] == 20
     assert work["busy_s"] >= 0.02
     assert 0.0 < work["occupancy"] <= 1.5
-    assert stats.as_dict()["depth"] == 2
+    assert stats.depth == 2
+
+
+# ------------------------------------------------------------ queue waits
+WAIT_KEYS = ("wait_in_s", "wait_out_s", "max_wait_in_s", "max_wait_out_s")
+DELAY_S, DELAYED = 0.2, 5  # the one slow execution, and its ordinal
+ITEMS = 14
+
+
+def _run_with_one_slow(where: str, depth: int = 2):
+    """``span`` -> ``transfer`` -> ``compute`` -> ``store`` over ``ITEMS``
+    items, item ``DELAYED`` sleeping ``DELAY_S`` in stage ``where``; the
+    stats, and the seconds that execution really took."""
+    took = {}
+
+    def stage_fn(name):
+        def fn(x):
+            if name == where and x == DELAYED:
+                start = time.perf_counter()
+                time.sleep(DELAY_S)
+                took["s"] = time.perf_counter() - start
+            return x
+        return fn
+
+    stats = run_pipeline(
+        range(ITEMS),
+        [Stage("transfer", stage_fn("transfer")), Stage("compute", stage_fn("compute"))],
+        stage_fn("store"),
+        depth=depth,
+        source_name="span",
+        sink_name="store",
+    )
+    return stats, took["s"]
+
+
+def upstream_is_the_pace(depth=2):
+    """A slow ``transfer``: the dispatch thread had nothing to dispatch."""
+    stats, took = _run_with_one_slow("transfer")
+    compute, transfer = stats.stages["compute"], stats.stages["transfer"]
+    assert compute["wait_in_s"] == pytest.approx(took, rel=0.2)
+    assert compute["max_wait_in_s"] == pytest.approx(took, rel=0.2)
+    assert compute["max_wait_in_at"] == DELAYED, "the chunk it waited for"
+    assert compute["wait_out_s"] < 0.2 * took
+    assert (transfer["max_busy_at"], transfer["items"]) == (DELAYED, ITEMS)
+    assert transfer["max_busy_s"] == pytest.approx(took, rel=0.2)
+    # the sink waited for the same chunk, the source behind a full queue
+    assert stats.stages["store"]["max_wait_in_at"] == DELAYED
+    assert stats.stages["span"]["wait_out_s"] == pytest.approx(took, rel=0.2)
+    return stats
+
+
+def downstream_is_the_pace(depth=2):
+    """A slow sink: the stage before it blocks on its full output queue
+    once ``depth`` results lie there, one in its own hands."""
+    stats, took = _run_with_one_slow("store")
+    compute = stats.stages["compute"]
+    assert compute["wait_out_s"] == pytest.approx(took, rel=0.2)
+    assert compute["max_wait_out_s"] == pytest.approx(took, rel=0.2)
+    assert compute["max_wait_out_at"] == DELAYED + depth + 1
+    assert compute["wait_in_s"] < 0.2 * took
+    assert stats.stages["store"]["max_busy_at"] == DELAYED
+    # a sink has no output queue, a source no input queue
+    assert stats.stages["store"]["max_wait_out_at"] is None
+    assert stats.stages["span"]["max_wait_in_at"] is None
+    return stats
+
+
+def serial_mode_has_no_queues(depth=1):
+    stats, took = _run_with_one_slow("transfer", depth=depth)
+    for stage in stats.stages.values():
+        assert [stage[key] for key in WAIT_KEYS] == [0.0] * 4
+        assert stage["max_wait_in_at"] is None and stage["max_wait_out_at"] is None
+    assert stats.stages["transfer"]["max_busy_at"] == DELAYED
+    assert stats.stages["transfer"]["max_busy_s"] == pytest.approx(took, rel=0.2)
+    return stats
+
+
+@pytest.mark.parametrize("case", [
+    upstream_is_the_pace, downstream_is_the_pace, serial_mode_has_no_queues,
+], ids=lambda case: case.__name__)
+def test_executor_times_its_queue_waits_by_side(case):
+    stats = case()
+    assert set(stats.stages) == {"span", "transfer", "compute", "store"}
+    for name, stage in stats.stages.items():
+        # busy or blocked on one queue or the other: a thread's whole time
+        assert (
+            stage["busy_s"] + stage["wait_in_s"] + stage["wait_out_s"]
+            <= stats.wall_s + 0.005
+        ), name
+        assert stage["max_busy_s"] <= stage["busy_s"] + 1e-4
+        assert stage["max_wait_in_s"] <= stage["wait_in_s"] + 1e-6
+        assert stage["max_wait_out_s"] <= stage["wait_out_s"] + 1e-6
+
+
+def test_batch_stage_times_each_put_and_jax_free_clock_still_reports():
+    """A batch stage's waits belong to the execution that made the batch;
+    a `StageClock` without spans (`compilecache/cache.py`) reports as ever."""
+    from mlops_tpu.utils.timing import StageClock
+
+    stats = run_pipeline(
+        range(40),
+        [Stage("fetch", lambda xs: xs, batch_max=8, queue_depth=8)],
+        lambda x: time.sleep(0.002),
+        depth=2,
+    )
+    fetch = stats.stages["fetch"]
+    assert fetch["items"] == 40 and fetch["wait_out_s"] > 0.02
+    assert 0 <= fetch["max_wait_out_at"] < 40
+    clock = StageClock()
+    with clock.stage("compile"):
+        pass
+    assert clock.report(1.0)["compile"]["items"] == 1
 
 
 # ------------------------------------------------- satellite vectorizations
