@@ -23,8 +23,8 @@ other chips or for their exchange.
   the assignments that fell here. The sorted assignments are walked in
   SEGMENTS of a fixed number of rows (shapes stay static): a segment
   gathers its tokens' rows, runs the three products with its own slice of
-  the group sizes (`segment_products`), and adds its weighted rows into
-  the result; a segment past the last held assignment is skipped
+  the group sizes (`segment_products`), and its weighted rows are combined
+  into the result; a segment past the last held assignment is skipped
   (`lax.cond`), so the work follows the load. The worst case (every token
   choosing only held experts) is ``min(k, held) * tokens`` rows and is
   walked in full: routing skew costs time, never an answer.
@@ -44,6 +44,27 @@ Which form of a segment's products runs where:
   other shape (each tiny configuration of the tests), and the backward
   everywhere (`segment_products` is a ``custom_vjp`` where the kernels are
   the forward; the XLA form is recomputed and differentiated).
+
+Which form of the combine runs where:
+
+- one segment holds every held assignment (``segments == 1``, a fact of
+  the static shapes: every expert held, or a share of half the experts or
+  more): every token GATHERS its own experts' rows and sums them. The
+  sorted order is a permutation of the assignment ids, so its inverse
+  (`landed`, one more sort of ``T * k`` integers a layer) says where each
+  (token, slot) landed; ``result[t] = sum over s of where(held[t, s],
+  w[t, s] * out[pos[t, s]], 0)``, the weights and the mask inside the
+  reduction, slots outermost so that the gathered ``[k, T, d]`` is
+  ``[k * T, d]`` viewed. No scatter: on a v5e a gather of rows runs at
+  half the memory's rate and a scatter-add of as many at an eighth of it
+  (`lfm2-8b-a1b.bulk-hist`: 49,152 rows of 2,048 float32 a layer and run,
+  5.1 ms with its ``where`` against 2.5 and the sort's 0.05).
+- several segments under the `fori_loop` (a small share: `kimi-k2-5l`'s 24
+  of 384 experts, eight segments of which one is usually live): a segment
+  SCATTER-ADDS its weighted rows at their tokens. A token-side gather
+  would read ``T * k`` rows a live segment to use a sixteenth of them
+  (6,144 tokens, top-8, rows of 7,168 float32: 8.6 ms against the
+  scatter's 6.8 alone, and 3% of the cell's rows a second).
 
 The scopes ``router``, ``moe_dispatch`` (sort, gather), ``experts`` (the
 three grouped products: the kernels' calls carry it; the compiler's own
@@ -193,6 +214,13 @@ def segment_products(
     return _segment_products(taken, gate.astype(dtype), up.astype(dtype), down.astype(dtype), sizes)
 
 
+def landed(order: jnp.ndarray) -> jnp.ndarray:
+    """`Plan.order`'s inverse, int32 ``[T * k]``: the position each assignment
+    id took in the sorted order (``order[landed(order)]`` counts up from 0).
+    A held assignment's is under ``offsets[-1]``."""
+    return jnp.argsort(order).astype(jnp.int32)
+
+
 def grouped_swiglu(
     h: jnp.ndarray,
     routing: Routing,
@@ -211,23 +239,41 @@ def grouped_swiglu(
     flat_weights = routing.weights.reshape(-1)
     segments = -(-min(top_k, gate.shape[0]) * tokens // rows)
 
-    def segment(index, result):
+    def products(start):
+        """The segment from ``start`` on: which of its rows hold an
+        assignment, the rows' assignment ids and tokens, and the products'
+        float32 ``[rows, d]``. Rows past the last held assignment belong to
+        no group: a grouped product leaves them undefined."""
+        with jax.named_scope("moe_dispatch"):
+            at = start + jnp.arange(rows, dtype=jnp.int32)
+            live = at < total
+            assignment = planned.order[jnp.minimum(at, tokens * top_k - 1)]
+            token = assignment // top_k
+            taken = jnp.take(h, token, axis=0)
+            edges = jnp.clip(planned.offsets, start, start + rows)
+            sizes = edges[1:] - edges[:-1]
+        with jax.named_scope("experts"):
+            return live, assignment, token, segment_products(taken, gate, up, down, sizes)
+
+    def gathered():
+        *_, out = products(0)
+        with jax.named_scope("moe_dispatch"):
+            # slots outermost: ``[k, T, d]`` is ``[k * T, d]`` viewed
+            pos = landed(planned.order).reshape(tokens, top_k).T
+        with jax.named_scope("moe_combine"):
+            picked = out.at[jnp.minimum(pos, rows - 1)].get(mode="promise_in_bounds")
+            weighted = routing.weights.T[:, :, None] * picked
+            # a ``where`` and no product with 0: an absent assignment's
+            # clamped position reads another's row or an undefined one
+            return jnp.where((pos < total)[:, :, None], weighted, 0.0).sum(axis=0)
+
+    def scattered(index, result):
         start = index * rows
 
         def run(result):
-            with jax.named_scope("moe_dispatch"):
-                at = start + jnp.arange(rows, dtype=jnp.int32)
-                live = at < total
-                assignment = planned.order[jnp.minimum(at, tokens * top_k - 1)]
-                token = assignment // top_k
-                taken = jnp.take(h, token, axis=0)
-                edges = jnp.clip(planned.offsets, start, start + rows)
-                sizes = edges[1:] - edges[:-1]
-            with jax.named_scope("experts"):
-                out = segment_products(taken, gate, up, down, sizes)
+            live, assignment, token, out = products(start)
             with jax.named_scope("moe_combine"):
-                # rows past the last held assignment belong to no group: a
-                # grouped product leaves them undefined, so they are zeroed
+                # the undefined rows are zeroed
                 weighted = jnp.where(
                     live[:, None], out * flat_weights[assignment][:, None], 0.0
                 )
@@ -235,7 +281,7 @@ def grouped_swiglu(
 
         return jax.lax.cond(start < total, run, lambda result: result, result)
 
-    result = jnp.zeros(h.shape, jnp.float32)
+    nothing = jnp.zeros(h.shape, jnp.float32)
     if segments == 1:
-        return segment(0, result)
-    return jax.lax.fori_loop(0, segments, segment, result)
+        return jax.lax.cond(0 < total, gathered, lambda: nothing)
+    return jax.lax.fori_loop(0, segments, scattered, nothing)

@@ -385,6 +385,28 @@ def test_no_token_is_dropped_under_a_skewed_router():
     assert moe_dispatch.segment_rows(150, 4, 16, 16) == 600  # all held: the worst case is the case
 
 
+@pytest.mark.parametrize("held, segments", [(4, 2), (8, 1)], ids=["quarter", "half"])
+def test_the_combines_two_forms_agree_at_a_shares_own_rows(held, segments):
+    """A quarter of the experts is walked in two segments (the scatter-add
+    under the loop, this family's cell), half of them in one (every token
+    gathers its own experts' rows): each at its own `segment_rows` against
+    the other form, and against the reference expert by expert."""
+    h, router, gate, up, down = expert_inputs()
+    bias = jnp.asarray(np.random.default_rng(4).normal(size=16) * 0.1, jnp.float32)
+    routing = moe_dispatch.route(h, router, bias, 4, 2.827, 1e-20)
+    rows = moe_dispatch.segment_rows(150, 4, 16, held)
+    assert -(-4 * 150 // rows) == segments
+    own, planned = routed_part(h, routing, gate, up, down, 4, held)
+    other = routed_part(h, routing, gate, up, down, 4, held, rows=600 if segments > 1 else 128)[0]
+    assert 0 < int(planned.counts.sum()) < 600
+    np.testing.assert_allclose(own, other, atol=1e-6)
+    want = jnp.zeros_like(h)
+    for i in range(4, 4 + held):
+        mine = jnp.where(routing.experts == i, routing.weights, 0.0).sum(-1)[:, None]
+        want = want + mine * reference.swiglu(h, gate[i], up[i], down[i], "f32")
+    np.testing.assert_allclose(own, want, atol=3e-5)
+
+
 def test_the_bias_moves_the_choice_and_not_the_weights():
     h, router, *_ = expert_inputs()
     plain = moe_dispatch.route(h, router, jnp.zeros(16), 4, 2.827, 1e-20)

@@ -370,6 +370,126 @@ def test_the_two_halves_add_up_to_the_layer_with_every_expert_held():
     assert float(jnp.abs(low).max()) > 0 and float(jnp.abs(whole - low).max()) > 0.05
 
 
+# --------------------------------------------------------------- the combine
+# One segment holds every held assignment: every token gathers its own
+# experts' rows by the inverse of the dispatch's permutation and sums them.
+# (first, held, rows, bias): all 32 held; half of them (absent slots are
+# masked); two, fewer than a token chooses (positions past the segment's
+# rows are clamped); all held with expert 6 never chosen.
+SHARES = {
+    "all": (0, 32, 600, None),
+    "half": (16, 16, 600, None),
+    "two": (3, 2, 300, None),
+    "one_expert_idle": (0, 32, 600, (6, -5.0)),
+}
+
+
+def share_inputs(case):
+    first, held, rows_, skew = SHARES[case]
+    h, router, gate, up, down = expert_inputs()
+    bias = np.random.default_rng(4).normal(size=32) * 0.1
+    if skew:
+        bias[skew[0]] = skew[1]
+    routing = moe_dispatch.route(h, router, jnp.asarray(bias, jnp.float32), 4, 1.0, 1e-6)
+    sl = slice(first, first + held)
+    return h, routing, (gate[sl], up[sl], down[sl]), first, held, rows_
+
+
+def scattered(h, weights, planned, gate, up, down):
+    """The combine as a scatter-add, written plainly: every sorted row
+    times its weight, the rows past the last held assignment zeroed, added
+    at its token."""
+    top_k = weights.shape[1]
+    token = planned.order // top_k
+    out = moe_dispatch.segment_products_xla(h[token], gate, up, down, planned.counts)
+    live = jnp.arange(token.shape[0]) < planned.offsets[-1]
+    weighted = jnp.where(live[:, None], out * weights.reshape(-1)[planned.order][:, None], 0.0)
+    return jnp.zeros_like(h).at[token].add(weighted)
+
+
+@pytest.mark.parametrize("case", list(SHARES))
+def test_one_segments_gather_is_the_scatter_add(case):
+    h, routing, stacked, first, held, rows_ = share_inputs(case)
+    planned = moe_dispatch.plan(routing.experts, first, held)
+    counts = np.asarray(planned.counts)
+    assert -(-min(4, held) * 150 // rows_) == 1  # one segment: the gather form
+    assert case != "one_expert_idle" or (counts[6] == 0 and counts.sum() == 600)
+    assert (int(counts.sum()) < 600) == (held < 32)  # a share leaves slots absent
+    got = moe_dispatch.grouped_swiglu(h, routing, planned, *stacked, rows_)
+    want = scattered(h, routing.weights, planned, *stacked)
+    assert float(jnp.abs(want).max()) > 0.05
+    np.testing.assert_allclose(got, want, atol=1e-6)
+
+
+@pytest.mark.parametrize("case", ["half", "two"])
+def test_undefined_rows_never_reach_a_token(case, monkeypatch):
+    """A grouped product leaves the rows past its sizes' sum undefined. With
+    NaN there, the result is what it was: they are selected away (a
+    ``where``), not multiplied by 0."""
+    h, routing, stacked, first, held, rows_ = share_inputs(case)
+    planned = moe_dispatch.plan(routing.experts, first, held)
+    assert int(planned.offsets[-1]) < rows_
+    clean = moe_dispatch.grouped_swiglu(h, routing, planned, *stacked, rows_)
+
+    def undefined_past_the_sizes(taken, gate, up, down, sizes):
+        out = moe_dispatch.segment_products_xla(taken, gate, up, down, sizes)
+        return jnp.where((jnp.arange(out.shape[0]) < sizes.sum())[:, None], out, jnp.nan)
+
+    monkeypatch.setattr(moe_dispatch, "segment_products", undefined_past_the_sizes)
+    got = moe_dispatch.grouped_swiglu(h, routing, planned, *stacked, rows_)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_array_equal(got, clean)
+
+
+@pytest.mark.parametrize("case", ["all", "half"])
+def test_landed_is_the_sorted_orders_inverse(case):
+    h, routing, _, first, held, _ = share_inputs(case)
+    planned = moe_dispatch.plan(routing.experts, first, held)
+    pos = np.asarray(moe_dispatch.landed(planned.order))
+    np.testing.assert_array_equal(np.asarray(planned.order)[pos], np.arange(600))
+    # a held assignment landed under the total, at its expert's run
+    local = np.asarray(routing.experts).reshape(-1) - first
+    mine = (local >= 0) & (local < held)
+    np.testing.assert_array_equal(pos < int(planned.offsets[-1]), mine)
+    offsets = np.asarray(planned.offsets)
+    assert (pos[mine] >= offsets[local[mine]]).all() and (pos[mine] < offsets[local[mine] + 1]).all()
+
+
+@pytest.mark.parametrize("case", ["all", "one_expert_idle"])
+def test_several_segments_answer_as_one(case):
+    """The walk in segments of 128 rows (five of them: the scatter-add under
+    the loop) against one segment of 600 (the gather)."""
+    h, routing, stacked, first, held, rows_ = share_inputs(case)
+    planned = moe_dispatch.plan(routing.experts, first, held)
+    walked = moe_dispatch.grouped_swiglu(h, routing, planned, *stacked, 128)
+    one = moe_dispatch.grouped_swiglu(h, routing, planned, *stacked, rows_)
+    np.testing.assert_allclose(walked, one, atol=1e-6)
+
+
+@pytest.mark.parametrize("case", ["all", "half"])
+def test_the_gathers_gradient_is_the_scatter_adds(case):
+    """The trainers differentiate `grouped_swiglu`: through one segment's
+    gather the gradients of the rows, of the weights and of the three
+    stacked projections are those through the scatter-add."""
+    h, routing, stacked, first, held, rows_ = share_inputs(case)
+    planned = moe_dispatch.plan(routing.experts, first, held)
+    tilt = jnp.asarray(np.random.default_rng(5).normal(size=h.shape), jnp.float32)
+
+    def through_the_gather(h, weights, gate, up, down):
+        routed = routing._replace(weights=weights)
+        return (moe_dispatch.grouped_swiglu(h, routed, planned, gate, up, down, rows_) * tilt).sum()
+
+    def through_the_scatter(h, weights, gate, up, down):
+        return (scattered(h, weights, planned, gate, up, down) * tilt).sum()
+
+    operands = (h, routing.weights, *stacked)
+    got = jax.grad(through_the_gather, argnums=(0, 1, 2, 3, 4))(*operands)
+    want = jax.grad(through_the_scatter, argnums=(0, 1, 2, 3, 4))(*operands)
+    for g, w in zip(got, want):
+        assert float(jnp.abs(w).max()) > 1e-3
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+
+
 # ----------------------------------------------- the model and the bulk job
 @pytest.mark.parametrize("held", [(0, 0), (2, 4)], ids=["uncut", "share"])
 def test_score_dataset_matches_the_reference(held):

@@ -417,6 +417,17 @@ def _assert_the_experts_products_are_the_kernels(text: str, layers: int) -> None
     assert "ragged-dot" not in text
 
 
+def _scoped_instructions(text: str, scope: str, kind: str) -> list[str]:
+    """The program's ``kind`` instructions (``scatter``, ``gather``,
+    ``sort``) whose ``op_name`` lies under ``scope``, fused or not."""
+    found = []
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        if name and scope in name.group(1).split("/") and f" {kind}(" in line:
+            found.append(line.strip())
+    return found
+
+
 def test_kimi_k2_chunk_program_compiles_for_v5e_at_published_widths(
     one_chip, no_persistent_cache
 ):
@@ -487,6 +498,12 @@ def test_kimi_k2_chunk_program_compiles_for_v5e_at_published_widths(
             op_name = re.search(r'op_name="([^"]*)"', line).group(1)
             assert "/block_4/" in op_name or op_name.endswith("mla_q/reshape"), op_name
     assert memory.temp_size_in_bytes <= 0.867e9, memory.temp_size_in_bytes
+    # eight segments under the loop: the combine stays the scatter-add of a
+    # segment's rows (`ops/moe_dispatch.py`: a token-side gather would
+    # read 49,152 rows to use some 3,072), and no inverse is sorted for it
+    scatters = _scoped_instructions(text, "moe_combine", "scatter")
+    assert len(scatters) == 4 and all(" = f32[" in line for line in scatters), scatters
+    assert len(_scoped_instructions(text, "moe_dispatch", "sort")) == 4  # `plan`'s, one a layer
 
 
 def test_lfm2_moe_chunk_program_compiles_for_v5e_at_published_widths(
@@ -501,8 +518,10 @@ def test_lfm2_moe_chunk_program_compiles_for_v5e_at_published_widths(
     projection or of the embedding, the routed experts' products are the
     two grouped kernels (gate and up with the SwiGLU, then down, in
     fourteen expert layers) and leave no float32 ``[rows, f]`` in HBM, the
-    counter is the program's third output, and grouped attention holds no
-    keys or values repeated to the query heads' count."""
+    counter is the program's third output, grouped attention holds no
+    keys or values repeated to the query heads' count, and the combine (one
+    segment holds every assignment) is a gather of each token's four rows,
+    slots outermost, and a reduction: no scatter under ``moe_combine``."""
     import json
     from pathlib import Path
 
@@ -546,6 +565,12 @@ def test_lfm2_moe_chunk_program_compiles_for_v5e_at_published_widths(
     assert "f32[49152,1792]" not in text
     assert memory.temp_size_in_bytes <= 1.145e9, memory.temp_size_in_bytes
     assert re.search(r"s32\[2,14,32\]", text), "the routing counter is not an output"
+    assert not _scoped_instructions(text, "moe_combine", "scatter")
+    # `plan`'s sort and the inverse's, a layer
+    assert len(_scoped_instructions(text, "moe_dispatch", "sort")) == 28
+    gathers = _scoped_instructions(text, "moe_combine", "gather")
+    assert len(gathers) == 14, gathers
+    assert all(re.search(r" = f32\[4,(12288|256),2048\]", line) for line in gathers), gathers
     # 8 key/value heads stay 8: the only 32-head buffers of a whole run are q's and o's
     for line in text.splitlines():
         if re.search(r"= bf16\[4,3072,32,64\]", line) and "op_name=" in line:
