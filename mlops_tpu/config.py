@@ -40,7 +40,7 @@ class DataConfig:
 
 # Causal families that take the zoo's 2-D rows, read every `doc_records`
 # consecutive rows as one history and answer every record.
-HISTORY_FAMILIES = ("evabyte", "kimi_k2", "lfm2_moe")
+HISTORY_FAMILIES = ("evabyte", "kimi_k2", "lfm2_moe", "exaone_moe")
 # LFM2-8B-A1B's published `layer_types`: which token mixer each of its 24
 # layers runs (family lfm2_moe's default)
 LFM2_LAYER_TYPES = (
@@ -54,16 +54,16 @@ LFM2_LAYER_TYPES = (
 @dataclasses.dataclass
 class ModelConfig:
     family: str = "mlp"  # mlp | ft_transformer | moe | linear | bert |
-    # evabyte | kimi_k2 | lfm2_moe | gbm | rf
+    # evabyte | kimi_k2 | lfm2_moe | exaone_moe | gbm | rf
     hidden_dims: tuple[int, ...] = (256, 256, 128)
     embed_dim: int = 16
     dropout: float = 0.1
     precision: str = "bf16"  # compute dtype on MXU: bf16 | f32 (params stay f32)
     param_dtype: str = "f32"  # the dtype parameters are STORED in, on disk
     # and on the device: f32 | bf16. The sparse decoders (kimi_k2,
-    # lfm2_moe) alone take bf16 (what a chip holds of them does not fit it
-    # at four bytes a parameter); nothing casts the tree in the program, a
-    # product reads its leaf as stored
+    # lfm2_moe, exaone_moe) alone take bf16 (what a chip holds of them does
+    # not fit it at four bytes a parameter); nothing casts the tree in the
+    # program, a product reads its leaf as stored
     ensemble_size: int = 1  # >1 wraps the Flax family in a vmapped deep
     # ensemble (models/ensemble.py) — the MXU-native answer to the
     # reference's RandomForest variance reduction; 1 = single model
@@ -83,10 +83,10 @@ class ModelConfig:
     # the LAST record's default from the history (training path
     # `train/long_context.py`); family evabyte is causal, takes the zoo's
     # 2-D rows and answers EVERY record, conditioned on the records before
-    # it in its history, as do the token-level decoders kimi_k2 and
-    # lfm2_moe. `seq_parallel` routes bert's attention through the
-    # ppermute ring (`parallel.make_ring_attention`) over the mesh's 'seq'
-    # axis.
+    # it in its history, as do the token-level decoders kimi_k2,
+    # lfm2_moe and exaone_moe. `seq_parallel` routes bert's attention through
+    # the ppermute ring (`parallel.make_ring_attention`) over the mesh's
+    # 'seq' axis.
     doc_records: int = 1
     seq_parallel: bool = False
     # Pipeline parallelism (families bert / ft_transformer): split the
@@ -147,6 +147,16 @@ class ModelConfig:
     layer_types: tuple[str, ...] = LFM2_LAYER_TYPES
     dense_layers: int = 2
     conv_width: int = 3
+    # Family exaone_moe (models/exaone_moe.py; the attention of lfm2_moe in
+    # every layer, the expert layer of kimi_k2 with its shared expert).
+    # `layer_types` names each layer "sliding_attention" (a query sees
+    # itself and the `attn_window` - 1 keys before it, and turns by rotary
+    # positions) or "full_attention" (every key so far, unturned), and has
+    # to be given: the default is lfm2_moe's list; `dense_layers` is 1 in
+    # the source. The one field of its own: a head's width, which its
+    # source states apart from the hidden size (64 heads of 128 in a hidden
+    # size of 6,144); 0 = `token_dim // heads`.
+    head_dim: int = 0
 
     @property
     def reads_documents(self) -> bool:

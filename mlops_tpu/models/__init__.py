@@ -24,6 +24,13 @@ the TPU-native zoo is:
   sparse routed experts with no shared one (the expert layer of
   ``kimi_k2``, told which experts it holds); histories, tokens and
   bfloat16 parameters as ``kimi_k2``
+- ``exaone_moe``      token-level causal decoder whose every layer is
+  grouped-query attention with normed heads of a width of their own, by a
+  published per-layer list over a sliding window (turned by rotary
+  positions) or over every key so far (unturned); one dense layer, then
+  sparse routed experts beside a shared one (the expert layer of
+  ``kimi_k2``, the attention of ``lfm2_moe``); histories, tokens and
+  bfloat16 parameters as ``kimi_k2``
 
 All families share one calling convention:
 ``model.apply(vars, cat_ids[int32 N,C], numeric[f32 N,M], train=...) ->
@@ -42,6 +49,7 @@ from mlops_tpu.config import ModelConfig
 from mlops_tpu.models.bert import BertEncoder
 from mlops_tpu.models.ensemble import DeepEnsemble
 from mlops_tpu.models.evabyte import EvaByteScorer
+from mlops_tpu.models.exaone_moe import ExaoneMoeScorer
 from mlops_tpu.models.ft_transformer import FTTransformer
 from mlops_tpu.models.kimi_k2 import KimiK2Scorer
 from mlops_tpu.models.lfm2_moe import Lfm2MoeScorer
@@ -51,9 +59,10 @@ from mlops_tpu.schema.features import SCHEMA
 
 FAMILIES = (
     "linear", "mlp", "ft_transformer", "moe", "bert", "evabyte", "kimi_k2", "lfm2_moe",
+    "exaone_moe",
 )
 # the sparse decoders, whose parameters may be STORED in bfloat16
-BF16_PARAM_FAMILIES = ("kimi_k2", "lfm2_moe")
+BF16_PARAM_FAMILIES = ("kimi_k2", "lfm2_moe", "exaone_moe")
 
 
 def build_model(config: ModelConfig) -> nn.Module:
@@ -175,6 +184,30 @@ def build_model(config: ModelConfig) -> nn.Module:
             dtype=dtype,
             param_dtype=param_dtype,
         )
+    if config.family == "exaone_moe":
+        return ExaoneMoeScorer(
+            cards=SCHEMA.cards,
+            num_numeric=SCHEMA.num_numeric,
+            layer_types=tuple(config.layer_types),
+            hidden=config.token_dim,
+            depth=config.depth,
+            heads=config.heads,
+            kv_heads=config.kv_heads or config.heads,
+            head_dim=config.head_dim or config.token_dim // config.heads,
+            window=config.attn_window,
+            ffn_dim=config.ffn_dim,
+            moe_ffn_dim=config.moe_ffn_dim,
+            num_experts=config.num_experts,
+            experts_per_token=config.experts_per_token,
+            first_expert=config.first_expert,
+            experts_held=config.experts_held or config.num_experts,
+            vocab_rows=config.vocab_rows,
+            records_per_history=config.doc_records,
+            dense_layers=config.dense_layers,
+            rope_theta=config.rope_theta,
+            dtype=dtype,
+            param_dtype=param_dtype,
+        )
     from mlops_tpu.models.gbm import SKLEARN_FAMILIES
 
     if config.family in SKLEARN_FAMILIES:
@@ -212,6 +245,7 @@ __all__ = [
     "BertEncoder",
     "DeepEnsemble",
     "EvaByteScorer",
+    "ExaoneMoeScorer",
     "FTTransformer",
     "KimiK2Scorer",
     "Lfm2MoeScorer",

@@ -1,54 +1,54 @@
-"""An LFM2-MoE-style hybrid sparse decoder (``model_type: lfm2_moe``) as a
-token-level history scorer: a token mixer chosen layer by layer from the
-published ``layer_types`` list (a double-gated short convolution, or
-causal grouped-query attention with RMSNorm on each head's query and
-key), two leading dense SwiGLU layers, then layers of routed experts with
-no shared one, under the zoo's calling convention and the read-out of
-`models/kimi_k2.py`.
+"""A K-EXAONE-style sparse decoder (``model_type: exaone_moe``) as a
+token-level history scorer: causal grouped-query attention in every
+layer, by the published ``layer_types`` list either over a sliding window
+or over every key so far, with a head width of its own (``heads *
+head_dim`` need not be the hidden size); one leading dense SwiGLU layer,
+then layers of routed experts beside one shared expert; under the zoo's
+calling convention and the read-out of `models/kimi_k2.py`.
 
 - **Rows in, an answer a row out.** ``apply(vars, cat_ids[N, C],
   numeric[N, M], train) -> logits[N]``; every ``records_per_history``
   consecutive rows (from row 0) are ONE history, the last may be shorter:
-  the history scorers' rule (`ModelConfig.history_rows`). Both mixers are
-  causal, so rows padded behind a record never change its answer, and the
-  convolution pads each history on the left: it never reads across a
-  history's start.
+  the history scorers' rule (`ModelConfig.history_rows`). The model is
+  causal, so rows padded behind a record never change its answer, and a
+  window never reaches across a history's start.
 - **Input.** A record is the 48 tokens `models/bert.py tokenize` gives,
   in-jit; token ``t`` of the layout's ``V`` reads row ``t * (vocab_rows //
-  V)`` of the embedding.
+  V)`` of the embedding slice this chip holds.
 - **A layer**, pre-norm on the float32 residual stream (RMSNorm, eps
-  1e-5, a plain weight): ``x += mixer(operator_norm x)``; ``x +=
+  1e-5, a plain weight): ``x += attention(attn_norm x)``; ``x +=
   FFN(ffn_norm x)``.
-- **The convolution** (`ops/short_conv.py`): ``in_proj`` (hidden -> 3
-  hidden: in-gate, out-gate, signal), ``conv_width`` depthwise taps,
-  ``out_proj``; no biases.
 - **The attention** (`models/grouped_attention.py`, which
-  `models/exaone_moe.py` calls too): ``q`` (``heads`` of ``hidden //
-  heads``), ``k``, ``v`` (``kv_heads`` of the same width), RMSNorm over
-  each head's query and key, then `ops/eva_attention.py rope`
-  (rotate-half, plain frequencies), `ops/causal_attention.py
-  causal_attend` (query head ``i`` over key/value head ``i // (heads //
-  kv_heads)``, keys and values never repeated; plain XLA in blocks of
-  queries: two Pallas forms of it were slower on the chip, PERF.md section
-  6, PR 33), ``o``.
+  `models/lfm2_moe.py` calls too): ``q`` (``heads`` of ``head_dim``),
+  ``k``, ``v`` (``kv_heads`` of the same width), RMSNorm over each head's
+  query and key, `ops/causal_attention.py causal_attend`, ``o`` (``heads *
+  head_dim`` -> hidden). A ``sliding_attention`` layer turns its queries
+  and keys (`ops/eva_attention.py rope`, rotate-half, plain frequencies)
+  and a query sees itself and the ``window - 1`` keys before it: the op
+  computes the band and nothing left of it. A ``full_attention`` layer
+  sees every key up to the query and does NOT turn (no positional
+  encoding where the attention is global).
 - **The FFN**: a dense SwiGLU of ``ffn_dim`` in the first ``dense_layers``
   layers; after them `models/routed_experts.py` over
   `ops/moe_dispatch.py`: a sigmoid router with a selection bias,
   ``experts_per_token`` experts a token, weights normalised over the
-  chosen (``+ 1e-6``) and scaled by 1, told ``(first_expert,
-  experts_held)`` and counting into the ``routing`` collection.
+  chosen (``+ 1e-20``) and scaled by 2.5, told ``(first_expert,
+  experts_held)`` and counting into the ``routing`` collection, beside
+  one shared expert of the experts' width, whole and unweighted
+  (`experts_beside_a_shared_one`, as `models/kimi_k2.py`).
 - **Precision.** Parameters are stored in ``param_dtype``; products take
   ``dtype`` operands and accumulate in float32; residual stream, norms,
-  the gates' products and the taps, softmax, router and head are float32.
+  softmax, router and head are float32.
 - **Read-out**: the final RMSNorm at each record's last token, then
-  ``head`` (hidden -> 1) in float32. The last layer computes its mixer's
-  inputs (the convolution whole; keys and values) at every position and
-  everything behind them at the read positions.
+  ``head`` (hidden -> 1) in float32. The last layer, of either kind,
+  computes keys and values at every position and everything else at the
+  read positions.
 
-Scopes for a device trace: ``conv_in``, ``short_conv``, ``conv_out``;
-``gqa_qkv``, ``gqa_attend``, ``gqa_o``; ``router``, ``moe_dispatch``,
-``experts``, ``moe_combine``; beside ``embed``, ``ffn`` (the dense layers)
-and ``head``.
+Scopes for a device trace: ``swa_qkv``, ``swa_attend``, ``swa_o`` in the
+window layers and ``gqa_qkv``, ``gqa_attend``, ``gqa_o`` in the full ones
+(`models/lfm2_moe.py`'s names); ``router``, ``moe_dispatch``, ``experts``,
+``moe_combine``, ``shared_expert``; beside ``embed``, ``ffn`` (the dense
+layer), ``head`` and ``rope``.
 """
 
 from __future__ import annotations
@@ -66,38 +66,32 @@ from mlops_tpu.models.grouped_attention import GQA_SCOPES, grouped_query_attenti
 from mlops_tpu.models.routed_experts import (
     ROUTING,
     check_share,
-    routed_experts,
+    experts_beside_a_shared_one,
     routing_counts,
     swiglu,
 )
-from mlops_tpu.ops.short_conv import short_conv
 
-LAYER_TYPES = ("conv", "full_attention")
-ROUTE_EPS = 1e-6  # the router's normaliser (the source's modelling code)
-ROUTED_SCALING = 1.0  # the source's routed_scaling_factor
-
-
-class _Taps(nn.Module):
-    """The convolution's filters, ``kernel`` ``[width, channels]``: one a
-    channel (depthwise)."""
-
-    width: int
-    param_dtype: jnp.dtype
-
-    @nn.compact
-    def __call__(self, channels: int) -> jnp.ndarray:
-        init = nn.initializers.lecun_normal(in_axis=0, out_axis=1)
-        return self.param("kernel", init, (self.width, channels), self.param_dtype)
+LAYER_TYPES = ("sliding_attention", "full_attention")
+SWA_SCOPES = ("swa_qkv", "swa_attend", "swa_o")
+ROUTE_EPS = 1e-20  # the router's normaliser (DeepSeek-V3's, as `models/kimi_k2.py`)
+ROUTED_SCALING = 2.5  # the source's routed_scaling_factor
+# queries a block of a full layer: the fastest of 128 / 256 / 512 on the chip
+# at the published widths (a job of the cell 1.794 s against 1.804 at 256; a
+# layer alone 50.2 / 51.2 / 54.7 ms), at the least scratch (4.57 / 4.57 /
+# 4.78 GB: 64 heads' float32 scores of 512 queries against 3,072 keys are
+# 0.40 GB a history); PERF.md section 6, PR 37
+FULL_QUERY_BLOCK = 128
 
 
-class Lfm2Block(nn.Module):
+class ExaoneBlock(nn.Module):
     """One decoder layer on the float32 residual stream ``[B, S, dim]``.
     With ``read`` (positions), the layer returns those positions only."""
 
-    layer_type: str  # "conv" | "full_attention"
+    layer_type: str  # "sliding_attention" | "full_attention"
     heads: int
     kv_heads: int
-    conv_width: int
+    head_dim: int
+    window: int
     ffn_dim: int  # the dense SwiGLU's width; 0: this is an expert layer
     moe_ffn_dim: int
     num_experts: int
@@ -117,24 +111,20 @@ class Lfm2Block(nn.Module):
     def _norm(self, name: str) -> RMSNorm:
         return RMSNorm(unit_offset=False, param_dtype=self.param_dtype, name=name)
 
-    def _conv(self, h: jnp.ndarray, read: np.ndarray | None) -> jnp.ndarray:
-        dim = h.shape[-1]
-        with jax.named_scope("conv_in"):
-            bcu = self._dense(3 * dim, "in_proj")(h)
-        mixed = short_conv(bcu, _Taps(self.conv_width, self.param_dtype, name="conv")(dim))
-        with jax.named_scope("conv_out"):
-            return self._dense(dim, "out_proj")(mixed if read is None else mixed[:, read])
-
     def _attention(self, h: jnp.ndarray, read: np.ndarray | None) -> jnp.ndarray:
+        if self.layer_type == "sliding_attention":
+            return grouped_query_attention(
+                self, h, read, head_dim=self.head_dim, scopes=SWA_SCOPES,
+                window=self.window, turn=True,
+            )
         return grouped_query_attention(
-            self, h, read, head_dim=h.shape[-1] // self.heads, scopes=GQA_SCOPES
+            self, h, read, head_dim=self.head_dim, scopes=GQA_SCOPES,
+            turn=False, query_block=FULL_QUERY_BLOCK,
         )
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, read: np.ndarray | None = None) -> jnp.ndarray:
-        h = self._norm("operator_norm")(x).astype(self.dtype)
-        mixer = {"conv": self._conv, "full_attention": self._attention}[self.layer_type]
-        mixed = mixer(h, read)
+        mixed = self._attention(self._norm("attn_norm")(x).astype(self.dtype), read)
         if read is not None:
             x = x[:, read]
         x = x + mixed.astype(jnp.float32)
@@ -144,31 +134,34 @@ class Lfm2Block(nn.Module):
             with jax.named_scope("ffn"):
                 out = swiglu(self, h.astype(self.dtype), self.ffn_dim).astype(jnp.float32)
         else:
-            out = routed_experts(self, h, scaling=ROUTED_SCALING, eps=ROUTE_EPS)
+            out = experts_beside_a_shared_one(
+                self, h, scaling=ROUTED_SCALING, eps=ROUTE_EPS
+            )
         return x + out.reshape(b, seq, dim)
 
 
-class Lfm2MoeScorer(nn.Module):
+class ExaoneMoeScorer(nn.Module):
     """``apply(vars, cat_ids, numeric, train) -> logits[f32 N]``: the zoo
     convention, one logit a record, read at the record's last token."""
 
     cards: Sequence[int]
     num_numeric: int
     layer_types: Sequence[str]  # at least ``depth`` entries; layer i takes the i-th
-    hidden: int = 2048
-    depth: int = 24
-    heads: int = 32
+    hidden: int = 6144
+    depth: int = 48
+    heads: int = 64
     kv_heads: int = 8
-    conv_width: int = 3
-    ffn_dim: int = 7168
-    moe_ffn_dim: int = 1792
-    num_experts: int = 32
-    experts_per_token: int = 4
+    head_dim: int = 128
+    window: int = 128
+    ffn_dim: int = 18432
+    moe_ffn_dim: int = 2048
+    num_experts: int = 128
+    experts_per_token: int = 8
     first_expert: int = 0
-    experts_held: int = 32
-    vocab_rows: int = 65536
+    experts_held: int = 128
+    vocab_rows: int = 153600
     records_per_history: int = 64
-    dense_layers: int = 2  # the source's num_dense_layers
+    dense_layers: int = 1  # the source's first_k_dense_replace
     rope_theta: float = 1000000.0
     num_bins: int = 32
     dtype: jnp.dtype = jnp.bfloat16
@@ -200,10 +193,10 @@ class Lfm2MoeScorer(nn.Module):
                 f"layer_types names {len(listed)} of {self.depth} layers, "
                 f"each one of {LAYER_TYPES}: {listed}"
             )
-        if self.heads % self.kv_heads or self.hidden % self.heads:
+        if self.heads % self.kv_heads or self.head_dim % 2 or self.window < 1:
             raise ValueError(
                 f"{self.heads} query heads over {self.kv_heads} key/value heads "
-                f"in a hidden size of {self.hidden}"
+                f"of {self.head_dim}, a window of {self.window}"
             )
 
     @nn.compact
@@ -224,11 +217,12 @@ class Lfm2MoeScorer(nn.Module):
                 param_dtype=self.param_dtype, name="tok_embed",
             )(tokens * stride).astype(jnp.float32)
         for i in range(self.depth):
-            x = Lfm2Block(
+            x = ExaoneBlock(
                 layer_type=self.layer_types[i],
                 heads=self.heads,
                 kv_heads=self.kv_heads,
-                conv_width=self.conv_width,
+                head_dim=self.head_dim,
+                window=self.window,
                 ffn_dim=self.ffn_dim if i < self.dense_layers else 0,
                 moe_ffn_dim=self.moe_ffn_dim,
                 num_experts=self.num_experts,

@@ -1,0 +1,66 @@
+"""Causal grouped-query attention with normed heads as the token-level
+decoders build it (`models/lfm2_moe.py`, `models/exaone_moe.py`): the four
+projections and the two head norms under the CALLING block's own names
+(``q``, ``k``, ``v``, ``o``, ``q_norm``, ``k_norm``), the optional turn
+(`ops/eva_attention.py rope`), `ops/causal_attention.py causal_attend` and
+the output projection, under the three scopes the caller names.
+
+A family differs in what it passes: a head's width (its own, or ``hidden
+// heads``), the window (``None``: every key up to the query), whether the
+layer turns its queries and keys, and the scopes' names. The head counts,
+the rotary base and the dtypes are the block's own fields (``heads``,
+``kv_heads``, ``rope_theta``, ``dtype``), the layers its ``_dense`` and
+``_norm``.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from flax import linen as nn
+
+from mlops_tpu.ops.causal_attention import QUERY_BLOCK, causal_attend
+from mlops_tpu.ops.eva_attention import rope
+
+# the scopes of a layer that sees every key so far, whichever family runs it
+# (`benchmark/layer_metrics/bulk_gqa_device_pct.py` reads them)
+GQA_SCOPES = ("gqa_qkv", "gqa_attend", "gqa_o")
+
+
+def grouped_query_attention(
+    block: nn.Module,
+    h: jnp.ndarray,
+    read: np.ndarray | None,
+    *,
+    head_dim: int,
+    scopes: tuple[str, str, str],
+    window: int | None = None,
+    turn: bool = True,
+    query_block: int = QUERY_BLOCK,
+) -> jnp.ndarray:
+    """``h`` ``[B, S, dim]`` (normed, in the products' dtype) -> ``[B, S,
+    dim]``, or ``[B, len(read), dim]`` with ``read``: keys and values at
+    every position, everything else at the read positions. Called inside
+    ``block``'s compact ``__call__``: the parameters become the block's.
+    ``scopes`` names the projections with the head norms and the turn, the
+    attention, and the output projection."""
+    b, seq, dim = h.shape
+    heads, kv_heads, width = block.heads, block.kv_heads, head_dim
+    project, attend, output = scopes
+    with jax.named_scope(project):
+        asked = h if read is None else h[:, read]
+        q = block._dense(heads * width, "q")(asked).reshape(b, -1, heads, width)
+        k = block._dense(kv_heads * width, "k")(h).reshape(b, seq, kv_heads, width)
+        v = block._dense(kv_heads * width, "v")(h).reshape(b, seq, kv_heads, width)
+        # a head's query and key are normed (float32) before they turn
+        q, k = block._norm("q_norm")(q), block._norm("k_norm")(k)
+        if turn:
+            q, k = rope(q, block.rope_theta, positions=read), rope(k, block.rope_theta)
+    with jax.named_scope(attend):
+        mixed = causal_attend(
+            q.astype(block.dtype), k.astype(block.dtype), v, width**-0.5, read=read,
+            query_block=query_block, window=window,
+        )
+    with jax.named_scope(output):
+        return block._dense(dim, "o")(mixed.reshape(b, -1, heads * width))

@@ -62,8 +62,9 @@ from mlops_tpu.models.evabyte import RMSNorm
 from mlops_tpu.models.routed_experts import (
     ROUTING,
     check_share,
-    routed_experts,
+    experts_beside_a_shared_one,
     routing_counts,
+    swiglu,
 )
 from mlops_tpu.ops.eva_attention import rope
 from mlops_tpu.ops.mla import mla_attend, softmax_scale, yarn_inv_freq
@@ -103,12 +104,6 @@ class KimiBlock(nn.Module):
     def _norm(self, name: str) -> RMSNorm:
         return RMSNorm(unit_offset=False, param_dtype=self.param_dtype, name=name)
 
-    def _swiglu(self, h: jnp.ndarray, width: int, prefix: str) -> jnp.ndarray:
-        gate = self._dense(width, f"{prefix}gate")(h)
-        up = self._dense(width, f"{prefix}up")(h)
-        gated = nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-        return self._dense(h.shape[-1], f"{prefix}down")(gated.astype(self.dtype))
-
     def _attention(self, x: jnp.ndarray, read: np.ndarray | None) -> jnp.ndarray:
         b, _, dim = x.shape
         nope, rot, wide = self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim
@@ -135,13 +130,6 @@ class KimiBlock(nn.Module):
         with jax.named_scope("mla_o"):
             return self._dense(dim, "o")(mixed)
 
-    def _experts(self, h: jnp.ndarray) -> jnp.ndarray:
-        """``h`` float32 ``[T, dim]`` -> float32 ``[T, dim]``."""
-        routed = routed_experts(self, h, scaling=self.routed_scaling, eps=ROUTE_EPS)
-        with jax.named_scope("shared_expert"):
-            shared = self._swiglu(h.astype(self.dtype), self.moe_ffn_dim, "shared_")
-        return routed + shared.astype(jnp.float32)
-
     @nn.compact
     def __call__(self, x: jnp.ndarray, read: np.ndarray | None = None) -> jnp.ndarray:
         mixed = self._attention(x, read)
@@ -152,9 +140,11 @@ class KimiBlock(nn.Module):
         h = self._norm("ffn_norm")(x).reshape(b * seq, dim)
         if self.ffn_dim:
             with jax.named_scope("ffn"):
-                out = self._swiglu(h.astype(self.dtype), self.ffn_dim, "").astype(jnp.float32)
+                out = swiglu(self, h.astype(self.dtype), self.ffn_dim).astype(jnp.float32)
         else:
-            out = self._experts(h)
+            out = experts_beside_a_shared_one(
+                self, h, scaling=self.routed_scaling, eps=ROUTE_EPS
+            )
         return x + out.reshape(b, seq, dim)
 
 

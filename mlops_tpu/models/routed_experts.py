@@ -6,12 +6,15 @@ dropless dispatch of `ops/moe_dispatch.py`, and the routing counter.
 
 A family differs in its block's fields (how many experts a token chooses,
 which experts this process holds) and in what it passes: the scaling and
-the normaliser's epsilon of its router. What else a layer adds (a shared
-expert) is the block's.
+the normaliser's epsilon of its router. The one SwiGLU the three decoders
+run outside the routed experts is here too (`swiglu`: a dense layer's FFN,
+and the shared expert that `experts_beside_a_shared_one` adds unweighted,
+`models/kimi_k2.py` and `models/exaone_moe.py`).
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -99,6 +102,29 @@ def routed_experts(
         stacked(width, dim, "experts_down"),
         moe_dispatch.segment_rows(tokens, top_k, block.num_experts, held),
     )
+
+
+def swiglu(block: nn.Module, h: jnp.ndarray, width: int, prefix: str = "") -> jnp.ndarray:
+    """``down(silu(gate h) * up h)`` of ``width`` through the block's own
+    ``_dense`` layers ``<prefix>gate``, ``<prefix>up``, ``<prefix>down``:
+    the gate's activation and the product in float32, rounded once."""
+    gate = block._dense(width, f"{prefix}gate")(h)
+    up = block._dense(width, f"{prefix}up")(h)
+    gated = nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+    return block._dense(h.shape[-1], f"{prefix}down")(gated.astype(block.dtype))
+
+
+def experts_beside_a_shared_one(
+    block: nn.Module, h: jnp.ndarray, *, scaling: float, eps: float
+) -> jnp.ndarray:
+    """`routed_experts` plus ONE shared expert of the routed experts' width
+    (``shared_{gate,up,down}``, scope ``shared_expert``), added unweighted
+    and whole whatever share of the routed experts is held: ``h`` float32
+    ``[T, dim]`` -> float32 ``[T, dim]``."""
+    routed = routed_experts(block, h, scaling=scaling, eps=eps)
+    with jax.named_scope("shared_expert"):
+        shared = swiglu(block, h.astype(block.dtype), block.moe_ffn_dim, "shared_")
+    return routed + shared.astype(jnp.float32)
 
 
 def routing_counts(state: dict) -> jnp.ndarray:

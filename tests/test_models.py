@@ -10,6 +10,14 @@ from mlops_tpu.models import FAMILIES, build_model, init_params
 from mlops_tpu.schema import NUM_CATEGORICAL, NUM_NUMERIC
 
 
+# what a family has to be told beside the shared sizes: `exaone_moe` has no
+# list of its own among `ModelConfig`'s defaults (`layer_types` is
+# `lfm2_moe`'s published list, which it refuses)
+FAMILY_FIELDS = {
+    "exaone_moe": {"layer_types": ("sliding_attention", "full_attention"), "attn_window": 16},
+}
+
+
 def _dummy_batch(n=16, seed=0):
     rng = np.random.default_rng(seed)
     cat = rng.integers(0, 2, size=(n, NUM_CATEGORICAL)).astype(np.int32)
@@ -20,7 +28,8 @@ def _dummy_batch(n=16, seed=0):
 @pytest.mark.parametrize("family", FAMILIES)
 def test_forward_shapes(family):
     config = ModelConfig(
-        family=family, hidden_dims=(32, 32), token_dim=32, depth=2, heads=4
+        family=family, hidden_dims=(32, 32), token_dim=32, depth=2, heads=4,
+        **FAMILY_FIELDS.get(family, {}),
     )
     model = build_model(config)
     variables = init_params(model, jax.random.PRNGKey(0))
@@ -34,7 +43,8 @@ def test_forward_shapes(family):
 @pytest.mark.parametrize("family", FAMILIES)
 def test_forward_deterministic_eval(family):
     config = ModelConfig(
-        family=family, hidden_dims=(32,), token_dim=32, depth=1, heads=4
+        family=family, hidden_dims=(32,), token_dim=32, depth=1, heads=4,
+        **FAMILY_FIELDS.get(family, {}),
     )
     model = build_model(config)
     variables = init_params(model, jax.random.PRNGKey(1))

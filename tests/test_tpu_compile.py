@@ -578,6 +578,72 @@ def test_lfm2_moe_chunk_program_compiles_for_v5e_at_published_widths(
             assert "/k/" not in op_name and "/v/" not in op_name and "repeat" not in op_name, op_name
 
 
+def test_exaone_moe_chunk_program_compiles_for_v5e_at_published_widths(
+    one_chip, no_persistent_cache
+):
+    """The bulk chunk program of `k-exaone-236b-a23b.bulk-hist` as the cell
+    runs it (`parallel/bulk.py make_bulk_fused` over `models/exaone_moe.py`
+    at the configuration file's widths, eight histories of 64 records a run,
+    bfloat16 parameters, 16 of 128 experts held): it fits the 75% rule its
+    chunk was sized by (`benchmark/compile_check.py`), nothing holds a
+    float32 copy of the stacked experts or of the embedding, the routed
+    experts' products of its four sparse layers are the two grouped
+    kernels, the counter is the program's third output, and a window
+    layer's scores are the band's tiles (24 blocks of 128 queries, 8 query
+    heads a group side by side, against 256 keys), never a history's
+    square."""
+    import json
+    from pathlib import Path
+
+    from mlops_tpu.config import ModelConfig
+    from mlops_tpu.models import abstract_variables, build_model
+    from mlops_tpu.monitor.state import abstract_monitor_state
+    from mlops_tpu.parallel.bulk import make_bulk_fused
+    from mlops_tpu.schema import SCHEMA
+
+    real = json.loads(
+        (Path(__file__).resolve().parents[1] / "benchmark/configs/k-exaone-236b-a23b.json")
+        .read_text()
+    )
+    fields = dict(real["model_config"])
+    fields["hidden_dims"] = tuple(fields["hidden_dims"])
+    model = build_model(ModelConfig(**fields))
+    rows = real["deployment"]["score_chunk_rows"]
+    assert rows == 512 and model.depth == 5
+    compiled = (
+        jax.jit(make_bulk_fused(model))
+        .lower(
+            _on(abstract_variables(model), one_chip),
+            _on(abstract_monitor_state(), one_chip),
+            S((), jnp.float32, sharding=one_chip),
+            S((rows, SCHEMA.num_categorical), jnp.int8, sharding=one_chip),
+            S((rows, SCHEMA.num_numeric), jnp.float32, sharding=one_chip),
+            S((rows,), jnp.bool_, sharding=one_chip),
+        )
+        .compile()
+    )
+    memory = compiled.memory_analysis()
+    needed = memory.temp_size_in_bytes + memory.argument_size_in_bytes
+    assert 7.18e9 < memory.argument_size_in_bytes < 7.20e9  # 3.59 B parameters, 2 bytes each
+    assert needed <= 0.75 * 15.75 * 2**30, needed
+    assert memory.temp_size_in_bytes <= 4.6e9, memory.temp_size_in_bytes
+    text = compiled.as_text()
+    assert not re.search(r"f32\[16,6144,2048\]|f32\[16,2048,6144\]|f32\[19200,6144\]", text)
+    _assert_the_experts_products_are_the_kernels(text, layers=4)
+    assert re.search(r"s32\[2,4,16\]", text), "the routing counter is not an output"
+    # a window layer's scores: [histories, blocks, groups, 8 heads x 128 queries, 256 keys]
+    assert re.search(r"f32\[8,24,8,1024,256\]", text), "the band's tiles"
+    for line in text.splitlines():  # a history's square of scores is the full layer's alone
+        if re.search(r"= f32\[8,8,\d+,(3072|2816|2560)\]", line) and "op_name=" in line:
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert "gqa_attend" in op_name.split("/"), op_name
+    # 8 key/value heads stay 8: no key or value repeated to the query heads' count
+    for line in text.splitlines():
+        if re.search(r"= bf16\[8,3072,64,128\]", line) and "op_name=" in line:
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert "/k/" not in op_name and "/v/" not in op_name and "repeat" not in op_name, op_name
+
+
 def test_mla_attention_compiles_for_v5e_at_the_longest_sequence_its_rule_admits(
     one_chip, no_persistent_cache
 ):
