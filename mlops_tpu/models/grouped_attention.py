@@ -2,8 +2,18 @@
 decoders build it (`models/lfm2_moe.py`, `models/exaone_moe.py`): the four
 projections and the two head norms under the CALLING block's own names
 (``q``, ``k``, ``v``, ``o``, ``q_norm``, ``k_norm``), the optional turn
-(`ops/eva_attention.py rope`), `ops/causal_attention.py causal_attend` and
-the output projection, under the three scopes the caller names.
+(`ops/eva_attention.py rope`), `ops/gqa_attention.py gqa_attend` and the
+output projection, under the three scopes the caller names.
+
+Which form of the attention runs where (`ops/gqa_attention.py` has it in
+full): a layer that answers every position of a whole history, full or
+windowed, is ONE call of the Pallas kernel ``gqa_attend_fwd`` under the
+caller's second scope wherever the program is lowered for a TPU and
+`wants_gqa_kernel` admits the shape (both published ones: `exaone_moe`'s
+heads of 128, full and window 128; `lfm2_moe`'s heads of 64); every other
+platform and shape, the ``read`` form (a model's last layer) and the
+backward are `ops/causal_attention.py causal_attend` in plain XLA, which
+``query_block`` steers and nothing else.
 
 A family differs in what it passes: a head's width (its own, or ``hidden
 // heads``), the window (``None``: every key up to the query), whether the
@@ -20,8 +30,9 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from mlops_tpu.ops.causal_attention import QUERY_BLOCK, causal_attend
+from mlops_tpu.ops.causal_attention import QUERY_BLOCK
 from mlops_tpu.ops.eva_attention import rope
+from mlops_tpu.ops.gqa_attention import gqa_attend
 
 # the scopes of a layer that sees every key so far, whichever family runs it
 # (`benchmark/layer_metrics/bulk_gqa_device_pct.py` reads them)
@@ -58,7 +69,7 @@ def grouped_query_attention(
         if turn:
             q, k = rope(q, block.rope_theta, positions=read), rope(k, block.rope_theta)
     with jax.named_scope(attend):
-        mixed = causal_attend(
+        mixed = gqa_attend(
             q.astype(block.dtype), k.astype(block.dtype), v, width**-0.5, read=read,
             query_block=query_block, window=window,
         )

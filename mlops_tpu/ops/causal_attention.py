@@ -1,7 +1,17 @@
 """Causal softmax attention in plain XLA, one block of queries at a time:
-the one form the token-level decoders call (`ops/mla.py mla_attend_xla`;
+the definition of what the token-level decoders compute (`ops/mla.py
+mla_attend_xla`; `ops/gqa_attention.py gqa_attend` under
 `models/grouped_attention.py` for `models/lfm2_moe.py` and
 `models/exaone_moe.py`), under whichever scope the caller opens.
+
+Which form runs where: on a TPU the layers that answer every position of
+a whole history run a Pallas kernel in this one's place wherever their
+predicate admits the shape (`ops/mla.py mla_attend_fwd`;
+`ops/gqa_attention.py gqa_attend_fwd`, full and windowed, at the published
+shapes of both grouped-query families). This form is what runs on every
+other platform, at every shape the predicates refuse, with ``read`` (a
+model's last layer) and in the backward everywhere: the kernels'
+``custom_vjp``s differentiate it, recomputed.
 
 Query heads may outnumber key/value heads (grouped-query attention):
 head ``i`` of ``H`` reads key/value head ``i // (H // G)`` of ``G``. The

@@ -417,6 +417,29 @@ def _assert_the_experts_products_are_the_kernels(text: str, layers: int) -> None
     assert "ragged-dot" not in text
 
 
+def _assert_the_attention_is_the_kernel(text: str, calls: dict[str, int], seq: int) -> None:
+    """The layers that answer every position of a history are
+    `ops/gqa_attention.py`'s Mosaic call, ``calls[scope]`` of them under
+    each scope a trace's readers key on, and nothing under those scopes is
+    a float32 array with an axis of ``seq`` keys (or of a block's share of
+    them): the scores stay in VMEM."""
+    mine = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "gqa_attend_fwd" in line.split(" = ")[0]
+    ]
+    found = {scope: 0 for scope in calls}
+    for call in mine:
+        op_name = re.search(r'op_name="([^"]*)"', call).group(1).split("/")
+        (scope,) = [s for s in calls if s in op_name]
+        found[scope] += 1
+    assert found == calls and len(mine) == sum(calls.values()), (found, len(mine))
+    wide = "|".join(str(n) for n in range(seq, seq // 2, -128))
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        if name and set(calls) & set(name.group(1).split("/")):
+            assert not re.search(rf"= f32\[[\d,]*,({wide})\]", line), line[:300]
+
+
 def _scoped_instructions(text: str, scope: str, kind: str) -> list[str]:
     """The program's ``kind`` instructions (``scatter``, ``gather``,
     ``sort``) whose ``op_name`` lies under ``scope``, fused or not."""
@@ -519,9 +542,12 @@ def test_lfm2_moe_chunk_program_compiles_for_v5e_at_published_widths(
     two grouped kernels (gate and up with the SwiGLU, then down, in
     fourteen expert layers) and leave no float32 ``[rows, f]`` in HBM, the
     counter is the program's third output, grouped attention holds no
-    keys or values repeated to the query heads' count, and the combine (one
-    segment holds every assignment) is a gather of each token's four rows,
-    slots outermost, and a reduction: no scatter under ``moe_combine``."""
+    keys or values repeated to the query heads' count and is ONE
+    ``gqa_attend_fwd`` kernel call a layer (`ops/gqa_attention.py` admits
+    heads of 64: a pair of key/value heads a lane tile) that leaves no
+    float32 scores in HBM, and the combine (one segment holds every
+    assignment) is a gather of each token's four rows, slots outermost, and
+    a reduction: no scatter under ``moe_combine``."""
     import json
     from pathlib import Path
 
@@ -565,6 +591,7 @@ def test_lfm2_moe_chunk_program_compiles_for_v5e_at_published_widths(
     assert "f32[49152,1792]" not in text
     assert memory.temp_size_in_bytes <= 1.145e9, memory.temp_size_in_bytes
     assert re.search(r"s32\[2,14,32\]", text), "the routing counter is not an output"
+    _assert_the_attention_is_the_kernel(text, {"gqa_attend": 4}, seq=3072)
     assert not _scoped_instructions(text, "moe_combine", "scatter")
     # `plan`'s sort and the inverse's, a layer
     assert len(_scoped_instructions(text, "moe_dispatch", "sort")) == 28
@@ -588,10 +615,12 @@ def test_exaone_moe_chunk_program_compiles_for_v5e_at_published_widths(
     chunk was sized by (`benchmark/compile_check.py`), nothing holds a
     float32 copy of the stacked experts or of the embedding, the routed
     experts' products of its four sparse layers are the two grouped
-    kernels, the counter is the program's third output, and a window
-    layer's scores are the band's tiles (24 blocks of 128 queries, 8 query
-    heads a group side by side, against 256 keys), never a history's
-    square."""
+    kernels, the counter is the program's third output, and the four
+    layers that answer every position (three window layers and the full
+    one) are ONE ``gqa_attend_fwd`` kernel call each under their scopes,
+    with no float32 scores a history wide left in HBM; the last layer, a
+    window layer at the read positions, is the XLA form's 128 keys a
+    position."""
     import json
     from pathlib import Path
 
@@ -631,12 +660,11 @@ def test_exaone_moe_chunk_program_compiles_for_v5e_at_published_widths(
     assert not re.search(r"f32\[16,6144,2048\]|f32\[16,2048,6144\]|f32\[19200,6144\]", text)
     _assert_the_experts_products_are_the_kernels(text, layers=4)
     assert re.search(r"s32\[2,4,16\]", text), "the routing counter is not an output"
-    # a window layer's scores: [histories, blocks, groups, 8 heads x 128 queries, 256 keys]
-    assert re.search(r"f32\[8,24,8,1024,256\]", text), "the band's tiles"
-    for line in text.splitlines():  # a history's square of scores is the full layer's alone
-        if re.search(r"= f32\[8,8,\d+,(3072|2816|2560)\]", line) and "op_name=" in line:
-            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
-            assert "gqa_attend" in op_name.split("/"), op_name
+    _assert_the_attention_is_the_kernel(text, {"swa_attend": 3, "gqa_attend": 1}, seq=3072)
+    # the band's tiles of before the kernel: [histories, blocks, groups, 8 x 128 queries, 256 keys]
+    assert not re.search(r"f32\[8,24,8,1024,256\]", text)
+    # the last layer reads 64 positions a history, each against its window's 128 keys
+    assert re.search(r"f32\[8,64,8,8,128\]", text), "the read form's scores"
     # 8 key/value heads stay 8: no key or value repeated to the query heads' count
     for line in text.splitlines():
         if re.search(r"= bf16\[8,3072,64,128\]", line) and "op_name=" in line:
@@ -667,3 +695,49 @@ def test_mla_attention_compiles_for_v5e_at_the_longest_sequence_its_rule_admits(
     assert "mla_attend_fwd" in _compile(attend, *operands(MAX_VISIT_KEYS))
     assert "tpu_custom_call" not in _compile(attend, *operands(1025))
 
+
+def test_gqa_attention_compiles_for_v5e_at_the_published_and_the_longest_shapes(
+    one_chip, no_persistent_cache
+):
+    """`ops/gqa_attention.py gqa_attend` as both cells call it
+    (`k-exaone-236b-a23b`: 64 heads over 8 of 128, full and window 128;
+    `lfm2-8b-a1b`: 32 over 8 of 64) and at the most VMEM and the most code
+    its rule lets a kernel ask for: a full layer's last visit of 4,096
+    keys, short histories whose steps stack two and four lane tiles of
+    heads up to `MAX_STEP_SCORES`, windows whose steps stack eight and
+    four, the widest window at all (36 places) and the longest history
+    held as one tile's keys and values. A ragged history and the ``read``
+    form take the XLA form: no kernel."""
+    import numpy as np
+
+    from mlops_tpu.ops.gqa_attention import MAX_KEYS, gqa_attend
+
+    def operands(batch, seq, heads, kv_heads, width, asked=None):
+        return tuple(
+            S((batch, n, h, width), jnp.bfloat16, sharding=one_chip)
+            for n, h in ((asked or seq, heads), (seq, kv_heads), (seq, kv_heads))
+        )
+
+    def attend(window=None, read=None):
+        return lambda *xs: gqa_attend(*xs, 0.1, read=read, window=window)
+
+    for window, shape in [
+        (None, (8, 3072, 64, 8, 128)),
+        (128, (8, 3072, 64, 8, 128)),
+        (None, (4, 3072, 32, 8, 64)),
+        (None, (1, 4096, 8, 1, 128)),
+        (None, (1, 2048, 8, 1, 128)),
+        (None, (1, 1024, 8, 1, 128)),
+        (None, (1, 2048, 8, 2, 64)),
+        (1025, (1, 4096, 8, 1, 128)),
+        (1921, (1, 4096, 8, 1, 128)),
+        (4481, (1, 8192, 8, 1, 128)),
+        (128, (1, MAX_KEYS, 8, 1, 128)),
+    ]:
+        text = _compile(attend(window), *operands(*shape))
+        assert "gqa_attend_fwd" in text, (window, shape)
+    assert "tpu_custom_call" not in _compile(attend(), *operands(8, 3072 - 48, 64, 8, 128))
+    read = np.arange(47, 3072, 48)
+    assert "tpu_custom_call" not in _compile(
+        attend(128, read), *operands(8, 3072, 64, 8, 128, asked=len(read))
+    )
