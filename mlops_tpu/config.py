@@ -40,7 +40,7 @@ class DataConfig:
 
 # Causal families that take the zoo's 2-D rows, read every `doc_records`
 # consecutive rows as one history and answer every record.
-HISTORY_FAMILIES = ("evabyte", "kimi_k2", "lfm2_moe", "exaone_moe")
+HISTORY_FAMILIES = ("evabyte", "kimi_k2", "lfm2_moe", "exaone_moe", "falcon_h1")
 # LFM2-8B-A1B's published `layer_types`: which token mixer each of its 24
 # layers runs (family lfm2_moe's default)
 LFM2_LAYER_TYPES = (
@@ -54,16 +54,16 @@ LFM2_LAYER_TYPES = (
 @dataclasses.dataclass
 class ModelConfig:
     family: str = "mlp"  # mlp | ft_transformer | moe | linear | bert |
-    # evabyte | kimi_k2 | lfm2_moe | exaone_moe | gbm | rf
+    # evabyte | kimi_k2 | lfm2_moe | exaone_moe | falcon_h1 | gbm | rf
     hidden_dims: tuple[int, ...] = (256, 256, 128)
     embed_dim: int = 16
     dropout: float = 0.1
     precision: str = "bf16"  # compute dtype on MXU: bf16 | f32 (params stay f32)
     param_dtype: str = "f32"  # the dtype parameters are STORED in, on disk
-    # and on the device: f32 | bf16. The sparse decoders (kimi_k2,
-    # lfm2_moe, exaone_moe) alone take bf16 (what a chip holds of them does
-    # not fit it at four bytes a parameter); nothing casts the tree in the
-    # program, a product reads its leaf as stored
+    # and on the device: f32 | bf16. The token-level decoders (kimi_k2,
+    # lfm2_moe, exaone_moe, falcon_h1) alone take bf16 (what a chip holds of
+    # them does not fit it at four bytes a parameter); nothing casts the
+    # tree in the program, a product reads its leaf as stored
     ensemble_size: int = 1  # >1 wraps the Flax family in a vmapped deep
     # ensemble (models/ensemble.py) — the MXU-native answer to the
     # reference's RandomForest variance reduction; 1 = single model
@@ -83,8 +83,8 @@ class ModelConfig:
     # the LAST record's default from the history (training path
     # `train/long_context.py`); family evabyte is causal, takes the zoo's
     # 2-D rows and answers EVERY record, conditioned on the records before
-    # it in its history, as do the token-level decoders kimi_k2,
-    # lfm2_moe and exaone_moe. `seq_parallel` routes bert's attention through
+    # it in its history, as do the token-level decoders kimi_k2, lfm2_moe,
+    # exaone_moe and falcon_h1. `seq_parallel` routes bert's attention through
     # the ppermute ring (`parallel.make_ring_attention`) over the mesh's
     # 'seq' axis.
     doc_records: int = 1
@@ -157,6 +157,31 @@ class ModelConfig:
     # source states apart from the hidden size (64 heads of 128 in a hidden
     # size of 6,144); 0 = `token_dim // heads`.
     head_dim: int = 0
+    # Family falcon_h1 (models/falcon_h1.py; in EVERY layer a Mamba-2
+    # state-space mixer, `ops/ssd.py` behind `ops/short_conv.py causal_conv`,
+    # and the grouped-query attention above, unnormed and turned, read one
+    # normed input and are summed; then a dense SwiGLU of `ffn_dim`). Beside
+    # `token_dim`, `depth`, `heads`, `kv_heads`, `head_dim`, `ffn_dim`,
+    # `conv_width`, `rope_theta`, `vocab_rows`: the mixer's inner width
+    # (`ssm_heads` heads of `ssm_dim // ssm_heads`), a head's state, the
+    # groups that share B and C, the scan's chunk; and the source's muP
+    # multipliers, each a constant of the forward pass: on the embedding,
+    # into and out of each mixer, on the keys, on the five parts of the
+    # mixer's input projection (z, x, B, C, dt) and on the SwiGLU's gate
+    # and output.
+    ssm_dim: int = 4096
+    ssm_heads: int = 32
+    ssm_state: int = 256
+    ssm_groups: int = 2
+    ssm_chunk: int = 128
+    embedding_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: tuple[float, ...] = (1.0, 1.0)
 
     @property
     def reads_documents(self) -> bool:

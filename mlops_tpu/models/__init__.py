@@ -31,6 +31,13 @@ the TPU-native zoo is:
   sparse routed experts beside a shared one (the expert layer of
   ``kimi_k2``, the attention of ``lfm2_moe``); histories, tokens and
   bfloat16 parameters as ``kimi_k2``
+- ``falcon_h1``       token-level causal hybrid decoder whose every layer
+  runs a Mamba-2 state-space mixer (a chunked selective scan behind a
+  plain causal short convolution) and grouped-query attention (unnormed
+  heads, turned) side by side on one normed input and sums them, each
+  path and projection under a muP multiplier of the configuration; a
+  dense SwiGLU in every layer, no experts; histories, tokens and bfloat16
+  parameters as ``kimi_k2``
 
 All families share one calling convention:
 ``model.apply(vars, cat_ids[int32 N,C], numeric[f32 N,M], train=...) ->
@@ -50,6 +57,7 @@ from mlops_tpu.models.bert import BertEncoder
 from mlops_tpu.models.ensemble import DeepEnsemble
 from mlops_tpu.models.evabyte import EvaByteScorer
 from mlops_tpu.models.exaone_moe import ExaoneMoeScorer
+from mlops_tpu.models.falcon_h1 import FalconH1Scorer
 from mlops_tpu.models.ft_transformer import FTTransformer
 from mlops_tpu.models.kimi_k2 import KimiK2Scorer
 from mlops_tpu.models.lfm2_moe import Lfm2MoeScorer
@@ -59,10 +67,10 @@ from mlops_tpu.schema.features import SCHEMA
 
 FAMILIES = (
     "linear", "mlp", "ft_transformer", "moe", "bert", "evabyte", "kimi_k2", "lfm2_moe",
-    "exaone_moe",
+    "exaone_moe", "falcon_h1",
 )
-# the sparse decoders, whose parameters may be STORED in bfloat16
-BF16_PARAM_FAMILIES = ("kimi_k2", "lfm2_moe", "exaone_moe")
+# the token-level decoders, whose parameters may be STORED in bfloat16
+BF16_PARAM_FAMILIES = ("kimi_k2", "lfm2_moe", "exaone_moe", "falcon_h1")
 
 
 def build_model(config: ModelConfig) -> nn.Module:
@@ -208,6 +216,36 @@ def build_model(config: ModelConfig) -> nn.Module:
             dtype=dtype,
             param_dtype=param_dtype,
         )
+    if config.family == "falcon_h1":
+        return FalconH1Scorer(
+            cards=SCHEMA.cards,
+            num_numeric=SCHEMA.num_numeric,
+            hidden=config.token_dim,
+            depth=config.depth,
+            heads=config.heads,
+            kv_heads=config.kv_heads or config.heads,
+            head_dim=config.head_dim or config.token_dim // config.heads,
+            ffn_dim=config.ffn_dim,
+            ssm_dim=config.ssm_dim,
+            ssm_heads=config.ssm_heads,
+            ssm_state=config.ssm_state,
+            ssm_groups=config.ssm_groups,
+            ssm_chunk=config.ssm_chunk,
+            conv_width=config.conv_width,
+            vocab_rows=config.vocab_rows,
+            records_per_history=config.doc_records,
+            rope_theta=config.rope_theta,
+            embedding_multiplier=config.embedding_multiplier,
+            attention_in_multiplier=config.attention_in_multiplier,
+            attention_out_multiplier=config.attention_out_multiplier,
+            key_multiplier=config.key_multiplier,
+            ssm_in_multiplier=config.ssm_in_multiplier,
+            ssm_out_multiplier=config.ssm_out_multiplier,
+            ssm_multipliers=tuple(config.ssm_multipliers),
+            mlp_multipliers=tuple(config.mlp_multipliers),
+            dtype=dtype,
+            param_dtype=param_dtype,
+        )
     from mlops_tpu.models.gbm import SKLEARN_FAMILIES
 
     if config.family in SKLEARN_FAMILIES:
@@ -247,6 +285,7 @@ __all__ = [
     "EvaByteScorer",
     "ExaoneMoeScorer",
     "FTTransformer",
+    "FalconH1Scorer",
     "KimiK2Scorer",
     "Lfm2MoeScorer",
     "LinearModel",

@@ -104,13 +104,19 @@ def routed_experts(
     )
 
 
-def swiglu(block: nn.Module, h: jnp.ndarray, width: int, prefix: str = "") -> jnp.ndarray:
-    """``down(silu(gate h) * up h)`` of ``width`` through the block's own
-    ``_dense`` layers ``<prefix>gate``, ``<prefix>up``, ``<prefix>down``:
-    the gate's activation and the product in float32, rounded once."""
-    gate = block._dense(width, f"{prefix}gate")(h)
+def swiglu(
+    block: nn.Module, h: jnp.ndarray, width: int, prefix: str = "", gate_scale: float = 1.0
+) -> jnp.ndarray:
+    """``down(silu(gate_scale * gate h) * up h)`` of ``width`` through the
+    block's own ``_dense`` layers ``<prefix>gate``, ``<prefix>up``,
+    ``<prefix>down``: the gate's activation and the product in float32,
+    rounded once. ``gate_scale`` is `models/falcon_h1.py`'s (a muP
+    multiplier); at 1 the program has no such multiply."""
+    gate = block._dense(width, f"{prefix}gate")(h).astype(jnp.float32)
     up = block._dense(width, f"{prefix}up")(h)
-    gated = nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+    if gate_scale != 1.0:
+        gate = gate * gate_scale
+    gated = nn.silu(gate) * up.astype(jnp.float32)
     return block._dense(h.shape[-1], f"{prefix}down")(gated.astype(block.dtype))
 
 

@@ -21,6 +21,13 @@ from the projection's output to the output projection's operand: no
 `lax.conv` (a depthwise convolution of width 3 would be lowered to one
 anyway) and no float32 copy of ``[T, 3 d]`` in HBM. The projections are
 the caller's (`models/lfm2_moe.py`, scopes ``conv_in`` and ``conv_out``).
+
+Beside it `causal_conv`, the PLAIN causal depthwise convolution of the
+Mamba family (`models/falcon_h1.py`, scope ``ssm_conv``): the same shifted
+multiply-adds over one input with no gate, a bias a channel, and SiLU
+after:
+
+    y[t] = silu(bias + sum_{j < L} w[j] * x[t - (L - 1) + j])
 """
 
 from __future__ import annotations
@@ -45,3 +52,17 @@ def short_conv(bcu: jnp.ndarray, taps: jnp.ndarray) -> jnp.ndarray:
     taps = taps.astype(jnp.float32)
     mixed = sum(taps[j] * gated[:, j : j + seq] for j in range(width))
     return (out_gate * mixed).astype(bcu.dtype)
+
+
+def causal_conv(x: jnp.ndarray, taps: jnp.ndarray, bias: jnp.ndarray) -> jnp.ndarray:
+    """``x`` ``[B, S, d]``, ``taps`` ``[L, d]``, ``bias`` ``[d]`` -> float32
+    ``[B, S, d]``: what follows (`ops/ssd.py`) rounds each part once, as
+    its products' operand. Zeros left of position 0."""
+    width, channels = taps.shape
+    if x.shape[-1] != channels or bias.shape != (channels,):
+        raise ValueError(f"{x.shape[-1]} channels, a bias of {bias.shape}, taps of {channels}")
+    seq = x.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
+    taps = taps.astype(jnp.float32)
+    mixed = sum(taps[j] * padded[:, j : j + seq] for j in range(width))
+    return jax.nn.silu(mixed + bias.astype(jnp.float32))

@@ -672,6 +672,84 @@ def test_exaone_moe_chunk_program_compiles_for_v5e_at_published_widths(
             assert "/k/" not in op_name and "/v/" not in op_name and "repeat" not in op_name, op_name
 
 
+def test_falcon_h1_chunk_program_compiles_for_v5e_at_published_widths(
+    one_chip, no_persistent_cache
+):
+    """The bulk chunk program of `falcon-h1-34b.bulk-hist` as the cell runs
+    it (`parallel/bulk.py make_bulk_fused` over `models/falcon_h1.py` at the
+    configuration file's widths, eight histories of 64 records a run,
+    bfloat16 parameters, six layers and the whole vocabulary): it fits the
+    75% rule its chunk was sized by (`benchmark/compile_check.py`), nothing
+    holds a float32 copy of a projection or of the embedding, the five
+    layers that answer every position run `gqa_attend_fwd` at a group of
+    FIVE query heads (a tiling `ops/gqa_attention.py _tiling` had not been
+    asked for before this family), the scan forms nothing a history wide
+    and hands its states over in ONE float32 array a layer, and the last
+    layer answers at the read positions."""
+    import json
+    from pathlib import Path
+
+    from mlops_tpu.config import ModelConfig
+    from mlops_tpu.models import abstract_variables, build_model
+    from mlops_tpu.monitor.state import abstract_monitor_state
+    from mlops_tpu.parallel.bulk import make_bulk_fused
+    from mlops_tpu.schema import SCHEMA
+
+    real = json.loads(
+        (Path(__file__).resolve().parents[1] / "benchmark/configs/falcon-h1-34b.json")
+        .read_text()
+    )
+    fields = dict(real["model_config"])
+    for name in ("hidden_dims", "ssm_multipliers", "mlp_multipliers"):
+        fields[name] = tuple(fields[name])
+    model = build_model(ModelConfig(**fields))
+    rows = real["deployment"]["score_chunk_rows"]
+    assert rows == 512 and model.depth == 6
+    compiled = (
+        jax.jit(make_bulk_fused(model))
+        .lower(
+            _on(abstract_variables(model), one_chip),
+            _on(abstract_monitor_state(), one_chip),
+            S((), jnp.float32, sharding=one_chip),
+            S((rows, SCHEMA.num_categorical), jnp.int8, sharding=one_chip),
+            S((rows, SCHEMA.num_numeric), jnp.float32, sharding=one_chip),
+            S((rows,), jnp.bool_, sharding=one_chip),
+        )
+        .compile()
+    )
+    memory = compiled.memory_analysis()
+    needed = memory.temp_size_in_bytes + memory.argument_size_in_bytes
+    assert 7.83e9 < memory.argument_size_in_bytes < 7.85e9  # 3.92 B parameters, 2 bytes each
+    assert needed <= 0.75 * 15.75 * 2**30, needed
+    assert memory.temp_size_in_bytes <= 3.7e9, memory.temp_size_in_bytes
+    text = compiled.as_text()
+    assert not re.search(
+        r"f32\[5120,9248\]|f32\[5120,21504\]|f32\[21504,5120\]|f32\[261120,5120\]", text
+    )
+    kernels = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "gqa_attend_fwd" in line.split(" = ")[0]
+    ]
+    assert len(kernels) == 5, len(kernels)  # every layer but the last
+    for call in kernels:
+        assert "gqa_attend" in re.search(r'op_name="([^"]*)"', call).group(1).split("/")
+        assert re.search(r"bf16\[8,3072,2560\]", call.split(" = ")[1])  # 20 heads of 128, as projected
+    # the last layer: 64 read positions of 5 query heads a key/value head against every key
+    assert re.search(r"f32\[8,4,320,3072\]", text), "the read form's scores"
+    # the scan: a chunk's decays and scores [histories, chunks, groups, heads a group,
+    # 128, 128], the states handed over [chunks, histories, groups, heads a group, 128,
+    # 256], and nothing 3,072 x 3,072
+    assert re.search(r"f32\[24,8,2,16,128,256\]", text), "the chunk states"
+    assert not re.search(r"\[[\d,]*3072,3072\]", text)
+    under_scan = [
+        line for line in text.splitlines()
+        if (name := re.search(r'op_name="([^"]*)"', line)) and "ssm_scan" in name.group(1).split("/")
+    ]
+    assert under_scan and not any(re.search(r"= f32\[[\d,]*,3072\]", line) for line in under_scan)
+    for scope in ("ssm_in", "ssm_conv", "ssm_scan", "ssm_out", "gqa_qkv", "gqa_o", "ffn", "embed"):
+        assert re.search(rf'op_name="[^"]*/{scope}[/"]', text), scope
+
+
 def test_mla_attention_compiles_for_v5e_at_the_longest_sequence_its_rule_admits(
     one_chip, no_persistent_cache
 ):
