@@ -10,7 +10,10 @@ data-parallel program.
 Mechanics (scaling-book recipe):
 - a chunk is padded to a fixed shape and jit'd with `in_shardings` that lay
   rows out over the mesh's 'data' axis; params replicate. XLA inserts the
-  (trivially few) collectives; every chunk reuses the same executable.
+  (trivially few) collectives; every chunk but the job's last reuses the
+  same executable, and the last, padded only to the smallest whole-history
+  power of two that holds it (``tail_chunk_rows``), is at most one more
+  signature of the same ``jax.jit``.
 - the compiled chunk program outlives the job: ``make_bulk_jit`` and
   ``make_bulk_quant_jit`` hand out the SAME ``jax.jit`` object for the
   same program from a small bounded keep (``ChunkProgramKeep``), so a
@@ -33,9 +36,10 @@ time went. ``phases`` holds the seconds of the four phases;
 ``compile_events`` what the job traced, lowered, compiled and took from
 JAX's persistent cache (`compilecache/events.py`), with
 ``chunk_program_reused``: 1 where the job found its chunk program compiled
-for its signature and so warmed nothing; ``stages`` the executor's busy
-seconds and queue waits by side (`utils/timing.py StageClock`); ``pauses``
-the garbage collector's share (`utils/timing.py PauseCounter`).
+for every signature it runs and so warmed nothing; ``stages`` the
+executor's busy seconds and queue waits by side (`utils/timing.py
+StageClock`); ``pauses`` the garbage collector's share (`utils/timing.py
+PauseCounter`).
 In a profiler trace the same phases are ``mlops:bulk.<phase>`` spans
 inside one ``mlops:bulk.job``, the pipeline's stage executions are
 ``mlops:pipe.<stage>`` spans on their own threads, and every one of them
@@ -88,9 +92,11 @@ FETCH_WAVE = 32
 
 # A bulk job's phases, in order: scorer + transfer build (the chunk
 # program taken from the keep); making sure that program is compiled for
-# the job's signature (`warm_chunk_scorer`: microseconds where it is, else
-# trace, lower, compile or cache load and one run on a chunk of zeros);
-# the pipelined sweep; the drift sample.
+# the job's first signature (`warm_chunk_scorer`: microseconds where it is,
+# else trace, lower, compile or cache load and one run on a chunk of
+# zeros; where the job's tail size is new, a wave of the body's chunks and
+# the tail's first dispatch, which traces, lowers and loads it while the
+# device runs them); the pipelined sweep; the drift sample.
 PHASES = ("build", "warmup", "sweep", "drift")
 
 # tpulint Layer-3 manifest: two leaf locks, never held together: one
@@ -108,8 +114,8 @@ KEPT_CHUNK_PROGRAMS = 4
 class KeptProgram:
     """One chunk program of the keep: its ``jax.jit`` object, whose own
     cache holds the executables, and the signatures it has run to
-    completion (`warm_chunk_scorer` reads and adds them: single set
-    operations, atomic under the GIL)."""
+    completion (`chunk_program_ready` reads them, `chunk_program_ran` adds
+    them: single set operations, atomic under the GIL)."""
 
     jitted: Callable
     compiled_for: set = dataclasses.field(default_factory=set)
@@ -223,8 +229,30 @@ def mesh_chunk_rows(
     (compilecache/warmup.py) must all agree, or a pre-warmed
     ``bulk-score-chunk`` artifact's signature never matches the shape the
     run actually dispatches (silent cache miss, full recompile)."""
-    unit = history_rows * (1 if mesh is None else int(mesh.shape["data"]))
+    unit = history_unit(mesh, history_rows)
     return max(unit, -(-chunk_rows // unit) * unit)
+
+
+def history_unit(mesh: Mesh | None, history_rows: int = 1) -> int:
+    """The rows no chunk may cut: one history on every shard of the mesh's
+    'data' axis. Every chunk size of a job is a whole number of them."""
+    return history_rows * (1 if mesh is None else int(mesh.shape["data"]))
+
+
+def tail_chunk_rows(rows: int, chunk: int, unit: int) -> int:
+    """THE one tail-size rule: the rows a job's last span is padded to.
+    ``chunk`` is the job's run size (``mesh_chunk_rows``), a whole number
+    of ``unit`` (``history_unit``). The last span holds ``rows - (ceil(rows
+    ÷ chunk) - 1) × chunk`` rows, and runs at the smallest ``unit × 2**k``
+    that holds them, never more than ``chunk``: no history and no shard is
+    cut, and a process's jobs of many lengths meet few tail shapes (each
+    one more signature of the same ``jax.jit``, compiled by the first job
+    that runs it). Rows that divide evenly, or a tail that rounds back up
+    to ``chunk``, give ``chunk``: the job runs one shape. A job of one
+    span runs at this size alone."""
+    tail = rows - (-(-rows // chunk) - 1) * chunk
+    units = -(-tail // unit)
+    return min(chunk, unit << (units - 1).bit_length())
 
 
 @dataclasses.dataclass
@@ -234,7 +262,10 @@ class BulkScoreResult:
     feature_drift: dict[str, float]  # per-feature 1 - p_val on the sample
     rows: int
     elapsed_s: float  # the pipelined sweep's wall time: no scorer build, no
-    # compile or warm-up chunk, no drift sample (``phases`` has those)
+    # warm-up chunk, no drift sample (``phases`` has those); a job whose
+    # tail size (``tail_chunk_rows``) the process had not run adds the
+    # seconds in which its warm-up dispatched a wave of the body and the
+    # tail, the tail's compile among them
     path: str = "exact"  # "exact" | "distilled" | "quant" — which params scored
     compile_cache: dict[str, Any] | None = None  # hit/miss/bypass counts +
     # per-program compile vs deserialize wall time (compilecache/cache.py)
@@ -266,7 +297,8 @@ class BulkScoreResult:
         """What the job traced, lowered, compiled and loaded
         (`compilecache/events.py CompileCounter.delta`), and
         ``chunk_program_reused``: 1 where it found its chunk program
-        compiled for its signature (`warm_chunk_scorer`)."""
+        compiled for every signature it ran, the body's and the tail's
+        (`chunk_program_ready`)."""
         return self.record and self.record["compile_events"]
 
     @property
@@ -386,7 +418,8 @@ def make_chunk_scorer(
 ):
     """One compiled program: (cat[chunk,C], num[chunk,M], mask[chunk]) ->
     (probs, outlier_flags), fixed-shape per call site (the caller feeds
-    equal-sized chunks so a single compile serves the whole sweep).
+    equal-sized chunks and at most one smaller tail, so one compile serves
+    the body of the sweep and one more its tail).
     Sharded over 'data' when a mesh is given. ``exact`` controls
     distilled-student routing (see ``use_distilled_bulk``); ``tier``
     routes the int8/bf16 quant student (``use_quant_bulk``) and, when it
@@ -494,12 +527,20 @@ def make_chunk_scorer(
 
 
 def warm_chunk_scorer(
-    scorer, transfer, chunk_rows: int, host_model: bool = False
+    scorer,
+    transfer,
+    chunk_rows: int,
+    host_model: bool = False,
+    meanwhile: Callable[[], None] | None = None,
 ) -> bool:
     """THE one warm-up rule of the bulk callers (``score_dataset``,
     `data/stream.py score_csv_stream`): make sure the chunk program is
-    compiled before the timed sweep starts, so that no compile lands in
-    ``elapsed_s`` / ``rows_per_s``. Returns whether it already was.
+    compiled for the job's first chunk size before the timed sweep starts,
+    so that no compile holds up its first run. Returns whether it already
+    was. (A ``score_dataset`` tail of another size, ``tail_chunk_rows``, is
+    not warmed here: the first job that runs it compiles it by its own
+    first dispatch, behind the body's runs, and records it with
+    ``chunk_program_ran``; every later job finds it ready.)
 
     A job's signature is its chunk rows and the avals of ``variables`` and
     ``monitor`` (what `compilecache/warmup.py bulk_chunk_job` lists in
@@ -513,22 +554,45 @@ def warm_chunk_scorer(
     chunk of the sweep will, and the signature is recorded once it has
     come back.
     ``host_model``: the sklearn flavour scores on the host and has nothing
-    to compile but the outlier program, so its warm-up scores one row."""
-    kept = getattr(scorer, "kept", None)
-    signature = (chunk_rows, getattr(scorer, "avals", None))
-    if kept is not None and signature in kept.compiled_for:
+    to compile but the outlier program, so its warm-up scores one row.
+    ``meanwhile``: the caller's own work, run once on this thread after
+    the chunk of zeros is dispatched and before it is waited for (at once
+    where nothing is warmed)."""
+    if chunk_program_ready(scorer, chunk_rows):
+        if meanwhile is not None:
+            meanwhile()
         return True
     cat = np.zeros(
         (chunk_rows, SCHEMA.num_categorical), np.int32 if host_model else np.int8
     )
     num = np.zeros((chunk_rows, SCHEMA.num_numeric), np.float32)
     mask = np.arange(chunk_rows) < (1 if host_model else chunk_rows)
-    jax.block_until_ready(scorer(*transfer(cat, num, mask))[0])
+    zeros = scorer(*transfer(cat, num, mask))[0]
     if getattr(scorer, "tally", None) is not None:
         scorer.tally.reset()  # a chunk of zeros is no job's tokens
-    if kept is not None:
-        kept.compiled_for.add(signature)
+    if meanwhile is not None:
+        meanwhile()
+    jax.block_until_ready(zeros)
+    chunk_program_ran(scorer, chunk_rows)
     return False
+
+
+def chunk_program_ready(scorer, chunk_rows: int) -> bool:
+    """Whether the keep's entry of ``scorer`` has run the signature of
+    ``chunk_rows`` (and the scorer's ``avals``) to completion; ``False`` for
+    a scorer that says nothing about itself."""
+    kept = getattr(scorer, "kept", None)
+    signature = (chunk_rows, getattr(scorer, "avals", None))
+    return kept is not None and signature in kept.compiled_for
+
+
+def chunk_program_ran(scorer, chunk_rows: int) -> None:
+    """Record, once a run at ``chunk_rows`` has come back, that the keep's
+    entry is compiled for that signature (nothing for a scorer the keep
+    does not hold)."""
+    kept = getattr(scorer, "kept", None)
+    if kept is not None:
+        kept.compiled_for.add((chunk_rows, getattr(scorer, "avals", None)))
 
 
 def make_bulk_jit(model, mesh: Mesh | None):
@@ -719,14 +783,19 @@ def score_dataset(
         )
     config = bundle.model_config
     chunk = mesh_chunk_rows(chunk_rows, mesh, config.history_rows)
+    chunks = -(-n // chunk)
+    tail_chunk = tail_chunk_rows(n, chunk, history_unit(mesh, config.history_rows))
     # what the job is, for its span and its record alike
     head = {
         "job": next_job_id(),
         "rows": n,
         "chunk_rows": chunk,
-        "chunks": -(-n // chunk),
+        "chunks": chunks,
         "path": path,
         "histories": -(-n // config.history_rows),
+        "tail_chunk_rows": tail_chunk,
+        # the rows the chunk program computes, padding included
+        "rows_run": (chunks - 1) * chunk + tail_chunk,
     }
     job = head["job"]
     phases: dict[str, float] = {}
@@ -753,11 +822,6 @@ def score_dataset(
         predictions = np.empty(n, np.float32)
         outliers = np.empty(n, np.float32)
 
-        with _phase(phases, "warmup", job):
-            reused = warm_chunk_scorer(
-                scorer, transfer, chunk, host_model=bundle.flavor == "sklearn"
-            )
-
         narrow = (
             np.int8 if bundle.flavor != "sklearn" else ds.cat_ids.dtype
         )  # host trees index with the original ids; device path widens in-jit
@@ -767,14 +831,15 @@ def score_dataset(
         def slice_chunk(span):
             start, stop = span
             size = stop - start
+            run = chunk if stop < n else tail_chunk
             cat = ds.cat_ids[start:stop].astype(narrow)
             num = ds.numeric[start:stop]
-            if size < chunk:
-                cat = np.pad(cat, ((0, chunk - size), (0, 0)))
-                num = np.pad(num, ((0, chunk - size), (0, 0)))
-                mask = base_index < size
+            if size < run:
+                cat = np.pad(cat, ((0, run - size), (0, 0)))
+                num = np.pad(num, ((0, run - size), (0, 0)))
+                mask = base_index[:run] < size
             else:
-                mask = full_mask
+                mask = full_mask[:run]
             return start, stop, cat, num, mask
 
         def transfer_chunk(item):
@@ -804,12 +869,35 @@ def score_dataset(
             predictions[start:stop] = probs[:size]
             outliers[start:stop] = flags[:size]
 
-        spans = (
-            (start, min(start + chunk, n)) for start in range(0, n, chunk)
-        )
+        spans = [(start, min(start + chunk, n)) for start in range(0, n, chunk)]
+        first = chunk if chunks > 1 else tail_chunk
+        tail_ready = tail_chunk == first or chunk_program_ready(scorer, tail_chunk)
+        # A tail size the process has not run gets no chunk of zeros. While
+        # the warm-up's chunk runs, this thread dispatches a wave of the
+        # body's chunks and then the tail's, so that the device runs them
+        # while the tail's program is traced, lowered and loaded (the load
+        # takes several times longer on an executor thread than here: 2.0 s
+        # against 0.55 on a TPU v5e). The executor takes the rest.
+        ahead = [] if tail_ready else [*spans[:-1][:FETCH_WAVE], spans[-1]]
+        dispatched, ahead_s = [], 0.0
+
+        def dispatch_ahead():
+            nonlocal ahead_s
+            since = time.perf_counter()
+            dispatched.extend(
+                compute_chunk(transfer_chunk(slice_chunk(span))) for span in ahead
+            )
+            ahead_s = time.perf_counter() - since
+
+        with _phase(phases, "warmup", job):
+            reused = warm_chunk_scorer(
+                scorer, transfer, first, host_model=bundle.flavor == "sklearn",
+                meanwhile=dispatch_ahead,
+            )
+
         with _phase(phases, "sweep", job):
             pipe = run_pipeline(
-                spans,
+                spans[len(ahead) - 1 : -1] if ahead else spans,
                 [
                     Stage("slice", slice_chunk),
                     Stage("transfer", transfer_chunk),
@@ -836,6 +924,10 @@ def score_dataset(
                 sink_name="store",
                 span_attrs={"job": job},
             )
+            for item in fetch_chunks(dispatched):
+                store_chunk(item)
+        if not tail_ready:  # every chunk is back: the tail's run among them
+            chunk_program_ran(scorer, tail_chunk)
 
         with _phase(phases, "drift", job):
             # Dataset-level drift on a bounded uniform sample (see module
@@ -859,7 +951,7 @@ def score_dataset(
             head, started, phases, pipe,
             compile_events={
                 **CompileCounter.delta(traced_before, counter.snapshot()),
-                "chunk_program_reused": int(reused),
+                "chunk_program_reused": int(reused and tail_ready),
             },
             pauses=PauseCounter.delta(paused_before, pauses.snapshot()),
             routing=routing,
@@ -880,7 +972,7 @@ def score_dataset(
             zip(SCHEMA.feature_names, drift.astype(float).tolist())
         ),
         rows=n,
-        elapsed_s=pipe.wall_s,
+        elapsed_s=ahead_s + phases["sweep"],
         path=path,
         compile_cache=(
             compile_cache.stats() if compile_cache is not None else None
@@ -898,8 +990,10 @@ def job_record(
     strings and lists and dicts of them, never an array nor a reference to
     the scorer, the bundle or the dataset (a job's scorer dies with the
     job: `tests/test_bulk_dp4.py`). ``head``: ``job``, ``rows``,
-    ``chunk_rows``, ``chunks``, ``path``, ``histories``, as on the
-    ``mlops:bulk.job`` span; ``started``: ``time.perf_counter()`` at that
+    ``chunk_rows``, ``chunks``, ``path``, ``histories``,
+    ``tail_chunk_rows`` (the last run's size, ``tail_chunk_rows()``) and
+    ``rows_run`` (the rows the chunk program computed, padding included),
+    as on the ``mlops:bulk.job`` span; ``started``: ``time.perf_counter()`` at that
     span's opening, and ``wall_s`` from there to here; ``phases``;
     ``compile_events``; the executor's ``depth`` and ``stages`` (busy
     seconds, occupancy and queue waits by side, `utils/timing.py

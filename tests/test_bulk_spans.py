@@ -217,8 +217,9 @@ def test_compile_events(traced_jobs, check, which):
 
 # ------------------------------------------------------ the job's record
 RECORD_KEYS = {
-    "job", "rows", "chunk_rows", "chunks", "path", "histories", "started",
-    "wall_s", "phases", "compile_events", "depth", "stages", "pauses",
+    "job", "rows", "chunk_rows", "chunks", "path", "histories",
+    "tail_chunk_rows", "rows_run", "started", "wall_s", "phases",
+    "compile_events", "depth", "stages", "pauses",
 }
 
 
@@ -248,6 +249,8 @@ def result_and_log_hold_the_one_record(traced_jobs, which):
     assert (result.rows, result.path) == (record["rows"], record["path"])
     assert (record["rows"], record["chunk_rows"], record["chunks"],
             record["histories"], record["depth"]) == (ROWS, CHUNK, CHUNKS, ROWS, 2)
+    # a tail of 188 rows rounds back up to the chunk: every run is 256
+    assert (record["tail_chunk_rows"], record["rows_run"]) == (CHUNK, CHUNKS * CHUNK)
     assert result.pipeline["items"] == CHUNKS
     assert set(record["pauses"]) == {"gc_s", "gc_collections", "gc_gen2_s"}
     assert _plain(record)
@@ -272,7 +275,8 @@ def spans_read_the_record(traced_jobs, which):
     record = traced_jobs["jobs"][which][0].record
     span = _named(traced_jobs["spans"], "mlops:bulk.job")[which]
     marker = _named(traced_jobs["spans"], "mlops:bulk.compile_events")[which][3]
-    for key in ("job", "rows", "chunk_rows", "chunks", "path", "histories"):
+    for key in ("job", "rows", "chunk_rows", "chunks", "path", "histories",
+                "tail_chunk_rows", "rows_run"):
         assert span[3][key] == record[key], key
     # the record's clock at the span's opening, and the span's own length
     assert span[3]["started"] == pytest.approx(record["started"], abs=1e-5)
@@ -452,7 +456,9 @@ def test_a_new_signature_compiles_inside_warmup_never_inside_sweep(
     bundle, ds = tiny_bert
     _job(bundle, ds)
     if what == "chunk_rows":
-        job = functools.partial(_job, bundle, ds, chunk_rows=CHUNK // 2)
+        # 384 + a tail of 316, which rounds back up to 384: one shape, so
+        # the job's only signature is the warmed one
+        job = functools.partial(_job, bundle, ds, chunk_rows=3 * CHUNK // 2)
     else:  # a monitor of another reference length: other avals
         shorter = _bundle(ds, reference_rows=ROWS // 2)
         assert (shorter.monitor.num_ref_sorted.shape
