@@ -161,14 +161,13 @@ def ks_two_sample_small_masked(
     """K-S for SMALL batches as dense comparisons — the grouped-serving
     hot path.
 
-    ``ks_two_sample_masked`` sorts the batch and runs ``searchsorted``
-    over the pooled R+B points; vmapped per request-slot that lowers to
-    per-slot sorts/gathers, which are slow on TPU (~4-5 ms per slot
-    measured on v5e — it dominated grouped dispatch). For B << R the
-    supremum over pooled points splits into batch points and reference
-    points, and every ECDF evaluation becomes a ``<=`` outer comparison
-    ([B,R] and [R,B] elementwise reductions, MXU/VPU-friendly), with
-    ECDF_ref at reference points a fit-time constant (``ref_cdf``).
+    ``ks_two_sample_masked`` sorts the pooled R+B points; vmapped per
+    request-slot that is a sort per slot (its earlier ``searchsorted``
+    form took ~4-5 ms per slot on v5e — it dominated grouped dispatch).
+    For B << R the supremum over pooled points splits into batch points
+    and reference points, and every ECDF evaluation becomes a ``<=`` outer
+    comparison ([B,R] and [R,B] elementwise reductions, MXU/VPU-friendly),
+    with ECDF_ref at reference points a fit-time constant (``ref_cdf``).
     Identical statistics to the pooled form, including ties and padding
     (+inf rows contribute 0 everywhere).
     """
@@ -191,24 +190,40 @@ def ks_two_sample_masked(
 
     Padded entries are replaced with +inf so they sort to the tail; the batch
     ECDF denominator is the number of REAL rows, so at every finite pooled
-    point both ECDFs agree with the unpadded computation, and at +inf points
-    both are exactly 1.
+    point both ECDFs agree with the unpadded computation.
+
+    The ECDFs come from ONE sort of the pooled values, each carrying a flag
+    of its origin, and a running count of the flags: at the LAST position
+    of a run of equal values the counts so far are the reference and batch
+    rows ``<=`` that value, exactly what ``searchsorted(side="right")``
+    gives at every pooled point, so the statistic is the same bits. No
+    ``searchsorted``: vmapped over features it lowers to a ``while`` loop
+    of scalar gathers, which a TPU runs slowly (bulk jobs sample 65,536
+    rows).
     """
     r = ref_sorted.shape[0]
     ref_sorted = ref_sorted.astype(jnp.float32)
     bvals = jnp.where(mask, batch.astype(jnp.float32), jnp.inf)
-    batch_sorted = jnp.sort(bvals)
     n_valid = jnp.maximum(mask.sum().astype(jnp.float32), 1.0)
 
-    pooled = jnp.concatenate([ref_sorted, batch_sorted])
-    # f32-pinned count division (x64-context tracing — see ks_two_sample).
-    ref_cdf = (
-        jnp.searchsorted(ref_sorted, pooled, side="right") / r
-    ).astype(jnp.float32)
-    batch_counts = jnp.searchsorted(batch_sorted, pooled, side="right")
+    pooled, from_ref = jax.lax.sort(
+        (
+            jnp.concatenate([ref_sorted, bvals]),
+            jnp.concatenate(
+                [jnp.ones(r, jnp.int32), jnp.zeros(bvals.shape[0], jnp.int32)]
+            ),
+        ),
+        num_keys=1,
+    )
+    ref_counts = jnp.cumsum(from_ref)
+    batch_counts = jnp.arange(1, pooled.shape[0] + 1) - ref_counts
+    # f32-pinned count divisions (x64-context tracing — see ks_two_sample;
+    # there ``arange`` yields int64).
+    ref_cdf = (ref_counts / r).astype(jnp.float32)
     batch_cdf = jnp.minimum(batch_counts.astype(jnp.float32), n_valid) / n_valid
-    finite = jnp.isfinite(pooled)
-    statistic = jnp.where(finite, jnp.abs(ref_cdf - batch_cdf), 0.0).max()
+    last = jnp.concatenate([pooled[1:] != pooled[:-1], jnp.ones(1, bool)])
+    read = last & jnp.isfinite(pooled)
+    statistic = jnp.where(read, jnp.abs(ref_cdf - batch_cdf), 0.0).max()
     # All-padded batch: no data, no signal.
     statistic = jnp.where(mask.any(), statistic, 0.0)
 

@@ -23,7 +23,8 @@ Mechanics (scaling-book recipe):
 - classifier probabilities and outlier flags are exact per row.
 - batch drift is a *dataset-level* statistic: K-S/chi² over millions of rows
   saturates (any tiny shift -> p≈0), so it is computed once over a bounded
-  uniform row sample — same semantics as the serving monitor, bounded cost.
+  uniform row sample — same semantics as the serving monitor, bounded cost —
+  by one compiled program, dispatched once and fetched once.
 
 Where a job's time goes (always on): at its end a job builds ONE plain
 dict, its record (``job_record``), and everything that reports the job
@@ -36,7 +37,9 @@ time went. ``phases`` holds the seconds of the four phases;
 ``compile_events`` what the job traced, lowered, compiled and took from
 JAX's persistent cache (`compilecache/events.py`), with
 ``chunk_program_reused``: 1 where the job found its chunk program compiled
-for every signature it runs and so warmed nothing; ``stages`` the
+for every signature it runs and so warmed nothing, and
+``drift_program_reused``: 1 where its drift sample, ONE program a job
+(``drift_scores``), traced and compiled nothing; ``stages`` the
 executor's busy seconds and queue waits by side (`utils/timing.py
 StageClock`); ``pauses`` the garbage collector's share (`utils/timing.py
 PauseCounter`).
@@ -79,7 +82,8 @@ from mlops_tpu.bundle.bundle import Bundle
 from mlops_tpu.compilecache.events import CompileCounter, compile_counter
 from mlops_tpu.compilecache.keys import abstract_signature
 from mlops_tpu.data.encode import EncodedDataset
-from mlops_tpu.monitor.state import drift_scores, outlier_flags
+from mlops_tpu.monitor import state as monitor_state
+from mlops_tpu.monitor.state import outlier_flags
 from mlops_tpu.parallel.sharding import batch_sharding, replicated
 from mlops_tpu.schema import SCHEMA
 from mlops_tpu.utils.timing import PauseCounter, pause_counter
@@ -98,6 +102,13 @@ FETCH_WAVE = 32
 # the tail's first dispatch, which traces, lowers and loads it while the
 # device runs them); the pipelined sweep; the drift sample.
 PHASES = ("build", "warmup", "sweep", "drift")
+
+# The drift sample's statistics as ONE program, dispatched once a job. The
+# monitor arrays and the sample are its arguments, nothing is closed over,
+# so every bundle of a sample length shares one executable, which the
+# ``jax.jit``'s own cache keeps for the process. It compiles for longer than
+# JAX's persistent cache's minimum, so a later process loads it from there.
+drift_scores = jax.jit(monitor_state.drift_scores)  # tpulint: disable=TPU203
 
 # tpulint Layer-3 manifest: two leaf locks, never held together: one
 # around the keep's table, one around the job log.
@@ -298,7 +309,8 @@ class BulkScoreResult:
         (`compilecache/events.py CompileCounter.delta`), and
         ``chunk_program_reused``: 1 where it found its chunk program
         compiled for every signature it ran, the body's and the tail's
-        (`chunk_program_ready`)."""
+        (`chunk_program_ready`); ``drift_program_reused``: 1 where its
+        drift sample traced and compiled nothing (``drift_scores``)."""
         return self.record and self.record["compile_events"]
 
     @property
@@ -938,6 +950,7 @@ def score_dataset(
                 if take < n
                 else np.arange(n)
             )
+            before = counter.snapshot()
             drift = np.asarray(
                 drift_scores(
                     bundle.monitor,
@@ -946,12 +959,17 @@ def score_dataset(
                     np.ones(take, bool),
                 )
             )
+            drift_compiled = CompileCounter.delta(before, counter.snapshot())
             routing = _routing_summary(scorer, bundle.model, job)
         record = job_record(
             head, started, phases, pipe,
             compile_events={
                 **CompileCounter.delta(traced_before, counter.snapshot()),
                 "chunk_program_reused": int(reused and tail_ready),
+                "drift_program_reused": int(
+                    drift_compiled["programs_traced"] == 0
+                    and drift_compiled["backend_compile_s"] == 0
+                ),
             },
             pauses=PauseCounter.delta(paused_before, pauses.snapshot()),
             routing=routing,

@@ -190,8 +190,10 @@ def job_reuses_its_chunk_program(traced_jobs, which):
     assert events["lower_s"] == 0 and events["backend_compile_s"] == 0
     marker = _named(traced_jobs["spans"], "mlops:bulk.compile_events")[which][3]
     assert marker["chunk_program_reused"] == 1
+    assert events["drift_program_reused"] == marker["drift_program_reused"] == 1
     assert marker["programs_traced"] == events["programs_traced"]
-    assert marker["programs"].split("|") == events["programs"]
+    # a profiler drops an empty attribute: a job that traced nothing has none
+    assert marker.get("programs", "") == "|".join(events["programs"])
     for key in ("trace_s", "lower_s", "backend_compile_s", "cache_retrieval_s"):
         assert marker[key] == pytest.approx(events[key])
     assert (marker["cache_hits"], marker["cache_misses"]) == (
@@ -282,9 +284,10 @@ def spans_read_the_record(traced_jobs, which):
     assert span[3]["started"] == pytest.approx(record["started"], abs=1e-5)
     assert (span[2] - span[1]) / 1e9 == pytest.approx(record["wall_s"], abs=0.005)
     events = record["compile_events"]
-    assert set(marker) == {"job", *events}
+    # a profiler drops an empty attribute: a job that traced nothing has none
+    assert set(marker) | {"programs"} == {"job", *events}
     assert marker["job"] == record["job"]
-    assert marker["programs"] == "|".join(events["programs"])
+    assert marker.get("programs", "") == "|".join(events["programs"])
     for key in set(events) - {"programs"}:
         assert marker[key] == pytest.approx(events[key]), key
     assert "cache_requests" in events
@@ -359,6 +362,46 @@ def test_pauses_count_the_collections_inside_a_job(tiny_bert, monkeypatch):
     pauses = result.record["pauses"]
     assert pauses["gc_collections"] >= 1
     assert 0 < pauses["gc_gen2_s"] <= pauses["gc_s"] <= result.phases["drift"]
+
+
+def test_the_drift_sample_is_one_program_compiled_once(tiny_bert, monkeypatch):
+    """A job's drift sample is one compiled program: the first job of a
+    sample length traces it, the next traces nothing in its drift phase,
+    and both answer what the eager ``drift_scores`` answers on the sample."""
+    from mlops_tpu.monitor.state import drift_scores as eager_drift_scores
+
+    bundle, ds = tiny_bert
+    rows = 613  # a sample length no other job of this module has
+    sample = ds.slice(np.arange(rows))
+    counter = compile_counter()
+    traced = []
+    program = bulk.drift_scores
+
+    def watched_drift(*args):
+        before = counter.snapshot()
+        try:
+            return program(*args)
+        finally:
+            traced.append(counter.delta(before, counter.snapshot()))
+
+    monkeypatch.setattr(bulk, "drift_scores", watched_drift)
+    first, _ = _job(bundle, sample)
+    second, _ = _job(bundle, sample)
+    assert first.compile_events["drift_program_reused"] == 0
+    assert "drift_scores" in traced[0]["programs"]
+    assert "drift_scores" in first.compile_events["programs"]
+    assert second.compile_events["drift_program_reused"] == 1
+    assert traced[1]["programs_traced"] == 0 and traced[1]["lower_s"] == 0
+    assert "drift_scores" not in second.compile_events["programs"]
+    assert _logged(second.record["job"])["compile_events"]["drift_program_reused"] == 1
+    eager = np.asarray(eager_drift_scores(
+        bundle.monitor, sample.cat_ids, sample.numeric, np.ones(rows, bool)))
+    for result in (first, second):
+        served = np.asarray(list(result.feature_drift.values()), np.float32)
+        # the statistics are exact; the p-value series may round apart by a
+        # few float32 steps where the compiler fuses it
+        np.testing.assert_allclose(
+            served, eager, rtol=0, atol=8 * np.finfo(np.float32).eps)
 
 
 # ------------------------------------------- the chunk program is kept

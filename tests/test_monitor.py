@@ -1,5 +1,6 @@
 """Monitor tests: scipy parity for the statistics, drift/outlier semantics."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -128,6 +129,104 @@ def test_ks_small_masked_matches_pooled():
         s2, p2 = ks_two_sample_small_masked(ref, ref_cdf, batch, mask)
         np.testing.assert_allclose(float(s1), float(s2), atol=1e-6)
         np.testing.assert_allclose(float(p1), float(p2), atol=1e-6)
+
+
+def _ks_searchsorted(ref_sorted, batch, mask):
+    """The masked K-S statistic by its definition: both right-continuous
+    ECDFs read by ``searchsorted`` at every pooled point, padded rows +inf
+    and left out of the batch's denominator."""
+    r = ref_sorted.shape[0]
+    ref_sorted = ref_sorted.astype(jnp.float32)
+    bvals = jnp.where(mask, batch.astype(jnp.float32), jnp.inf)
+    batch_sorted = jnp.sort(bvals)
+    n_valid = jnp.maximum(mask.sum().astype(jnp.float32), 1.0)
+    pooled = jnp.concatenate([ref_sorted, batch_sorted])
+    ref_cdf = (
+        jnp.searchsorted(ref_sorted, pooled, side="right") / r
+    ).astype(jnp.float32)
+    batch_counts = jnp.searchsorted(batch_sorted, pooled, side="right")
+    batch_cdf = jnp.minimum(batch_counts.astype(jnp.float32), n_valid) / n_valid
+    statistic = jnp.where(
+        jnp.isfinite(pooled), jnp.abs(ref_cdf - batch_cdf), 0.0
+    ).max()
+    return jnp.where(mask.any(), statistic, 0.0)
+
+
+# (reference rows, batch rows, real batch rows, decimals the values keep):
+# one decimal or none makes runs of equal values, within and across the
+# two samples. Where both denominators are powers of two every ECDF value
+# and difference is exact in float32, so scipy's float64 statistic is the
+# same number.
+KS_CASES = {
+    "ties_batch_above_ref": (256, 1024, 1024, 0),
+    "ties_batch_below_ref": (512, 128, 128, 1),
+    "padded": (256, 1000, 512, 1),
+    "all_padded": (256, 300, 0, 1),
+    "uneven_denominators": (300, 700, 555, 1),
+}
+
+
+def _ks_case(r, b, n_valid, decimals):
+    rng = np.random.default_rng([r, b, n_valid])
+    ref = np.sort(np.round(rng.normal(size=r), decimals).astype(np.float32))
+    batch = np.round(rng.normal(0.2, 1.1, size=b), decimals).astype(np.float32)
+    batch[: b // 4] = rng.choice(ref, b // 4)  # values both samples hold
+    return ref, batch, np.arange(b) < n_valid
+
+
+@pytest.mark.parametrize("case", list(KS_CASES))
+def test_ks_masked_is_the_searchsorted_statistic(case):
+    """The sort-and-count K-S of large batches gives the same bits as the
+    ``searchsorted`` definition, eagerly and compiled, and scipy's number
+    where the denominators make it exact in float32."""
+    from mlops_tpu.ops.drift import ks_two_sample_masked
+
+    r, b, n_valid, decimals = KS_CASES[case]
+    ref, batch, mask = _ks_case(r, b, n_valid, decimals)
+    for run in (lambda f: f, jax.jit):
+        stat, p = run(ks_two_sample_masked)(ref, batch, mask)
+        assert stat.dtype == p.dtype == jnp.float32
+        want = run(_ks_searchsorted)(ref, batch, mask)
+        assert np.asarray(stat).tobytes() == np.asarray(want).tobytes()
+    if n_valid == 0:
+        assert float(stat) == 0.0
+    elif r & (r - 1) == 0 and n_valid & (n_valid - 1) == 0:
+        scipy_stats = pytest.importorskip("scipy.stats")
+        res = scipy_stats.ks_2samp(ref, batch[mask], method="asymp")
+        assert float(stat) == res.statistic
+
+
+def test_ks_masked_stays_float32_under_x64():
+    """The gbm-tensor tier traces the monitors in an x64 context, where
+    ``arange`` gives int64: the statistic and p-value stay float32 and the
+    same bits as the definition there."""
+    from mlops_tpu.ops.drift import ks_two_sample_masked
+
+    ref, batch, mask = _ks_case(*KS_CASES["padded"])
+    with jax.enable_x64(True):
+        stat, p = jax.jit(ks_two_sample_masked)(ref, batch, mask)
+        want = jax.jit(_ks_searchsorted)(ref, batch, mask)
+    assert stat.dtype == p.dtype == jnp.float32
+    assert np.asarray(stat).tobytes() == np.asarray(want).tobytes()
+
+
+def test_ks_masked_holds_no_loop_and_no_gather():
+    """Vmapped over features, as ``drift_scores`` runs it, the K-S is a
+    sort and a running count: no ``while`` and no ``gather`` (what a
+    vmapped ``searchsorted`` lowers to)."""
+    from mlops_tpu.ops.drift import ks_two_sample_masked
+
+    program = jax.jit(jax.vmap(ks_two_sample_masked, in_axes=(0, 0, None)))
+    shapes = (
+        jax.ShapeDtypeStruct((14, 2048), jnp.float32),
+        jax.ShapeDtypeStruct((14, 65536), jnp.float32),
+        jax.ShapeDtypeStruct((65536,), jnp.bool_),
+    )
+    jaxpr = str(jax.make_jaxpr(program)(*shapes))
+    lowered = program.lower(*shapes).as_text()
+    for text in (jaxpr, lowered):
+        assert "while" not in text and "gather" not in text
+    assert "sort" in lowered
 
 
 def test_monitor_state_backcompat_without_ref_cdf(encoded_small):
