@@ -10,8 +10,8 @@ predicate admits the shape (`ops/mla.py mla_attend_fwd`;
 `ops/gqa_attention.py gqa_attend_fwd`, full and windowed, at the published
 shapes of both grouped-query families). This form is what runs on every
 other platform, at every shape the predicates refuse, with ``read`` (a
-model's last layer) and in the backward everywhere: the kernels'
-``custom_vjp``s differentiate it, recomputed.
+model's last layer) and in the backward everywhere: the kernels' scaffold
+(`ops/kernel_gate.py tpu_kernel_forward`) differentiates it, recomputed.
 
 Query heads may outnumber key/value heads (grouped-query attention):
 head ``i`` of ``H`` reads key/value head ``i // (H // G)`` of ``G``. The

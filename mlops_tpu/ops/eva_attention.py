@@ -40,8 +40,10 @@ scores of ONE sequence are 32 x 8 windows x 2048 x up to 2944 float32 =
   once, as the second product's operand. Only ``o`` is written.
 - ``eva_attend_xla``, plain XLA, one head at a time with that head's
   scores in HBM: every other platform, every other shape, and the backward
-  everywhere (``eva_attend`` is a ``custom_vjp`` where the kernel is the
-  forward; the XLA form is recomputed and differentiated).
+  everywhere (`kernel_gate.tpu_kernel_forward`: the kernel is the forward,
+  the XLA form is recomputed and differentiated).
+
+The kernel's two visits are `ops/lane_softmax.py joint_softmax_state`.
 
 The scopes ``rope``, ``eva_prep_kv`` and ``eva_attend`` are what a device
 trace carries (`benchmark/layer_metrics/`); the kernel's operation is
@@ -59,7 +61,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from mlops_tpu.ops.attention import NEG_INF
-from mlops_tpu.ops.kernel_gate import tpu_kernel_or
+from mlops_tpu.ops.kernel_gate import tpu_kernel_forward
+from mlops_tpu.ops.lane_softmax import LANES, joint_softmax_state
 
 
 def rope_inv_freq(head_dim: int, theta: float) -> np.ndarray:
@@ -250,26 +253,17 @@ def _eva_kernel(
 
     The softmax state between the two visits is float32 in VMEM scratch:
     the row maximum ``m``, the unnormalised accumulator ``acc``, and the
-    normaliser ``l`` as 128 partial sums a row (lane ``c`` sums the keys
-    ``c mod 128``). Cross-lane reductions are what this shape of kernel
-    pays for: a running maximum and sum reduced at every 512-key tile were
-    three fifths of the first version's time (PERF.md section 6, PR 30).
-    Here the maximum takes one a visit, after an elementwise maximum over
-    the visit's lane tiles, and the sum one in all, at the end."""
+    normaliser ``l`` as 128 partial sums a row. A visit is
+    `ops/lane_softmax.py joint_softmax_state` (one cross-lane reduction
+    for its maximum); the sum takes one in all, at the end."""
     w, qi = pl.program_id(2), pl.program_id(3)
     q = q_ref[0]
 
-    def over_lane_tiles(x, op):  # [block, n * 128] -> [block, 128]
-        out = x[:, :128]
-        for c in range(128, x.shape[1], 128):
-            out = op(out, x[:, c : c + 128])
-        return out
-
-    def visit(parts, first):
-        """``parts``: (keys ref, values ref, first row, rows, causal), the
-        key sets of one joint softmax."""
+    def visit(keys_ref, values_ref, parts, first):
+        """``parts``: (first row, rows, causal), the key sets of one joint
+        softmax, each read from ``keys_ref`` and ``values_ref``."""
         scores = []
-        for keys_ref, _, start, size, causal in parts:
+        for start, size, causal in parts:
             s = jax.lax.dot_general(
                 q, keys_ref[0, start : start + size, :], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -278,26 +272,12 @@ def _eva_kernel(
                 row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
                 col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
                 s = jnp.where(col <= row, s, NEG_INF)
-            scores.append(s)
-        tile_max = functools.reduce(
-            jnp.maximum, (over_lane_tiles(s, jnp.maximum) for s in scores)
+            scores.append((start, s))
+        m_prev, m_new, l_new, acc_new = joint_softmax_state(
+            scores,
+            lambda start, size: values_ref[0, start : start + size, :],
+            carried=None if first else lambda: m_ref[:, :1],
         )
-        m_new = jnp.max(tile_max, axis=-1, keepdims=True)
-        if not first:
-            m_prev = m_ref[:, :1]
-            m_new = jnp.maximum(m_prev, m_new)
-        sums, mixed = [], []
-        for s, (_, values_ref, start, size, _) in zip(scores, parts):
-            p = jnp.exp(s - m_new)
-            sums.append(over_lane_tiles(p, jnp.add))
-            values = values_ref[0, start : start + size, :]
-            mixed.append(
-                jax.lax.dot_general(
-                    p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            )
-        l_new, acc_new = functools.reduce(jnp.add, sums), functools.reduce(jnp.add, mixed)
         if first:
             l_ref[:], acc_ref[:] = l_new, acc_new
         else:
@@ -313,15 +293,15 @@ def _eva_kernel(
 
         @pl.when(qi == before)
         def _local(before=before):
-            own = (k_ref, v_ref, before * block, block, True)
-            earlier = [(k_ref, v_ref, 0, before * block, False)] if before else []
-            visit([*earlier, own], first=True)
+            own = (before * block, block, True)
+            earlier = [(0, before * block, False)] if before else []
+            visit(k_ref, v_ref, [*earlier, own], first=True)
 
     for past in range(1, windows):  # windows before the step's
 
         @pl.when(w == past)
         def _remote(past=past):
-            visit([(ks_ref, vs_ref, 0, past * per_window, False)], first=False)
+            visit(ks_ref, vs_ref, [(0, past * per_window, False)], first=False)
 
     normaliser = jnp.sum(l_ref[:], axis=-1, keepdims=True)
     o_ref[0] = (acc_ref[:] / normaliser).astype(o_ref.dtype)
@@ -385,8 +365,8 @@ def eva_attend_blockwise(
         ),
         out_shape=jax.ShapeDtypeStruct((b, windows * window, heads * head_dim), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block, 128), jnp.float32),  # row maximum m
-            pltpu.VMEM((block, 128), jnp.float32),  # normaliser l, 128 partial sums a row
+            pltpu.VMEM((block, LANES), jnp.float32),  # row maximum m
+            pltpu.VMEM((block, LANES), jnp.float32),  # normaliser l, 128 partial sums a row
             pltpu.VMEM((block, head_dim), jnp.float32),  # unnormalised accumulator
         ],
         compiler_params=pltpu.CompilerParams(
@@ -400,38 +380,9 @@ def eva_attend_blockwise(
     return out.reshape(b, windows * window, heads, head_dim)[:, :seq]
 
 
-@functools.partial(jax.jit, static_argnames=("window", "chunk"))
-def _kernel_or_xla(q, k, v, k_sum, v_sum, window, chunk):
-    """Jitted so that a model traces and lowers the kernel ONCE for all
-    its layers (as `ops/attention.py _one_head` is for its heads): the
-    kernel's body is some 600 operations of Python tracing, and unjitted
-    the eight layers of `evabyte-8l` added 18 s to a process's set-up on a
-    v5e machine's host (PERF.md section 6, PR 30). XLA inlines the calls,
-    each under its own layer's scope."""
-    return tpu_kernel_or(
-        functools.partial(eva_attend_blockwise, window=window, chunk=chunk),
-        functools.partial(eva_attend_xla, window=window, chunk=chunk),
-        q, k, v, k_sum, v_sum,
-    )
-
-
-_eva_attend = jax.custom_vjp(_kernel_or_xla, nondiff_argnums=(5, 6))
-
-
-def _eva_attend_fwd(q, k, v, k_sum, v_sum, window, chunk):
-    operands = (q, k, v, k_sum, v_sum)
-    return _kernel_or_xla(*operands, window, chunk), operands
-
-
-def _eva_attend_bwd(window, chunk, operands, g):
-    """No backward kernel: the XLA form, recomputed, is differentiated."""
-    _, pull = jax.vjp(
-        functools.partial(eva_attend_xla, window=window, chunk=chunk), *operands
-    )
-    return pull(g)
-
-
-_eva_attend.defvjp(_eva_attend_fwd, _eva_attend_bwd)
+_eva_attend = tpu_kernel_forward(
+    eva_attend_blockwise, eva_attend_xla, static_argnames=("window", "chunk")
+)
 
 
 @jax.named_scope("eva_attend")
@@ -456,4 +407,4 @@ def eva_attend(
         raise ValueError(f"window {window} is not whole chunks of {chunk}")
     if not wants_eva_kernel(seq, head_dim, window, chunk):
         return eva_attend_xla(q, k, v, k_sum, v_sum, window, chunk)
-    return _eva_attend(q, k, v, k_sum, v_sum, window, chunk)
+    return _eva_attend(q, k, v, k_sum, v_sum, window=window, chunk=chunk)

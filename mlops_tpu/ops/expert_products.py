@@ -49,7 +49,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-VMEM_LIMIT_BYTES = 100 * 2**20  # of a v5e's 128 MiB; the default scoped limit is 16
+# of a v5e's 128 MiB (the default scoped limit is 16); not the attention kernels' 96
+# (`ops/lane_softmax.py`): sized for this module's blocks, BLOCKS_BUDGET_BYTES
+VMEM_LIMIT_BYTES = 100 * 2**20
 BLOCKS_BUDGET_BYTES = 64 * 2**20  # a step's blocks, each held twice by the pipeline
 
 

@@ -38,9 +38,11 @@ Which form of ``gqa_attend`` runs where:
   longer history, heads of 64 past 3,072 keys; a window no shorter than
   the history is the full form),
   ``read`` (the last layer: a few queries a history against whole keys, one
-  small memory-bound block) and the backward everywhere (``gqa_attend`` is
-  a ``custom_vjp`` where the kernel is the forward; the XLA form is
-  recomputed and differentiated).
+  small memory-bound block) and the backward everywhere
+  (`kernel_gate.tpu_kernel_forward`: the kernel is the forward, the XLA
+  form is recomputed and differentiated).
+
+The kernel's visit is `ops/lane_softmax.py joint_softmax`.
 
 The caller's scope (``gqa_attend``, ``swa_attend``) is what a device trace
 carries; the kernel's operation is ``.../<scope>/.../gqa_attend_fwd``.
@@ -58,9 +60,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from mlops_tpu.ops.attention import NEG_INF
 from mlops_tpu.ops.causal_attention import QUERY_BLOCK, causal_attend
-from mlops_tpu.ops.kernel_gate import tpu_kernel_or
+from mlops_tpu.ops.kernel_gate import tpu_kernel_forward
+from mlops_tpu.ops.lane_softmax import LANES, VMEM_LIMIT_BYTES, joint_softmax
 
-LANES = 128
 STEP_ROWS = 512  # queries a step of a full layer, a lane tile of heads: 512 rows
 WINDOW_BLOCK = 128  # queries a step under a window: a tile of keys is a few blocks
 MAX_KEYS = 16384  # a key/value tile's keys and values are held whole: 4 MB each
@@ -74,7 +76,6 @@ MAX_STEP_SCORES = STEP_ROWS * 4096
 # the WHOLE kernel, whichever place the step runs (the 40 us a step of PR
 # 33: four and eight heads stacked, 100 k and 200 k bundles)
 MAX_CODE_SCORES = 11_000_000
-VMEM_LIMIT_BYTES = 96 * 2**20  # of a v5e's 128 MiB; the default scoped limit is 16
 
 
 def _blocks_back(window: int) -> int:
@@ -151,11 +152,7 @@ def _gqa_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, block, share, fold, ratio,
     key/value head has there, beside zeros, so the product over 128 lanes
     is the head's own (finite keys taken for granted: a zero times the
     neighbour's infinity is not one); its values come out in the same half.
-
-    Cross-lane reductions are what this shape of kernel pays for
-    (`ops/mla.py _mla_kernel`): the maximum takes one, after an elementwise
-    maximum over the visit's lane tiles, and the sum one, over 128 partial
-    sums a row."""
+    The visit's softmax is `ops/lane_softmax.py joint_softmax`."""
     blocks = k_ref.shape[1] // block
     qi = pl.program_id(2) % blocks
     heads = share * fold  # query heads a step
@@ -175,12 +172,6 @@ def _gqa_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, block, share, fold, ratio,
         return tile if fold == 1 else moved(tile.astype(jnp.float32), h).astype(tile.dtype)
 
     q = jnp.concatenate([queries(h) for h in range(heads)], axis=0)
-
-    def over_lane_tiles(x, op):  # [rows, n * 128] -> [rows, 128]
-        out = x[:, :LANES]
-        for c in range(LANES, x.shape[1], LANES):
-            out = op(out, x[:, c : c + LANES])
-        return out
 
     def scores(start, size):
         return jax.lax.dot_general(
@@ -211,23 +202,7 @@ def _gqa_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, block, share, fold, ratio,
             parts = [(ahead, seen(scores(ahead, block), 0))]
             if before:
                 parts.insert(0, (0, scores(0, ahead)))
-        tile_max = functools.reduce(
-            jnp.maximum, (over_lane_tiles(s, jnp.maximum) for _, s in parts)
-        )
-        top = jnp.max(tile_max, axis=-1, keepdims=True)
-        sums, mixed = [], []
-        for first, s in parts:
-            p = jnp.exp(s - top)
-            sums.append(over_lane_tiles(p, jnp.add))
-            values = v_ref[0, pl.ds(first, s.shape[1]), :]
-            mixed.append(
-                jax.lax.dot_general(
-                    p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            )
-        total = jnp.sum(functools.reduce(jnp.add, sums), axis=-1, keepdims=True)
-        out = functools.reduce(jnp.add, mixed) / total
+        out = joint_softmax(parts, lambda at, size: v_ref[0, pl.ds(at, size), :])
         for c in range(share):
             if fold == 1:
                 tile = out[c * block : (c + 1) * block]
@@ -311,40 +286,11 @@ def gqa_attend_blockwise(
     return out.reshape(b, seq, heads, width)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "window", "query_block"))
-def _kernel_or_xla(q, k, v, scale, window, query_block):
-    """Jitted so that a model traces and lowers the kernel ONCE for all its
-    layers of one kind (`ops/eva_attention.py _kernel_or_xla`: unjitted, a
-    kernel's body is traced layer by layer, and a process's set-up pays).
-    XLA inlines the calls, each under its own layer's scope."""
-    return tpu_kernel_or(
-        functools.partial(gqa_attend_blockwise, scale=scale, window=window),
-        functools.partial(
-            causal_attend, scale=scale, window=window, query_block=query_block
-        ),
-        q, k, v,
-    )
-
-
-_gqa_attend = jax.custom_vjp(_kernel_or_xla, nondiff_argnums=(3, 4, 5))
-
-
-def _gqa_attend_fwd(q, k, v, scale, window, query_block):
-    return _kernel_or_xla(q, k, v, scale, window, query_block), (q, k, v)
-
-
-def _gqa_attend_bwd(scale, window, query_block, operands, g):
-    """No backward kernel: the XLA form, recomputed, is differentiated."""
-    _, pull = jax.vjp(
-        functools.partial(
-            causal_attend, scale=scale, window=window, query_block=query_block
-        ),
-        *operands,
-    )
-    return pull(g)
-
-
-_gqa_attend.defvjp(_gqa_attend_fwd, _gqa_attend_bwd)
+_gqa_attend = tpu_kernel_forward(
+    lambda q, k, v, scale, window, query_block: gqa_attend_blockwise(q, k, v, scale, window),
+    causal_attend,
+    static_argnames=("scale", "window", "query_block"),
+)
 
 
 def gqa_attend(
@@ -366,4 +312,4 @@ def gqa_attend(
         return causal_attend(
             q, k, v, scale, read=read, query_block=query_block, window=window
         )
-    return _gqa_attend(q, k, v, scale, window, query_block)
+    return _gqa_attend(q, k, v, scale=scale, window=window, query_block=query_block)

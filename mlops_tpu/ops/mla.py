@@ -56,9 +56,11 @@ float32:
   diagonal are never computed, and one block's scores, heads x block x
   keys so far, are live in HBM). Every other platform, every other shape,
   ``read`` (the last layer: 64 queries a history against whole keys, a
-  small memory-bound block), and the backward everywhere (``mla_attend``
-  is a ``custom_vjp`` where the kernel is the forward; the XLA form is
-  recomputed and differentiated).
+  small memory-bound block), and the backward everywhere
+  (`kernel_gate.tpu_kernel_forward`: the kernel is the forward, the XLA
+  form is recomputed and differentiated).
+
+The kernel's visit is `ops/lane_softmax.py joint_softmax`.
 
 The scope ``mla_attend`` is what a device trace carries
 (`benchmark/layer_metrics/mla_attend_roofline_pct.py`); the kernel's
@@ -78,7 +80,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from mlops_tpu.ops.attention import NEG_INF
 from mlops_tpu.ops.causal_attention import causal_attend
-from mlops_tpu.ops.kernel_gate import tpu_kernel_or
+from mlops_tpu.ops.kernel_gate import tpu_kernel_forward
+from mlops_tpu.ops.lane_softmax import VMEM_LIMIT_BYTES, joint_softmax
 
 
 def _yarn_correction_dim(rotations: float, dim: int, theta: float, positions: int) -> float:
@@ -119,7 +122,6 @@ def softmax_scale(qk_head_dim: int, factor: float, mscale_all_dim: float = 1.0) 
 
 KERNEL_BLOCK = 512  # queries a grid step
 MAX_VISIT_KEYS = 4096  # a step's scores are KERNEL_BLOCK x keys float32 in VMEM: 8 MB
-VMEM_LIMIT_BYTES = 96 * 2**20  # of a v5e's 128 MiB; the default scoped limit is 16
 
 
 def wants_mla_kernel(
@@ -154,12 +156,8 @@ def _mla_kernel(qn_ref, qr_ref, kn_ref, v_ref, kr_ref, o_ref, keys_ref, *, scale
     slower, PERF.md section 6, PR 32). Then ONE visit of a static width
     chosen by the step's place: every key before the block's own unmasked
     and the block's own under the causal mask, in one softmax. Keys after
-    the block are never read.
-
-    Cross-lane reductions are what this shape of kernel pays for
-    (`ops/eva_attention.py _eva_kernel`): the maximum takes one, after an
-    elementwise maximum over the visit's lane tiles, and the sum one, over
-    128 partial sums a row."""
+    the block are never read. The visit's softmax is
+    `ops/lane_softmax.py joint_softmax`."""
     qi = pl.program_id(2)
 
     @pl.when(qi == 0)
@@ -168,12 +166,6 @@ def _mla_kernel(qn_ref, qr_ref, kn_ref, v_ref, kr_ref, o_ref, keys_ref, *, scale
         keys_ref[:, 128:] = kr_ref[0]
 
     q = jnp.concatenate([qn_ref[0], qr_ref[0]], axis=-1)  # [block, 256]
-
-    def over_lane_tiles(x, op):  # [block, n * 128] -> [block, 128]
-        out = x[:, :128]
-        for c in range(128, x.shape[1], 128):
-            out = op(out, x[:, c : c + 128])
-        return out
 
     def scores(start, size):
         return jax.lax.dot_general(
@@ -188,23 +180,8 @@ def _mla_kernel(qn_ref, qr_ref, kn_ref, v_ref, kr_ref, o_ref, keys_ref, *, scale
         parts = [(before * block, jnp.where(col <= row, own, NEG_INF))]
         if before:
             parts.insert(0, (0, scores(0, before * block)))
-        tile_max = functools.reduce(
-            jnp.maximum, (over_lane_tiles(s, jnp.maximum) for _, s in parts)
-        )
-        top = jnp.max(tile_max, axis=-1, keepdims=True)
-        sums, mixed = [], []
-        for start, s in parts:
-            p = jnp.exp(s - top)
-            sums.append(over_lane_tiles(p, jnp.add))
-            values = v_ref[0, start : start + s.shape[1], :]
-            mixed.append(
-                jax.lax.dot_general(
-                    p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            )
-        total = jnp.sum(functools.reduce(jnp.add, sums), axis=-1, keepdims=True)
-        o_ref[0] = (functools.reduce(jnp.add, mixed) / total).astype(o_ref.dtype)
+        mixed = joint_softmax(parts, lambda start, size: v_ref[0, start : start + size, :])
+        o_ref[0] = mixed.astype(o_ref.dtype)
 
     # one branch a place, each of static widths: a flat chain of `pl.when`s
     # (a `lax.switch` nests its branches and overflowed Mosaic's layout
@@ -296,34 +273,9 @@ def mla_attend_xla(
     return mixed.reshape(b, seq, -1)
 
 
-@functools.partial(jax.jit, static_argnames=("scale",))
-def _kernel_or_xla(q_nope, q_rot, kv, k_rot, scale):
-    """Jitted so that a model traces and lowers the kernel ONCE for all its
-    layers (`ops/eva_attention.py _kernel_or_xla`: unjitted, a kernel's
-    body is traced layer by layer, and a process's set-up pays). XLA
-    inlines the calls, each under its own layer's scope."""
-    return tpu_kernel_or(
-        functools.partial(mla_attend_blockwise, scale=scale),
-        functools.partial(mla_attend_xla, scale=scale),
-        q_nope, q_rot, kv, k_rot,
-    )
-
-
-_mla_attend = jax.custom_vjp(_kernel_or_xla, nondiff_argnums=(4,))
-
-
-def _mla_attend_fwd(q_nope, q_rot, kv, k_rot, scale):
-    operands = (q_nope, q_rot, kv, k_rot)
-    return _kernel_or_xla(*operands, scale), operands
-
-
-def _mla_attend_bwd(scale, operands, g):
-    """No backward kernel: the XLA form, recomputed, is differentiated."""
-    _, pull = jax.vjp(functools.partial(mla_attend_xla, scale=scale), *operands)
-    return pull(g)
-
-
-_mla_attend.defvjp(_mla_attend_fwd, _mla_attend_bwd)
+_mla_attend = tpu_kernel_forward(
+    mla_attend_blockwise, mla_attend_xla, static_argnames=("scale",)
+)
 
 
 @jax.named_scope("mla_attend")
@@ -352,4 +304,4 @@ def mla_attend(
     rot, wide = q_rot.shape[-1], kv.shape[-1] // heads - nope
     if read is not None or not wants_mla_kernel(seq, nope, rot, wide):
         return mla_attend_xla(q_nope, q_rot, kv, k_rot, scale, read=read)
-    return _mla_attend(q_nope, q_rot, kv, k_rot, scale)
+    return _mla_attend(q_nope, q_rot, kv, k_rot, scale=scale)

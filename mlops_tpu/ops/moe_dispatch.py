@@ -42,8 +42,8 @@ Which form of a segment's products runs where:
 - ``segment_products_xla``, three `jax.lax.ragged_dot` calls and the
   activation between them, the definition: every other platform, every
   other shape (each tiny configuration of the tests), and the backward
-  everywhere (`segment_products` is a ``custom_vjp`` where the kernels are
-  the forward; the XLA form is recomputed and differentiated).
+  everywhere (`kernel_gate.tpu_kernel_forward`: the kernels are the
+  forward, the XLA form is recomputed and differentiated).
 
 Which form of the combine runs where:
 
@@ -78,10 +78,9 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from mlops_tpu.ops.expert_products import grouped_swiglu_kernels, wants_grouped_kernel
-from mlops_tpu.ops.kernel_gate import tpu_kernel_or
+from mlops_tpu.ops.kernel_gate import tpu_kernel_forward
 
 
 class Routing(NamedTuple):
@@ -168,30 +167,7 @@ def segment_products_xla(
     )
 
 
-@jax.jit
-def _kernel_or_xla(taken, gate, up, down, sizes):
-    """Jitted so that a model traces and lowers the kernels ONCE for all
-    its layers (`ops/mla.py _kernel_or_xla`: unjitted, a kernel's body is
-    traced layer by layer, and a process's set-up pays)."""
-    return tpu_kernel_or(grouped_swiglu_kernels, segment_products_xla, taken, gate, up, down, sizes)
-
-
-_segment_products = jax.custom_vjp(_kernel_or_xla)
-
-
-def _segment_products_fwd(*operands):
-    return _kernel_or_xla(*operands), operands
-
-
-def _segment_products_bwd(operands, g):
-    """No backward kernel: the XLA form, recomputed, is differentiated
-    (the sizes are whole numbers and get no cotangent)."""
-    *arrays, sizes = operands
-    _, pull = jax.vjp(lambda *xs: segment_products_xla(*xs, sizes), *arrays)
-    return (*pull(g), np.zeros(sizes.shape, jax.dtypes.float0))
-
-
-_segment_products.defvjp(_segment_products_fwd, _segment_products_bwd)
+_segment_products = tpu_kernel_forward(grouped_swiglu_kernels, segment_products_xla)
 
 
 def segment_products(
