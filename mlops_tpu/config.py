@@ -40,7 +40,9 @@ class DataConfig:
 
 # Causal families that take the zoo's 2-D rows, read every `doc_records`
 # consecutive rows as one history and answer every record.
-HISTORY_FAMILIES = ("evabyte", "kimi_k2", "lfm2_moe", "exaone_moe", "falcon_h1")
+HISTORY_FAMILIES = (
+    "evabyte", "kimi_k2", "lfm2_moe", "exaone_moe", "falcon_h1", "nemotron_h",
+)
 # LFM2-8B-A1B's published `layer_types`: which token mixer each of its 24
 # layers runs (family lfm2_moe's default)
 LFM2_LAYER_TYPES = (
@@ -54,14 +56,15 @@ LFM2_LAYER_TYPES = (
 @dataclasses.dataclass
 class ModelConfig:
     family: str = "mlp"  # mlp | ft_transformer | moe | linear | bert |
-    # evabyte | kimi_k2 | lfm2_moe | exaone_moe | falcon_h1 | gbm | rf
+    # evabyte | kimi_k2 | lfm2_moe | exaone_moe | falcon_h1 | nemotron_h |
+    # gbm | rf
     hidden_dims: tuple[int, ...] = (256, 256, 128)
     embed_dim: int = 16
     dropout: float = 0.1
     precision: str = "bf16"  # compute dtype on MXU: bf16 | f32 (params stay f32)
     param_dtype: str = "f32"  # the dtype parameters are STORED in, on disk
     # and on the device: f32 | bf16. The token-level decoders (kimi_k2,
-    # lfm2_moe, exaone_moe, falcon_h1) alone take bf16 (what a chip holds of
+    # lfm2_moe, exaone_moe, falcon_h1, nemotron_h) alone take bf16 (what a chip holds of
     # them does not fit it at four bytes a parameter); nothing casts the
     # tree in the program, a product reads its leaf as stored
     ensemble_size: int = 1  # >1 wraps the Flax family in a vmapped deep
@@ -84,7 +87,7 @@ class ModelConfig:
     # `train/long_context.py`); family evabyte is causal, takes the zoo's
     # 2-D rows and answers EVERY record, conditioned on the records before
     # it in its history, as do the token-level decoders kimi_k2, lfm2_moe,
-    # exaone_moe and falcon_h1. `seq_parallel` routes bert's attention through
+    # exaone_moe, falcon_h1 and nemotron_h. `seq_parallel` routes bert's attention through
     # the ppermute ring (`parallel.make_ring_attention`) over the mesh's
     # 'seq' axis.
     doc_records: int = 1
@@ -182,6 +185,15 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
     mlp_multipliers: tuple[float, ...] = (1.0, 1.0)
+    # Family nemotron_h (models/nemotron_h.py; every layer ONE of the
+    # falcon_h1 Mamba-2 mixer, routed ReLU² experts beside a shared one, or
+    # unturned grouped-query attention). `layer_types` names each layer
+    # "mamba" | "moe" | "attention" (the published hybrid_override_pattern's
+    # M, E and *, read off it) and has to be given; the mixer, attention
+    # and expert fields above serve as named there. The one field of its
+    # own: the shared expert's width, which its source states apart from
+    # the routed experts' (0 = `moe_ffn_dim`).
+    shared_ffn_dim: int = 0
 
     @property
     def reads_documents(self) -> bool:

@@ -38,6 +38,13 @@ the TPU-native zoo is:
   path and projection under a muP multiplier of the configuration; a
   dense SwiGLU in every layer, no experts; histories, tokens and bfloat16
   parameters as ``kimi_k2``
+- ``nemotron_h``      token-level causal hybrid decoder whose every layer
+  is ONE kind by a published pattern: the Mamba-2 mixer of ``falcon_h1``
+  (no multipliers), sparse routed experts of ``down(relu(up h)^2)``
+  beside a shared one of its own width (the expert layer of ``kimi_k2``,
+  told which experts it holds), or grouped-query attention (unnormed,
+  unturned heads, a group of 16); histories, tokens and bfloat16
+  parameters as ``kimi_k2``
 
 All families share one calling convention:
 ``model.apply(vars, cat_ids[int32 N,C], numeric[f32 N,M], train=...) ->
@@ -63,14 +70,15 @@ from mlops_tpu.models.kimi_k2 import KimiK2Scorer
 from mlops_tpu.models.lfm2_moe import Lfm2MoeScorer
 from mlops_tpu.models.mlp import MLP, LinearModel
 from mlops_tpu.models.moe import MoETransformer
+from mlops_tpu.models.nemotron_h import NemotronHScorer
 from mlops_tpu.schema.features import SCHEMA
 
 FAMILIES = (
     "linear", "mlp", "ft_transformer", "moe", "bert", "evabyte", "kimi_k2", "lfm2_moe",
-    "exaone_moe", "falcon_h1",
+    "exaone_moe", "falcon_h1", "nemotron_h",
 )
 # the token-level decoders, whose parameters may be STORED in bfloat16
-BF16_PARAM_FAMILIES = ("kimi_k2", "lfm2_moe", "exaone_moe", "falcon_h1")
+BF16_PARAM_FAMILIES = ("kimi_k2", "lfm2_moe", "exaone_moe", "falcon_h1", "nemotron_h")
 
 
 def build_model(config: ModelConfig) -> nn.Module:
@@ -246,6 +254,33 @@ def build_model(config: ModelConfig) -> nn.Module:
             dtype=dtype,
             param_dtype=param_dtype,
         )
+    if config.family == "nemotron_h":
+        return NemotronHScorer(
+            cards=SCHEMA.cards,
+            num_numeric=SCHEMA.num_numeric,
+            layer_types=tuple(config.layer_types),
+            hidden=config.token_dim,
+            depth=config.depth,
+            heads=config.heads,
+            kv_heads=config.kv_heads or config.heads,
+            head_dim=config.head_dim or config.token_dim // config.heads,
+            ssm_dim=config.ssm_dim,
+            ssm_heads=config.ssm_heads,
+            ssm_state=config.ssm_state,
+            ssm_groups=config.ssm_groups,
+            ssm_chunk=config.ssm_chunk,
+            conv_width=config.conv_width,
+            moe_ffn_dim=config.moe_ffn_dim,
+            shared_ffn_dim=config.shared_ffn_dim or config.moe_ffn_dim,
+            num_experts=config.num_experts,
+            experts_per_token=config.experts_per_token,
+            first_expert=config.first_expert,
+            experts_held=config.experts_held or config.num_experts,
+            vocab_rows=config.vocab_rows,
+            records_per_history=config.doc_records,
+            dtype=dtype,
+            param_dtype=param_dtype,
+        )
     from mlops_tpu.models.gbm import SKLEARN_FAMILIES
 
     if config.family in SKLEARN_FAMILIES:
@@ -291,6 +326,7 @@ __all__ = [
     "LinearModel",
     "MLP",
     "MoETransformer",
+    "NemotronHScorer",
     "build_model",
     "init_params",
 ]

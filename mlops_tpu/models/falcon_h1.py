@@ -26,17 +26,14 @@ convention and the read-out of `models/exaone_moe.py`.
   ``kv_heads`` key/value heads of ``head_dim``, NO head norm, the keys
   times ``key_multiplier``, rotate-half positions on queries and keys in
   every layer, every key so far at scale ``head_dim ** -0.5``.
-- **SSM** (Mamba-2): ``in_proj`` (hidden -> ``z`` ``ssm_dim`` | ``x``
-  ``ssm_dim`` | ``B`` and ``C`` ``ssm_groups * ssm_state`` each | ``dt``
-  ``ssm_heads``), its output times the muP vector (``ssm_multipliers`` on
-  the columns of ``z``, ``x``, ``B``, ``C``, ``dt``); ``x | B | C``
-  through `ops/short_conv.py causal_conv` (depthwise, ``conv_width`` taps,
-  a bias, SiLU); ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``, one
-  a head; `ops/ssd.py ssd_scan` with the skip ``D x``; the gate FIRST (``y
-  * silu(z)``), then RMSNorm over each of the ``ssm_groups`` groups of
-  channels, one weight a channel (``mamba_rms_norm``,
-  ``mamba_norm_before_gate: false``); ``out_proj`` (``ssm_dim`` ->
-  hidden).
+- **SSM** (`models/mamba2.py mamba2_mixer`, which `models/nemotron_h.py`
+  runs as a layer of its own): ``in_proj`` to ``z | x | B | C | dt``, its
+  output times the muP vector (``ssm_multipliers`` on those five parts);
+  the causal convolution over ``x | B | C``; ``dt = softplus(dt +
+  dt_bias)``, ``A = -exp(A_log)``; `ops/ssd.py ssd_scan` with the skip ``D
+  x``; the gate FIRST, then RMSNorm over each of the ``ssm_groups`` groups
+  of channels (``mamba_rms_norm``, ``mamba_norm_before_gate: false``);
+  ``out_proj``.
 - **Precision.** Parameters are stored in ``param_dtype``; products take
   ``dtype`` operands and accumulate in float32; residual stream, norms,
   softmax, the convolution, ``dt``, the decays, the carried state, the
@@ -50,11 +47,9 @@ convention and the read-out of `models/exaone_moe.py`.
   projection and one convolution over the three; it is USED at the read
   positions.)
 
-The three per-head leaves are initialised as Mamba-2's reference code
-does, so that a model `init`-ed here carries state across many chunks:
-``A_log = log(1..heads)``, ``dt_bias`` the inverse softplus of a ``dt``
-drawn log-uniformly from [0.001, 0.1], ``D = 1``. Their leaves are
-``a_log/bias``, ``dt_bias/bias`` and ``skip/scale``.
+The mixer's three per-head leaves (``a_log/bias``, ``dt_bias/bias``,
+``skip/scale``) are initialised as Mamba-2's reference code does
+(`models/mamba2.py`).
 
 Scopes for a device trace: ``ssm_in`` (the projection and the muP
 vector), ``ssm_conv``, ``ssm_scan`` (``dt``, the decays, the scan),
@@ -66,8 +61,7 @@ vector), ``ssm_conv``, ``ssm_scan`` (``dt``, the decays, the scan),
 
 from __future__ import annotations
 
-import math
-from typing import Callable, Sequence
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -75,88 +69,13 @@ import numpy as np
 from flax import linen as nn
 
 from mlops_tpu.models.bert import TokenLayout, tokenize_histories
-from mlops_tpu.models.evabyte import RMS_EPS, RMSNorm
+from mlops_tpu.models.evabyte import RMS_EPS, RMSNorm  # noqa: F401  (RMS_EPS: the source's eps)
 from mlops_tpu.models.grouped_attention import GQA_SCOPES, grouped_query_attention
+# the gated norm under the name `benchmark/faults/falcon_h1.py` plants a fault on
+from mlops_tpu.models.mamba2 import GatedGroupNorm as _GatedGroupNorm  # noqa: F401
+from mlops_tpu.models.mamba2 import check_mixer, mamba2_mixer
 from mlops_tpu.models.routed_experts import swiglu
-from mlops_tpu.ops.short_conv import causal_conv
 from mlops_tpu.ops.ssd import ssd_scan
-
-DT_RANGE = (0.001, 0.1)  # Mamba-2's dt_min, dt_max
-
-
-def _dt_bias_init(key, shape, dtype):
-    """The inverse softplus of ``dt`` log-uniform in `DT_RANGE`."""
-    low, high = (math.log(v) for v in DT_RANGE)
-    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, low, high))
-    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-
-
-def _a_log_init(key, shape, dtype):
-    del key
-    return jnp.log(jnp.arange(1, shape[0] + 1, dtype=jnp.float32)).astype(dtype)
-
-
-class _PerHead(nn.Module):
-    """One number a head, float32 where it is used."""
-
-    leaf: str
-    fill: Callable  # the leaf's initialiser
-    param_dtype: jnp.dtype
-
-    @nn.compact
-    def __call__(self, heads: int) -> jnp.ndarray:
-        return self.param(self.leaf, self.fill, (heads,), self.param_dtype).astype(jnp.float32)
-
-
-class _Columns(nn.Module):
-    """A projection without a bias whose output columns may be asked for in
-    part (``kernel`` ``[in, features]``, an `nn.Dense`'s leaf)."""
-
-    features: int
-    dtype: jnp.dtype
-    param_dtype: jnp.dtype
-
-    @nn.compact
-    def __call__(self, h: jnp.ndarray, start: int = 0, stop: int | None = None) -> jnp.ndarray:
-        kernel = self.param(
-            "kernel", nn.initializers.lecun_normal(), (h.shape[-1], self.features),
-            self.param_dtype,
-        )
-        return jnp.dot(h.astype(self.dtype), kernel[:, start:stop].astype(self.dtype))
-
-
-class _ConvTaps(nn.Module):
-    """The convolution's filters ``kernel`` ``[width, channels]`` (one a
-    channel: depthwise) and its ``bias`` ``[channels]``."""
-
-    width: int
-    param_dtype: jnp.dtype
-
-    @nn.compact
-    def __call__(self, channels: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-        taps = nn.initializers.lecun_normal(in_axis=0, out_axis=1)
-        return (
-            self.param("kernel", taps, (self.width, channels), self.param_dtype),
-            self.param("bias", nn.initializers.normal(0.1), (channels,), self.param_dtype),
-        )
-
-
-class _GatedGroupNorm(nn.Module):
-    """The source's ``FalconH1RMSNormGated`` with ``norm_before_gate``
-    false: ``y * silu(z)`` FIRST, then RMSNorm over each of ``groups`` equal
-    groups of the last axis's channels, one weight a channel (``scale``);
-    float32."""
-
-    groups: int
-    param_dtype: jnp.dtype
-
-    @nn.compact
-    def __call__(self, y: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
-        g = self.param("scale", nn.initializers.ones_init(), (y.shape[-1],), self.param_dtype)
-        gated = (y * nn.silu(z)).reshape(*y.shape[:-1], self.groups, -1)
-        rms = jax.lax.rsqrt(jnp.mean(gated * gated, axis=-1, keepdims=True) + RMS_EPS)
-        return (gated * rms).reshape(y.shape) * g.astype(jnp.float32)
-
 
 class FalconH1Block(nn.Module):
     """One decoder layer on the float32 residual stream ``[B, S, dim]``.
@@ -192,51 +111,6 @@ class FalconH1Block(nn.Module):
     def _norm(self, name: str) -> RMSNorm:
         return RMSNorm(unit_offset=False, param_dtype=self.param_dtype, name=name)
 
-    def _per_head(self, name: str, leaf: str, fill: Callable) -> jnp.ndarray:
-        return _PerHead(leaf, fill, self.param_dtype, name=name)(self.ssm_heads)
-
-    def _ssm(self, h: jnp.ndarray, read: np.ndarray | None) -> jnp.ndarray:
-        """``h`` ``[B, S, dim]`` (float32, times its multiplier) -> ``[B, S,
-        dim]``, or ``[B, len(read), dim]``."""
-        b, seq, dim = h.shape
-        inner, heads = self.ssm_dim, self.ssm_heads
-        shared = self.ssm_groups * self.ssm_state  # B's columns, and C's
-        parts = (inner, inner, shared, shared, heads)  # z | x | B | C | dt
-        mup = np.repeat(np.asarray(self.ssm_multipliers, np.float32), parts)
-        with jax.named_scope("ssm_in"):
-            project = _Columns(sum(parts), self.dtype, self.param_dtype, name="in_proj")
-            if read is None:
-                z, xbc, dt = jnp.split(
-                    project(h).astype(jnp.float32) * mup, [inner, 2 * inner + 2 * shared], axis=-1
-                )
-            else:  # the gate at the read positions, the rest at every one
-                z = project(h[:, read], 0, inner).astype(jnp.float32) * mup[:inner]
-                xbc, dt = jnp.split(
-                    project(h, inner).astype(jnp.float32) * mup[inner:], [inner + 2 * shared],
-                    axis=-1,
-                )
-        with jax.named_scope("ssm_conv"):
-            taps = _ConvTaps(self.conv_width, self.param_dtype, name="conv")
-            x, b_in, c_out = jnp.split(
-                causal_conv(xbc, *taps(inner + 2 * shared)), [inner, inner + shared], axis=-1
-            )
-        with jax.named_scope("ssm_scan"):
-            dt = jax.nn.softplus(dt + self._per_head("dt_bias", "bias", _dt_bias_init))
-            a = -jnp.exp(self._per_head("a_log", "bias", _a_log_init))
-            skip = self._per_head("skip", "scale", nn.initializers.ones_init())
-            c_out = c_out.reshape(b, seq, self.ssm_groups, self.ssm_state)
-            y = ssd_scan(
-                x.reshape(b, seq, heads, inner // heads), dt, a,
-                b_in.reshape(b, seq, self.ssm_groups, self.ssm_state),
-                c_out if read is None else c_out[:, read], skip,
-                chunk=self.ssm_chunk, read=read, dtype=self.dtype,
-            )
-        with jax.named_scope("ssm_out"):
-            normed = _GatedGroupNorm(self.ssm_groups, self.param_dtype, name="ssm_norm")(
-                y.reshape(b, -1, inner), z
-            )
-            return self._dense(dim, "out_proj")(normed.astype(self.dtype))
-
     def _attention(self, h: jnp.ndarray, read: np.ndarray | None) -> jnp.ndarray:
         return grouped_query_attention(
             self, h, read, head_dim=self.head_dim, scopes=GQA_SCOPES, turn=True,
@@ -246,7 +120,11 @@ class FalconH1Block(nn.Module):
     @nn.compact
     def __call__(self, x: jnp.ndarray, read: np.ndarray | None = None) -> jnp.ndarray:
         h = self._norm("input_norm")(x)  # float32: both mixers read this
-        state_mixed = self._ssm(h * self.ssm_in_multiplier, read)
+        # ``ssd_scan`` as this module names it (`benchmark/faults/falcon_h1.py`)
+        state_mixed = mamba2_mixer(
+            self, h * self.ssm_in_multiplier, read, scan=ssd_scan,
+            multipliers=self.ssm_multipliers,
+        )
         attended = self._attention((h * self.attention_in_multiplier).astype(self.dtype), read)
         if read is not None:
             x = x[:, read]
@@ -314,11 +192,7 @@ class FalconH1Scorer(nn.Module):
                 f"{self.heads} query heads over {self.kv_heads} key/value heads "
                 f"of {self.head_dim}"
             )
-        if self.ssm_dim % self.ssm_heads or self.ssm_heads % self.ssm_groups:
-            raise ValueError(
-                f"a state-space mixer of {self.ssm_dim} in {self.ssm_heads} heads "
-                f"over {self.ssm_groups} groups"
-            )
+        check_mixer(self.ssm_dim, self.ssm_heads, self.ssm_groups)
         if len(self.ssm_multipliers) != 5 or len(self.mlp_multipliers) != 2:
             raise ValueError(
                 f"{len(self.ssm_multipliers)} ssm_multipliers (z, x, B, C, dt) and "

@@ -1,8 +1,8 @@
 """Causal grouped-query attention as the token-level decoders build it
-(`models/lfm2_moe.py`, `models/exaone_moe.py`, `models/falcon_h1.py`): the
-four projections and, where the family norms its heads, the two head
-norms under the CALLING block's own names (``q``, ``k``, ``v``, ``o``,
-``q_norm``, ``k_norm``), the optional turn
+(`models/lfm2_moe.py`, `models/exaone_moe.py`, `models/falcon_h1.py`,
+`models/nemotron_h.py`): the four projections and, where the family norms
+its heads, the two head norms under the CALLING block's own names (``q``,
+``k``, ``v``, ``o``, ``q_norm``, ``k_norm``), the optional turn
 (`ops/eva_attention.py rope`), `ops/gqa_attention.py gqa_attend` and the
 output projection, under the three scopes the caller names.
 
@@ -11,21 +11,21 @@ full): a layer that answers every position of a whole history, full or
 windowed, is ONE call of the Pallas kernel ``gqa_attend_fwd`` under the
 caller's second scope wherever the program is lowered for a TPU and
 `wants_gqa_kernel` admits the shape (the published ones: `exaone_moe`'s
-heads of 128, full and window 128, and `falcon_h1`'s, a group of five;
-`lfm2_moe`'s heads of 64); every other
-platform and shape, the ``read`` form (a model's last layer) and the
-backward are `ops/causal_attention.py causal_attend` in plain XLA, which
-``query_block`` steers and nothing else.
+heads of 128, full and window 128, `falcon_h1`'s, a group of five,
+`nemotron_h`'s, a group of sixteen, and `lfm2_moe`'s heads of 64); every
+other platform and shape, the ``read`` form (a model's last layer) and
+the backward are `ops/causal_attention.py causal_attend` in plain XLA,
+which ``query_block`` steers and nothing else.
 
 A family differs in what it passes: a head's width (its own, or ``hidden
 // heads``), the window (``None``: every key up to the query), whether the
 layer turns its queries and keys, whether a head's query and key are
-normed (``normed``: `falcon_h1` norms none and has no such parameters), a
-scale on the keys (``key_scale``: `falcon_h1`'s muP ``key_multiplier``),
-and the scopes' names. The head counts,
-the rotary base and the dtypes are the block's own fields (``heads``,
-``kv_heads``, ``rope_theta``, ``dtype``), the layers its ``_dense`` and
-``_norm``.
+normed (``normed``: `falcon_h1` and `nemotron_h` norm none and have no
+such parameters), a scale on the keys (``key_scale``: `falcon_h1`'s muP
+``key_multiplier``), and the scopes' names. The head counts, the rotary
+base and the dtypes are the block's own fields (``heads``, ``kv_heads``,
+``dtype``; ``rope_theta`` where it turns), the layers its ``_dense`` and
+(where it norms) ``_norm``.
 """
 
 from __future__ import annotations

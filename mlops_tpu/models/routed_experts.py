@@ -1,15 +1,20 @@
 """The routed-expert part of a sparse decoder layer, as the token-level
-history scorers build it (`models/kimi_k2.py`, `models/lfm2_moe.py`): the
-router's and the held experts' parameters under the CALLING block's own
-names (``router/{kernel,bias}``, ``experts_{gate,up,down}/kernel``), the
+history scorers build it (`models/kimi_k2.py`, `models/lfm2_moe.py`,
+`models/exaone_moe.py`, `models/nemotron_h.py`): the router's and the held
+experts' parameters under the CALLING block's own names
+(``router/{kernel,bias}``, ``experts_{gate,up,down}/kernel``), the
 dropless dispatch of `ops/moe_dispatch.py`, and the routing counter.
 
 A family differs in its block's fields (how many experts a token chooses,
-which experts this process holds) and in what it passes: the scaling and
-the normaliser's epsilon of its router. The one SwiGLU the three decoders
-run outside the routed experts is here too (`swiglu`: a dense layer's FFN,
-and the shared expert that `experts_beside_a_shared_one` adds unweighted,
-`models/kimi_k2.py` and `models/exaone_moe.py`).
+which experts this process holds), in what it passes (the scaling and the
+normaliser's epsilon of its router) and in its experts' form: a SwiGLU
+(gate, up, down: every family but one) or, ``gated=False``, Nemotron-H's
+``down(relu(up h)^2)`` (``experts_{up,down}``, no gate). The dense forms
+of both run outside the routed experts are here too: `swiglu` (a dense
+layer's FFN, and the shared expert of `models/kimi_k2.py` and
+`models/exaone_moe.py`) and `relu2_mlp` (the shared expert of
+`models/nemotron_h.py`, of a width of its own), each added unweighted by
+`experts_beside_a_shared_one`.
 """
 
 from __future__ import annotations
@@ -19,6 +24,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from mlops_tpu.ops import moe_dispatch
+from mlops_tpu.ops.expert_products import relu2
 
 ROUTING = "routing"  # the collection the expert layers count into
 
@@ -72,7 +78,7 @@ def check_share(
 
 
 def routed_experts(
-    block: nn.Module, h: jnp.ndarray, *, scaling: float, eps: float
+    block: nn.Module, h: jnp.ndarray, *, scaling: float, eps: float, gated: bool = True
 ) -> jnp.ndarray:
     """The held experts' weighted part of every token's result: ``h``
     float32 ``[T, dim]`` -> float32 ``[T, dim]``. Called inside ``block``'s
@@ -93,6 +99,16 @@ def routed_experts(
     def stacked(inputs: int, outputs: int, name: str) -> jnp.ndarray:
         return _Stacked(held, inputs, outputs, block.param_dtype, name=name)()
 
+    rows = moe_dispatch.segment_rows(tokens, top_k, block.num_experts, held)
+    if not gated:
+        return moe_dispatch.grouped_relu2(
+            h.astype(block.dtype),
+            routing,
+            planned,
+            stacked(dim, width, "experts_up"),
+            stacked(width, dim, "experts_down"),
+            rows,
+        )
     return moe_dispatch.grouped_swiglu(
         h.astype(block.dtype),
         routing,
@@ -100,7 +116,7 @@ def routed_experts(
         stacked(dim, width, "experts_gate"),
         stacked(dim, width, "experts_up"),
         stacked(width, dim, "experts_down"),
-        moe_dispatch.segment_rows(tokens, top_k, block.num_experts, held),
+        rows,
     )
 
 
@@ -120,16 +136,36 @@ def swiglu(
     return block._dense(h.shape[-1], f"{prefix}down")(gated.astype(block.dtype))
 
 
+def relu2_mlp(block: nn.Module, h: jnp.ndarray, width: int, prefix: str = "") -> jnp.ndarray:
+    """``down(relu(up h)^2)`` of ``width`` through the block's own ``_dense``
+    layers ``<prefix>up``, ``<prefix>down``: the square in float32, rounded
+    once (Nemotron-H's ``relu2``, ``mlp_bias`` false)."""
+    up = block._dense(width, f"{prefix}up")(h).astype(jnp.float32)
+    return block._dense(h.shape[-1], f"{prefix}down")(relu2(up).astype(block.dtype))
+
+
 def experts_beside_a_shared_one(
-    block: nn.Module, h: jnp.ndarray, *, scaling: float, eps: float
+    block: nn.Module,
+    h: jnp.ndarray,
+    *,
+    scaling: float,
+    eps: float,
+    gated: bool = True,
+    shared_width: int = 0,
 ) -> jnp.ndarray:
-    """`routed_experts` plus ONE shared expert of the routed experts' width
-    (``shared_{gate,up,down}``, scope ``shared_expert``), added unweighted
-    and whole whatever share of the routed experts is held: ``h`` float32
-    ``[T, dim]`` -> float32 ``[T, dim]``."""
-    routed = routed_experts(block, h, scaling=scaling, eps=eps)
+    """`routed_experts` plus ONE shared expert of the same form
+    (``shared_{gate,up,down}``, or ``shared_{up,down}`` where not
+    ``gated``; scope ``shared_expert``), ``shared_width`` wide (0: the
+    routed experts' width), added unweighted and whole whatever share of
+    the routed experts is held: ``h`` float32 ``[T, dim]`` -> float32 ``[T,
+    dim]``."""
+    routed = routed_experts(block, h, scaling=scaling, eps=eps, gated=gated)
+    width = shared_width or block.moe_ffn_dim
     with jax.named_scope("shared_expert"):
-        shared = swiglu(block, h.astype(block.dtype), block.moe_ffn_dim, "shared_")
+        if gated:
+            shared = swiglu(block, h.astype(block.dtype), width, "shared_")
+        else:
+            shared = relu2_mlp(block, h.astype(block.dtype), width, "shared_")
     return routed + shared.astype(jnp.float32)
 
 

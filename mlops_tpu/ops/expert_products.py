@@ -1,9 +1,9 @@
 """The routed experts' grouped products of one segment as Pallas kernels
-(Mosaic): what `ops/moe_dispatch.py grouped_swiglu` calls under the scope
-``experts`` where the computation is lowered for a TPU and
-`wants_grouped_kernel` admits the segment. The XLA form, three
-`jax.lax.ragged_dot` calls, stays in `ops/moe_dispatch.py` and is the
-definition.
+(Mosaic): what `ops/moe_dispatch.py grouped_swiglu` (scope ``experts``) and
+`grouped_relu2` (scope ``relu2_experts``) call where the computation is
+lowered for a TPU and `wants_grouped_kernel` admits the segment. The XLA
+forms, `jax.lax.ragged_dot` calls, stay in `ops/moe_dispatch.py` and are
+the definition.
 
 A segment's rows are sorted by expert; ``sizes`` ``[held]`` says how many
 each held expert got, and may sum to LESS than the rows (a segment past
@@ -11,14 +11,20 @@ the last held assignment is clipped): the rows past the sum belong to no
 expert and are left as they are found, undefined, as a grouped product
 leaves them.
 
-- ``experts_gate_up_fwd``: ``silu(x @ gate[e]) * (x @ up[e])`` in ONE
-  call. A grid step owns one tile of rows of one expert, reads it once,
-  makes both products over the whole contraction with float32
-  accumulation, and writes the SwiGLU (float32 arithmetic, then the one
-  cast to the products' dtype): the two float32 ``[rows, f]`` the XLA form
-  writes and reads again never reach HBM.
-- ``experts_down_fwd``: ``x @ down[e]``, float32 out, the same walk
-  without the epilogue.
+Two forms of expert, each two kernels over one walk:
+
+- the SwiGLU (`grouped_swiglu_kernels`): ``experts_gate_up_fwd`` makes
+  ``silu(x @ gate[e]) * (x @ up[e])`` in ONE call. A grid step owns one
+  tile of rows of one expert, reads it once, makes both products over the
+  whole contraction with float32 accumulation, and writes the SwiGLU
+  (float32 arithmetic, then the one cast to the products' dtype): the two
+  float32 ``[rows, f]`` the XLA form writes and reads again never reach
+  HBM;
+- the ReLU² of Nemotron-H (`grouped_relu2_kernels`), two matrices and no
+  gate: ``experts_up_fwd`` makes ``relu(x @ up[e])^2`` the same way, its
+  epilogue the square of the ReLU;
+- then ``experts_down_fwd``: ``x @ down[e]``, float32 out, the same walk
+  without an epilogue.
 
 **The walk** (after `jax.experimental.pallas.ops.tpu.megablox`): the
 (expert, row tile) pairs that hold a row, expert by expert, are worked out
@@ -57,7 +63,7 @@ BLOCKS_BUDGET_BYTES = 64 * 2**20  # a step's blocks, each held twice by the pipe
 
 class Tiles(NamedTuple):
     rows: int  # rows of one expert a step
-    gate_up: int  # columns of `gate` and of `up` a step
+    gate_up: int  # columns of the first kernel's weights a step (`gate` and `up`, or `up`)
     down: int  # columns of `down` a step
 
 
@@ -71,11 +77,17 @@ def _widest(width: int, fits) -> int:
     return 0
 
 
-def grouped_tiles(rows: int, held: int, d: int, f: int, itemsize: int = 2) -> Tiles | None:
+def grouped_tiles(
+    rows: int, held: int, d: int, f: int, itemsize: int = 2, gated: bool = True
+) -> Tiles | None:
     """The kernels' tiles for a segment of ``rows`` rows over ``held``
     experts of ``[d, f]`` (and ``[f, d]``), or None where they have none:
     widths that are not whole 128-lane tiles, rows that are not whole row
-    tiles, or a contraction too long for VMEM.
+    tiles, or a contraction too long for VMEM. ``gated``: the SwiGLU's two
+    weights (gate, up) a step of the first kernel; else the ReLU²'s one
+    (up), whose ``f`` may also be no whole number of lane tiles (Nemotron-H's
+    1,856 is 14.5): the first kernel's columns and the second's contraction
+    are then the whole width, one block as wide as the array.
 
     Rows a step, from ``rows // held``, what an even router gives an
     expert: 256 from 1,024 rows an expert on (compute-bound: an expert's
@@ -88,31 +100,39 @@ def grouped_tiles(rows: int, held: int, d: int, f: int, itemsize: int = 2) -> Ti
     step's blocks (rows in, the weights, rows out), held twice each by the
     pipeline, within `BLOCKS_BUDGET_BYTES`; the fewer column tiles, the
     fewer times the rows are read."""
-    if min(rows, held, d, f) <= 0 or d % 128 or f % 128:
+    whole_lanes = f % 128 == 0
+    if min(rows, held, d, f) <= 0 or d % 128 or f % 8 or (gated and not whole_lanes):
         return None
     tile = 256 if rows // held >= 1024 else 128
     if rows % tile:
         return None
+    weights = 2 if gated else 1
 
-    def gate_up_fits(columns):  # x, gate, up in; the SwiGLU out
-        step = tile * d * itemsize + 2 * d * columns * itemsize + tile * columns * itemsize
+    def gate_up_fits(columns):  # x and the weights in; the activation out
+        step = tile * d * itemsize + weights * d * columns * itemsize + tile * columns * itemsize
         return 2 * step <= BLOCKS_BUDGET_BYTES
 
     def down_fits(columns):  # x, down in; float32 out
         step = tile * f * itemsize + f * columns * itemsize + tile * columns * 4
         return 2 * step <= BLOCKS_BUDGET_BYTES
 
-    gate_up, down = _widest(f, gate_up_fits), _widest(d, down_fits)
+    if whole_lanes:
+        gate_up = _widest(f, gate_up_fits)
+    else:
+        gate_up = f if gate_up_fits(f) else 0
+    down = _widest(d, down_fits)
     if not gate_up or not down:
         return None
     return Tiles(tile, gate_up, down)
 
 
-def wants_grouped_kernel(rows: int, held: int, d: int, f: int, itemsize: int = 2) -> bool:
+def wants_grouped_kernel(
+    rows: int, held: int, d: int, f: int, itemsize: int = 2, gated: bool = True
+) -> bool:
     """The kernels' rule, from shapes alone (`grouped_tiles` has the
     reasons). Every tiny configuration of the tests, whose widths are no
     lane tiles, takes the XLA form."""
-    return grouped_tiles(rows, held, d, f, itemsize) is not None
+    return grouped_tiles(rows, held, d, f, itemsize, gated) is not None
 
 
 class Walk(NamedTuple):
@@ -149,6 +169,11 @@ def plan_walk(sizes: jnp.ndarray, rows: int, tile: int) -> Walk:
 
 def _swiglu(gated, lifted):
     return jax.nn.silu(gated) * lifted
+
+
+def relu2(lifted):
+    """Nemotron-H's ``relu2``: the square of the ReLU."""
+    return jnp.square(jax.nn.relu(lifted))
 
 
 def _visit_kernel(offsets_ref, expert_ref, tile_ref, live_ref, x_ref, *refs, epilogue):
@@ -235,6 +260,33 @@ def grouped_swiglu_kernels(
     hidden = _grouped_call(
         "experts_gate_up_fwd", _swiglu, walk, x, (gate, up),
         tiles.rows, tiles.gate_up, x.dtype, interpret,
+    )
+    return _grouped_call(
+        "experts_down_fwd", lambda product: product, walk, hidden, (down,),
+        tiles.rows, tiles.down, jnp.float32, interpret,
+    )
+
+
+def grouped_relu2_kernels(
+    x: jnp.ndarray,
+    up: jnp.ndarray,
+    down: jnp.ndarray,
+    sizes: jnp.ndarray,
+    tiles: Tiles | None = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """A segment's two grouped products of the ReLU² experts, ``down(relu(x
+    up)^2)``, as two kernels, for shapes `wants_grouped_kernel` admits with
+    ``gated=False``. Compiled by Mosaic (``interpret=False``); ``interpret``
+    and other ``tiles`` are for CPU tests."""
+    rows, d = x.shape
+    held, _, f = up.shape
+    tiles = tiles or grouped_tiles(rows, held, d, f, x.dtype.itemsize, gated=False)
+    if tiles is None:
+        raise ValueError(f"no tiling for {rows} rows over {held} ReLU² experts of {d} x {f}")
+    walk = plan_walk(sizes, rows, tiles.rows)
+    hidden = _grouped_call(
+        "experts_up_fwd", relu2, walk, x, (up,), tiles.rows, tiles.gate_up, x.dtype, interpret,
     )
     return _grouped_call(
         "experts_down_fwd", lambda product: product, walk, hidden, (down,),

@@ -1,6 +1,7 @@
 """Causal grouped-query attention over whole histories, full or over a
 sliding window, as `models/grouped_attention.py` calls it for
-`models/lfm2_moe.py` and `models/exaone_moe.py`: `ops/causal_attention.py
+`models/lfm2_moe.py`, `models/exaone_moe.py`, `models/falcon_h1.py` and
+`models/nemotron_h.py`: `ops/causal_attention.py
 causal_attend`'s arithmetic (float32 scores, maximum, exponentials and
 sum; the two products on the inputs' dtype with float32 accumulation; the
 weights rounded to the inputs' dtype once before the second product) in
@@ -12,9 +13,10 @@ Which form of ``gqa_attend`` runs where:
   where the computation is lowered for a TPU (`kernel_gate.tpu_kernel_or`)
   and `wants_gqa_kernel` admits the shape: every position's query
   (``read is None``), heads of one 128-lane tile or half of one, a history
-  of whole query blocks, a visit whose float32 scores fit VMEM. Both
-  published shapes are (`exaone_moe`: 64 heads over 8 of 128, full and
-  window 128; `lfm2_moe`: 32 over 8 of 64). The operands are read where the
+  of whole query blocks, a visit whose float32 scores fit VMEM. Every
+  published shape is (`exaone_moe`: 64 heads over 8 of 128, full and
+  window 128; `lfm2_moe`: 32 over 8 of 64; `falcon_h1`: a group of five
+  of 128; `nemotron_h`: 32 over 2 of 128, a group of SIXTEEN, unturned). The operands are read where the
   projections wrote them: a step's queries and outputs are a column block
   of ``[B, S, H * E]``, a key/value head's keys and values column block
   ``g`` of ``[B, S, G * E]`` (at a width of 64 a PAIR of key/value heads is

@@ -18,8 +18,8 @@ CPU tests.
 - ``tpu_kernel_forward``: the choice as a forward whose backward is the
   XLA form's, recomputed: the scaffold of every kernel that has no
   backward kernel (`ops/eva_attention.py eva_attend`, `ops/mla.py
-  mla_attend`, `ops/moe_dispatch.py segment_products`,
-  `ops/gqa_attention.py gqa_attend`). The three attention kernels' joint
+  mla_attend`, `ops/moe_dispatch.py segment_products` and
+  `segment_relu2`, `ops/gqa_attention.py gqa_attend`). The three attention kernels' joint
   softmax is `ops/lane_softmax.py`.
 """
 

@@ -20,7 +20,9 @@ other chips or for their exchange.
   dropped.
 - ``grouped_swiglu``: ``sum_i w_i * down_i(silu(gate_i(h)) * up_i(h))``
   over the held experts as grouped (ragged) matrix products over exactly
-  the assignments that fell here. The sorted assignments are walked in
+  the assignments that fell here; ``grouped_relu2`` the same walk for
+  Nemotron-H's experts, ``sum_i w_i * down_i(relu(up_i(h))^2)``: two
+  matrices and no gate. The sorted assignments are walked in
   SEGMENTS of a fixed number of rows (shapes stay static): a segment
   gathers its tokens' rows, runs the three products with its own slice of
   the group sizes (`segment_products`), and its weighted rows are combined
@@ -34,16 +36,18 @@ Which form of a segment's products runs where:
 - `ops/expert_products.py`, two Pallas kernels (Mosaic): where the
   computation is lowered for a TPU (`kernel_gate.tpu_kernel_or`) and
   `wants_grouped_kernel` admits the segment (widths of whole 128-lane
-  tiles, rows of whole row tiles; both cells' published shapes are such).
-  ``experts_gate_up_fwd`` makes ``gate`` and ``up`` in one pass over the
-  rows with the SwiGLU in its epilogue (the two float32 ``[rows, f]``
-  never reach HBM), ``experts_down_fwd`` the third product; the tiles
-  follow the rows an expert gets (`grouped_tiles`).
-- ``segment_products_xla``, three `jax.lax.ragged_dot` calls and the
-  activation between them, the definition: every other platform, every
-  other shape (each tiny configuration of the tests), and the backward
-  everywhere (`kernel_gate.tpu_kernel_forward`: the kernels are the
-  forward, the XLA form is recomputed and differentiated).
+  tiles, or for the ReLU² an expert width taken whole; rows of whole row
+  tiles; every cell's published shapes are such). ``experts_gate_up_fwd``
+  makes ``gate`` and ``up`` in one pass over the rows with the SwiGLU in
+  its epilogue (the two float32 ``[rows, f]`` never reach HBM),
+  ``experts_up_fwd`` ``up`` under the ReLU², ``experts_down_fwd`` the last
+  product; the tiles follow the rows an expert gets (`grouped_tiles`).
+- ``segment_products_xla`` (three `jax.lax.ragged_dot` calls and the
+  activation between them) and ``segment_relu2_xla`` (two), the
+  definition: every other platform, every other shape (each tiny
+  configuration of the tests), and the backward everywhere
+  (`kernel_gate.tpu_kernel_forward`: the kernels are the forward, the XLA
+  form is recomputed and differentiated).
 
 Which form of the combine runs where:
 
@@ -67,9 +71,10 @@ Which form of the combine runs where:
   scatter's 6.8 alone, and 3% of the cell's rows a second).
 
 The scopes ``router``, ``moe_dispatch`` (sort, gather), ``experts`` (the
-three grouped products: the kernels' calls carry it; the compiler's own
-``ragged-dot`` call carries none) and ``moe_combine`` are what a device
-trace carries (`benchmark/layer_metrics/`).
+SwiGLU's grouped products: the kernels' calls carry it; the compiler's own
+``ragged-dot`` call carries none), ``relu2_experts`` (the ReLU²'s, the
+same way) and ``moe_combine`` are what a device trace carries
+(`benchmark/layer_metrics/`).
 """
 
 from __future__ import annotations
@@ -79,7 +84,12 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from mlops_tpu.ops.expert_products import grouped_swiglu_kernels, wants_grouped_kernel
+from mlops_tpu.ops.expert_products import (
+    grouped_relu2_kernels,
+    grouped_swiglu_kernels,
+    relu2,
+    wants_grouped_kernel,
+)
 from mlops_tpu.ops.kernel_gate import tpu_kernel_forward
 
 
@@ -190,6 +200,40 @@ def segment_products(
     return _segment_products(taken, gate.astype(dtype), up.astype(dtype), down.astype(dtype), sizes)
 
 
+def segment_relu2_xla(
+    taken: jnp.ndarray, up: jnp.ndarray, down: jnp.ndarray, sizes: jnp.ndarray
+) -> jnp.ndarray:
+    """A segment's two grouped products of the ReLU² experts in plain XLA,
+    the definition: ``down(relu(taken up)^2)``, the square in float32 and
+    rounded once; ``taken`` ``[rows, d]`` sorted by expert -> float32
+    ``[rows, d]``, the rows past the sizes' sum undefined."""
+    dtype = taken.dtype
+    lifted = jax.lax.ragged_dot(
+        taken, up.astype(dtype), sizes, preferred_element_type=jnp.float32
+    )
+    return jax.lax.ragged_dot(
+        relu2(lifted).astype(dtype), down.astype(dtype), sizes,
+        preferred_element_type=jnp.float32,
+    )
+
+
+_segment_relu2 = tpu_kernel_forward(grouped_relu2_kernels, segment_relu2_xla)
+
+
+def segment_relu2(
+    taken: jnp.ndarray, up: jnp.ndarray, down: jnp.ndarray, sizes: jnp.ndarray
+) -> jnp.ndarray:
+    """`segment_relu2_xla`'s answer: on a TPU, where `wants_grouped_kernel`
+    admits the shape (``gated=False``), the two kernels forward and the XLA
+    form backward; everywhere else the XLA form."""
+    rows, d = taken.shape
+    held, _, f = up.shape
+    dtype = taken.dtype
+    if not wants_grouped_kernel(rows, held, d, f, dtype.itemsize, gated=False):
+        return segment_relu2_xla(taken, up, down, sizes)
+    return _segment_relu2(taken, up.astype(dtype), down.astype(dtype), sizes)
+
+
 def landed(order: jnp.ndarray) -> jnp.ndarray:
     """`Plan.order`'s inverse, int32 ``[T * k]``: the position each assignment
     id took in the sorted order (``order[landed(order)]`` counts up from 0).
@@ -210,12 +254,46 @@ def grouped_swiglu(
     f]``, ``down`` ``[held, f, d]`` -> float32 ``[T, d]``: the held experts'
     weighted part of every token's result (zero rows for tokens that chose
     none of them)."""
+    return grouped_experts(
+        h, routing, planned, rows, gate.shape[0],
+        lambda taken, sizes: segment_products(taken, gate, up, down, sizes), "experts",
+    )
+
+
+def grouped_relu2(
+    h: jnp.ndarray,
+    routing: Routing,
+    planned: Plan,
+    up: jnp.ndarray,
+    down: jnp.ndarray,
+    rows: int,
+) -> jnp.ndarray:
+    """`grouped_swiglu` for the ReLU² experts: ``up`` ``[held, d, f]``,
+    ``down`` ``[held, f, d]``, the products under ``relu2_experts``."""
+    return grouped_experts(
+        h, routing, planned, rows, up.shape[0],
+        lambda taken, sizes: segment_relu2(taken, up, down, sizes), "relu2_experts",
+    )
+
+
+def grouped_experts(
+    h: jnp.ndarray,
+    routing: Routing,
+    planned: Plan,
+    rows: int,
+    held: int,
+    products,
+    scope: str,
+) -> jnp.ndarray:
+    """The walk both expert forms share: ``products(taken, sizes)`` is a
+    segment's grouped products (float32 ``[rows, d]``), run under
+    ``scope``; ``held`` experts."""
     tokens, top_k = routing.experts.shape
     total = planned.offsets[-1]
     flat_weights = routing.weights.reshape(-1)
-    segments = -(-min(top_k, gate.shape[0]) * tokens // rows)
+    segments = -(-min(top_k, held) * tokens // rows)
 
-    def products(start):
+    def segment(start):
         """The segment from ``start`` on: which of its rows hold an
         assignment, the rows' assignment ids and tokens, and the products'
         float32 ``[rows, d]``. Rows past the last held assignment belong to
@@ -228,11 +306,11 @@ def grouped_swiglu(
             taken = jnp.take(h, token, axis=0)
             edges = jnp.clip(planned.offsets, start, start + rows)
             sizes = edges[1:] - edges[:-1]
-        with jax.named_scope("experts"):
-            return live, assignment, token, segment_products(taken, gate, up, down, sizes)
+        with jax.named_scope(scope):
+            return live, assignment, token, products(taken, sizes)
 
     def gathered():
-        *_, out = products(0)
+        *_, out = segment(0)
         with jax.named_scope("moe_dispatch"):
             # slots outermost: ``[k, T, d]`` is ``[k * T, d]`` viewed
             pos = landed(planned.order).reshape(tokens, top_k).T
@@ -247,7 +325,7 @@ def grouped_swiglu(
         start = index * rows
 
         def run(result):
-            live, assignment, token, out = products(start)
+            live, assignment, token, out = segment(start)
             with jax.named_scope("moe_combine"):
                 # the undefined rows are zeroed
                 weighted = jnp.where(

@@ -23,7 +23,7 @@ anyway) and no float32 copy of ``[T, 3 d]`` in HBM. The projections are
 the caller's (`models/lfm2_moe.py`, scopes ``conv_in`` and ``conv_out``).
 
 Beside it `causal_conv`, the PLAIN causal depthwise convolution of the
-Mamba family (`models/falcon_h1.py`, scope ``ssm_conv``): the same shifted
+Mamba family (`models/mamba2.py`, scope ``ssm_conv``): the same shifted
 multiply-adds over one input with no gate, a bias a channel, and SiLU
 after:
 

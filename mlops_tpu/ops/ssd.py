@@ -1,6 +1,7 @@
 """The selective state-space scan of Mamba-2 (SSD: Dao and Gu,
 arXiv:2405.21060) over whole histories, in its chunked form: the token
-mixer that `models/falcon_h1.py` runs beside its attention in every layer.
+mixer of `models/mamba2.py`, which `models/falcon_h1.py` runs beside its
+attention in every layer and `models/nemotron_h.py` as a layer of its own.
 
 Per head ``h`` (``P`` channels) of group ``g = h // (H // G)``, with a
 state ``H`` ``[P, N]`` that is zero at a history's start:

@@ -51,6 +51,7 @@ TAIL_RULE = {  # rows of a job, its chunk, the unit, the tail's run size
     # the benchmark's cells
     "k-exaone-236b-a23b.bulk-hist": (1228, 512, 64, 256),
     "falcon-h1-34b.bulk-hist": (1228, 512, 64, 256),
+    "nemotron-labs-twotower-30b-a3b.bulk-hist": (1228, 512, 64, 256),
     "bert-base.bulk-uci": (30_000, 4096, 1, 2048),
     "bert-base.bulk": (65_000, 4096, 1, 4096),
     "bert-base.bulk-dp4": (260_000, 16_384, 4, 16_384),

@@ -10,11 +10,15 @@ from mlops_tpu.models import FAMILIES, build_model, init_params
 from mlops_tpu.schema import NUM_CATEGORICAL, NUM_NUMERIC
 
 
-# what a family has to be told beside the shared sizes: `exaone_moe` has no
-# list of its own among `ModelConfig`'s defaults (`layer_types` is
-# `lfm2_moe`'s published list, which it refuses)
+# what a family has to be told beside the shared sizes: `exaone_moe` and
+# `nemotron_h` have no list of their own among `ModelConfig`'s defaults
+# (`layer_types` is `lfm2_moe`'s published list, which they refuse)
 FAMILY_FIELDS = {
     "exaone_moe": {"layer_types": ("sliding_attention", "full_attention"), "attn_window": 16},
+    "nemotron_h": {
+        "layer_types": ("moe", "mamba"), "ssm_dim": 64, "ssm_heads": 8, "ssm_state": 16,
+        "ssm_groups": 2, "moe_ffn_dim": 32,
+    },
 }
 
 

@@ -750,6 +750,84 @@ def test_falcon_h1_chunk_program_compiles_for_v5e_at_published_widths(
         assert re.search(rf'op_name="[^"]*/{scope}[/"]', text), scope
 
 
+def test_nemotron_h_chunk_program_compiles_for_v5e_at_published_widths(
+    one_chip, no_persistent_cache
+):
+    """The bulk chunk program of `nemotron-labs-twotower-30b-a3b.bulk-hist`
+    as the cell runs it (`parallel/bulk.py make_bulk_fused` over
+    `models/nemotron_h.py` at the configuration file's widths, eight
+    histories of 64 records a run, bfloat16 parameters, layers 0-12 of the
+    pattern, 64 of 128 experts held): it fits the 75% rule its chunk was
+    sized by; each of the five E layers runs the ReLU² experts as
+    `ops/expert_products.py`'s two kernels under ``relu2_experts`` with an
+    expert width of 1,856 taken whole (no ``ragged-dot`` left); attention
+    layer 5 answers every position by `gqa_attend_fwd` at a group of SIXTEEN
+    query heads, and layer 12 (the last) at the read positions; the six
+    mixers' scans hand their states over in ONE float32 array a layer and
+    form nothing 3,072 wide; and no rotation is applied anywhere."""
+    import json
+    from pathlib import Path
+
+    from mlops_tpu.config import ModelConfig
+    from mlops_tpu.models import abstract_variables, build_model
+    from mlops_tpu.monitor.state import abstract_monitor_state
+    from mlops_tpu.parallel.bulk import make_bulk_fused
+    from mlops_tpu.schema import SCHEMA
+
+    real = json.loads(
+        (Path(__file__).resolve().parents[1]
+         / "benchmark/configs/nemotron-labs-twotower-30b-a3b.json").read_text()
+    )
+    fields = dict(real["model_config"])
+    for name in ("hidden_dims", "layer_types"):
+        fields[name] = tuple(fields[name])
+    model = build_model(ModelConfig(**fields))
+    rows = real["deployment"]["score_chunk_rows"]
+    assert rows == 512 and model.depth == 13
+    compiled = (
+        jax.jit(make_bulk_fused(model))
+        .lower(
+            _on(abstract_variables(model), one_chip),
+            _on(abstract_monitor_state(), one_chip),
+            S((), jnp.float32, sharding=one_chip),
+            S((rows, SCHEMA.num_categorical), jnp.int8, sharding=one_chip),
+            S((rows, SCHEMA.num_numeric), jnp.float32, sharding=one_chip),
+            S((rows,), jnp.bool_, sharding=one_chip),
+        )
+        .compile()
+    )
+    memory = compiled.memory_analysis()
+    needed = memory.temp_size_in_bytes + memory.argument_size_in_bytes
+    assert 7.85e9 < memory.argument_size_in_bytes < 7.86e9  # 3.93 B parameters, 2 bytes each
+    assert needed <= 0.75 * 15.75 * 2**30, needed
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    for kernel in ("experts_up_fwd", "experts_down_fwd"):
+        mine = [line for line in calls if kernel in line.split(" = ")[0]]
+        assert len(mine) == 5, (kernel, len(mine))
+        for call in mine:
+            op_name = re.search(r'op_name="([^"]*)"', call).group(1).split("/")
+            assert "relu2_experts" in op_name and "moe_layer" in op_name, op_name
+    assert not [line for line in calls if "experts_gate_up_fwd" in line.split(" = ")[0]]
+    assert "ragged-dot" not in text
+    # the up kernel's output: a segment's 147,456 rows by the whole 1,856
+    assert re.search(r"bf16\[147456,1856\]", text)
+    (kernel,) = [line for line in calls if "gqa_attend_fwd" in line.split(" = ")[0]]
+    op_name = re.search(r'op_name="([^"]*)"', kernel).group(1).split("/")
+    assert {"block_5", "attention_layer", "gqa_attend"} <= set(op_name), op_name
+    assert re.search(r"bf16\[8,3072,4096\]", kernel.split(" = ")[1])  # 32 heads of 128
+    # the last layer: 64 read positions of 16 query heads a key/value head against every key
+    assert re.search(r"f32\[8,2,1024,3072\]", text), "the read form's scores"
+    # the scan: states [chunks, histories, groups, heads a group, 64, 128]
+    assert re.search(r"f32\[24,8,8,8,64,128\]", text), "the chunk states"
+    assert not re.search(r"\[[\d,]*3072,3072\]", text)
+    names = [m.group(1).split("/") for m in re.finditer(r'op_name="([^"]*)"', text)]
+    assert not [n for n in names if "rope" in n]
+    for scope in ("mamba_mixer", "moe_layer", "attention_layer", "ssm_scan", "shared_expert",
+                  "router", "moe_combine", "gqa_qkv", "gqa_o", "embed"):
+        assert any(scope in n for n in names), scope
+
+
 def test_mla_attention_compiles_for_v5e_at_the_longest_sequence_its_rule_admits(
     one_chip, no_persistent_cache
 ):
